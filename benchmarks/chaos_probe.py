@@ -72,6 +72,7 @@ from repro.parallel import sharding as shd             # noqa: E402
 from repro.serve import snapshot as snap               # noqa: E402
 from repro.serve.kv_cache import PagedKVPool           # noqa: E402
 from repro.train.checkpoint import CheckpointManager   # noqa: E402
+from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
 RECOVERY_BOUND = 4          # lookup epochs from injection back to routed
 WIDTH = 32                  # divisible by 1/2/4 (shard-loss shrink path)
@@ -83,7 +84,7 @@ PAGE = 8
 def _mesh(n=N_DEV):
     assert len(jax.devices()) >= n, \
         f"forced host mesh absent: {len(jax.devices())} device(s)"
-    return jax.make_mesh((1, n), ("data", "model"))
+    return make_auto_mesh((1, n), ("data", "model"))
 
 
 def _seeded_state(n_keys=20, seed=11):

@@ -56,6 +56,7 @@ from repro.core import workload as wl                  # noqa: E402
 from repro.kernels import ops as kops                  # noqa: E402
 from repro.kernels import splay_search as ssk          # noqa: E402
 from repro.parallel import sharding as shd             # noqa: E402
+from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
 SPILL_OK = 0.01          # "recovered" = spill rate at or below this
 
@@ -143,7 +144,7 @@ def run_parity(width=1024, batch=512, epochs=12, seed=7):
     cap, L = width + 2, 12
     assert len(jax.devices()) >= N_DEV, \
         f"forced host mesh absent: {len(jax.devices())} device(s)"
-    mesh = jax.make_mesh((1, N_DEV), ("data", "model"))
+    mesh = make_auto_mesh((1, N_DEV), ("data", "model"))
     k_bound = len(rc.default_slack_ladder(N_DEV))
     print(f"drift parity: w={width} B={batch} E={epochs} shards={N_DEV} "
           f"recovery bound K={k_bound} mode={kops.exec_mode()}")
@@ -218,7 +219,7 @@ def run_parity(width=1024, batch=512, epochs=12, seed=7):
 def run_bench(width=4096, nq=8192, epochs=10, seed=7):
     n = int(width * 0.75)
     cap, L = width + 2, 14
-    mesh = jax.make_mesh((1, N_DEV), ("data", "model"))
+    mesh = make_auto_mesh((1, N_DEV), ("data", "model"))
     k_bound = len(rc.default_slack_ladder(N_DEV))
     out = {"width": width, "batch": nq, "epochs": epochs,
            "shards": N_DEV, "recovery_bound_epochs": k_bound,

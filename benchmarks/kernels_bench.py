@@ -68,28 +68,30 @@ def _bytes_model(L: la.LevelArrays, query_block: int, nq: int) -> dict:
     fetched once and must stay VMEM-resident; every level row is compared
     full-width by every query.
 
-    tiered kernel: one (1, W) level row + one (1, W) rank-map row stream
-    per (query block, live level); statically-empty rows are aliased away
-    by the fetch schedule; per-query compares are O(log window) probes.
+    tiered kernel: one level row (padded to whole 128-lane chunks)
+    streams per (query block, live level); statically-empty rows are
+    aliased away by the fetch schedule; a query costs W/128 sample
+    compares, a one-hot chunk fetch and 128 chunk compares per row.
     """
+    from repro.kernels import splay_search as ssk
     n_levels, width = L.keys.shape
     itemsize = 4
     q_blocks = max(nq // query_block, 1)
     live = int((L.widths > 0).sum())
     per_level_bytes = [int(width * itemsize) for _ in range(n_levels)]
     seed_resident = n_levels * width * itemsize
-    tiered_streamed = q_blocks * live * 2 * width * itemsize
+    tiered_streamed = q_blocks * ssk.tiered_row_bytes(live, width)
     return {
         "n_levels": n_levels,
         "width": width,
         "live_levels": live,
         "per_level_row_bytes": per_level_bytes,
         "seed_vmem_resident_bytes": seed_resident,
-        "tiered_vmem_resident_bytes": 2 * width * itemsize,
+        "tiered_vmem_resident_bytes": ssk.tiered_row_bytes(1, width),
         "tiered_streamed_bytes_per_batch": tiered_streamed,
         "seed_compares_per_query": n_levels * width,
-        "tiered_probes_per_query":
-            int(n_levels * (max(int(width).bit_length(), 1))),
+        "tiered_compares_per_query":
+            int(n_levels * (-(-width // ssk.CHUNK) + ssk.CHUNK)),
     }
 
 
@@ -272,8 +274,7 @@ def _sharded_search_case(width: int, nq: int) -> dict:
     overhead, and the structural columns (per-shard resident bytes,
     O(nq·slack) exchange wire, routing balance, spill rate) are what
     transfers."""
-    env = dict(os.environ, PYTHONPATH="src")
-    env.pop("XLA_FLAGS", None)            # probe forces its own count
+    env = _probe_env()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
         [sys.executable, "benchmarks/sharded_search_probe.py",
@@ -298,8 +299,7 @@ def _ordered_case(width: int, nq: int) -> dict:
     bottom row per query).  Same subprocess pattern as the other mesh
     probes (``benchmarks/ordered_search_probe.py --bench`` asserts
     replicated/sharded bit-identity and prints one JSON object)."""
-    env = dict(os.environ, PYTHONPATH="src")
-    env.pop("XLA_FLAGS", None)            # probe forces its own count
+    env = _probe_env()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
         [sys.executable, "benchmarks/ordered_search_probe.py",
@@ -313,6 +313,15 @@ def _ordered_case(width: int, nq: int) -> dict:
          f"truncated={out['scans_truncated']};"
          f"bit_identical={out['bit_identical']}")
     return out
+
+
+def _probe_env() -> dict:
+    """Environment of a child probe: the CPU host-mesh batteries force
+    their own device count, and ``JAX_PLATFORMS=cpu`` keeps them off the
+    chip — this process may hold it, and a chip serves one process."""
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    return env
 
 
 def _pipelined_case(width: int, nq: int, qb: int, reps: int) -> dict:
@@ -345,7 +354,7 @@ def _pipelined_case(width: int, nq: int, qb: int, reps: int) -> dict:
         assert (np.asarray(a) == np.asarray(b)).all()
     q_blocks = max(nq // qb, 1)
     live = int((np.asarray(w) > 0).sum())
-    tiered_bytes = q_blocks * live * 2 * width * 4
+    tiered_bytes = q_blocks * ssk.tiered_row_bytes(live, width)
     pipe_bytes = int(np.asarray(nb).sum())
     reduction = tiered_bytes / max(pipe_bytes, 1)
     emit(f"kernel_splay_search_pipelined_a{alpha}", dt_pipe / nq * 1e6,
@@ -375,8 +384,7 @@ def _drift_case(width: int, nq: int, epochs: int = 10) -> dict:
     per-epoch spill/max-share/gini trajectories and per-transition
     time-to-recover; the headline per scenario is the controller's
     worst recovery time against the static baseline's."""
-    env = dict(os.environ, PYTHONPATH="src")
-    env.pop("XLA_FLAGS", None)            # probe forces its own count
+    env = _probe_env()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
         [sys.executable, "benchmarks/drift_probe.py", "--bench",
@@ -407,8 +415,7 @@ def _serving_case(n_requests: int) -> dict:
     tokens/sec, index-plane query share, steady-state spill rate, the
     backpressure counters, and the host-vs-device bit-identity flag CI
     gates on."""
-    env = dict(os.environ, PYTHONPATH="src")
-    env.pop("XLA_FLAGS", None)            # probe forces its own count
+    env = _probe_env()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
         [sys.executable, "benchmarks/serving_probe.py", "--bench",
@@ -434,8 +441,7 @@ def _chaos_case() -> dict:
     snapshot replay, and cross-backend restore bit-identity.  CI gates
     on detected==injected, wrong_verdicts==0, recovery within bound,
     and the restore/replay flags."""
-    env = dict(os.environ, PYTHONPATH="src")
-    env.pop("XLA_FLAGS", None)            # probe forces its own count
+    env = _probe_env()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
         [sys.executable, "benchmarks/chaos_probe.py", "--bench"],
@@ -460,8 +466,7 @@ def _sharded_refresh_case(width: int) -> dict:
     bit-identity and prints one JSON object.  Host-mesh wall clock
     measures collective overhead, not accelerator scaling — the
     structural columns (per-shard lanes/bytes) are what transfers."""
-    env = dict(os.environ, PYTHONPATH="src")
-    env.pop("XLA_FLAGS", None)            # probe forces its own count
+    env = _probe_env()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
         [sys.executable, "benchmarks/sharded_refresh_probe.py",

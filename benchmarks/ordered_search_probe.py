@@ -50,6 +50,7 @@ from repro.core import splaylist as sx                 # noqa: E402
 from repro.kernels import ops as kops                  # noqa: E402
 from repro.kernels import splay_search as ssk          # noqa: E402
 from repro.parallel import sharding as shd             # noqa: E402
+from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
 PAD, NEG = ssk.PAD_KEY, ssk.NEG_INF_KEY
 
@@ -195,7 +196,7 @@ def run_parity(width=512, n_levels=16, seed=0) -> None:
     print(f"  replicated == host oracle ({len(qs)} queries, "
           f"{len(lo)} ranges, {total} live keys)")
 
-    mesh = jax.make_mesh((1, N_DEV), ("data", "model"))
+    mesh = make_auto_mesh((1, N_DEV), ("data", "model"))
     pl_s = shd.shard_index_plane(plane, mesh)
     for split in ("lanes", "mass"):
         ps, ovf = dix.refresh_device_sharded(st, pl_s, mesh=mesh,
@@ -245,7 +246,7 @@ def run_bench(width=2048, nq=2048, max_range=64, reps=3,
     span = rng.integers(1, 4 * max_range, nq)
     hi = live[np.minimum(zipf + span, len(live) - 1)].astype(np.int32)
 
-    mesh = jax.make_mesh((1, N_DEV), ("data", "model"))
+    mesh = make_auto_mesh((1, N_DEV), ("data", "model"))
     pl_s = shd.shard_index_plane(plane, mesh)
     pl_s, ovf = dix.refresh_device_sharded(st, pl_s, mesh=mesh,
                                            split="mass")

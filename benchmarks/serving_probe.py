@@ -61,6 +61,7 @@ from repro.kernels import ops as kops                  # noqa: E402
 from repro.models import model_zoo as zoo              # noqa: E402
 from repro.serve.engine import Engine, Request         # noqa: E402
 from repro.serve.kv_cache import PagedKVPool           # noqa: E402
+from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
 SPILL_OK = 0.01
 ARCH = "qwen2-0.5b"
@@ -69,7 +70,7 @@ ARCH = "qwen2-0.5b"
 def _mesh():
     assert len(jax.devices()) >= N_DEV, \
         f"forced host mesh absent: {len(jax.devices())} device(s)"
-    return jax.make_mesh((1, N_DEV), ("data", "model"))
+    return make_auto_mesh((1, N_DEV), ("data", "model"))
 
 
 def _replay_trace(pool: PagedKVPool, trace: wl.KVTrace,

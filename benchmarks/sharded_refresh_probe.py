@@ -46,6 +46,7 @@ from repro.core import level_arrays as la              # noqa: E402
 from repro.core import splaylist as sx                 # noqa: E402
 from repro.kernels import ops as kops                  # noqa: E402
 from repro.parallel import sharding as shd             # noqa: E402
+from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
 CMP_FIELDS = ("keys", "widths", "heights", "rank_map")
 
@@ -93,7 +94,7 @@ def run_parity() -> None:
     print(f"sharded refresh parity: mode={kops.exec_mode()}")
     pool = list(range(0, 160, 2))
     for S in (1, 2, 4):
-        mesh = jax.make_mesh((1, S), ("data", "model"))
+        mesh = make_auto_mesh((1, S), ("data", "model"))
         st = _seed_state(pool)
         pr = dix.from_state_device(st, n_levels=L, width=W)
         ps = shd.shard_index_plane(pr, mesh)
@@ -110,7 +111,7 @@ def run_parity() -> None:
         print(f"parity S={S}: 8 mixed epochs OK "
               f"(w_bot={int(np.asarray(pr.widths)[-1])})")
 
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_auto_mesh((1, 4), ("data", "model"))
 
     # overflow burst: both paths count the same drops, identical planes
     st = _seed_state(list(range(0, 100, 2)))
@@ -207,7 +208,7 @@ def run_bench(width: int = 4096, churn: int = 64, epochs: int = 4,
               reps: int = 4) -> dict:
     """Membership-changing epoch stream, sharded (1x4 host mesh) vs
     replicated refresh; asserts bit-identity on the final plane."""
-    mesh = jax.make_mesh((1, N_DEV), ("data", "model"))
+    mesh = make_auto_mesh((1, N_DEV), ("data", "model"))
     rng = np.random.default_rng(7)
     n_levels, hmax = 6, 5
     n0 = int(width * 0.9)

@@ -67,6 +67,7 @@ from repro.core import splaylist as sx                 # noqa: E402
 from repro.kernels import ops as kops                  # noqa: E402
 from repro.kernels import splay_search as ssk          # noqa: E402
 from repro.parallel import sharding as shd             # noqa: E402
+from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
 CMP_FIELDS = ("keys", "widths", "heights", "rank_map")
 
@@ -168,7 +169,7 @@ def run_parity() -> None:
     rng0 = np.random.default_rng(0)
 
     for S in (1, 2, 4):
-        mesh = jax.make_mesh((1, S), ("data", "model"))
+        mesh = make_auto_mesh((1, S), ("data", "model"))
         # skewed heights: the tall (hot) keys cluster at the low end of
         # the keyspace, so upper rows live almost entirely in shard 0's
         # key range — queries owned by later shards then carry rank
@@ -220,7 +221,7 @@ def run_parity() -> None:
         print(f"parity S={S}: dispatch seam + boundary windows + "
               f"forced spill + single-owner + 6 churn epochs OK")
 
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_auto_mesh((1, 4), ("data", "model"))
 
     # mass-weighted re-split epochs (§5.6): hammer a hot set so the hit
     # counters skew, re-split every epoch, and check the segmented
@@ -426,7 +427,7 @@ def run_bench(width: int = 4096, nq: int = 4096, reps: int = 4,
     all_to_all exchange and the §5.6 routing-balance/mass-split columns
     are emitted."""
     from repro.core import workload as wl
-    mesh = jax.make_mesh((1, N_DEV), ("data", "model"))
+    mesh = make_auto_mesh((1, N_DEV), ("data", "model"))
     n_levels = 8
     n_keys = int(width * 0.75)
     keys, heights, qs = wl.zipf_level_fixture(n_keys, 1.0, nq, seed=3)

@@ -29,6 +29,7 @@ import jax.numpy as jnp                                 # noqa: E402
 from repro.configs import registry                      # noqa: E402
 from repro.models import model_zoo as zoo               # noqa: E402
 from repro.serve.engine import Engine, Request          # noqa: E402
+from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
 
 def engine_demo():
@@ -75,7 +76,7 @@ def routed_sharded_serving_demo():
         st, jnp.full((len(pool),), sx.OP_INSERT, jnp.int32),
         jnp.asarray(pool), jnp.ones((len(pool),), bool))
 
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    mesh = make_auto_mesh((1, n_dev), ("data", "model"))
     plane = dix.from_state_device(st, n_levels=L, width=W)
     plane_s = shd.shard_index_plane(plane, mesh)
     # plane fsck (DESIGN.md §5.11) at each refresh boundary: the
@@ -164,7 +165,7 @@ def controlled_serving_demo():
         st, jnp.full((len(drift.populate),), sx.OP_INSERT, jnp.int32),
         jnp.asarray(drift.populate), jnp.ones((len(drift.populate),),
                                               bool))
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    mesh = make_auto_mesh((1, n_dev), ("data", "model"))
     plane_s = shd.shard_index_plane(
         dix.from_state_device(st, n_levels=L, width=W), mesh)
 

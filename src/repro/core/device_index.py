@@ -229,8 +229,8 @@ def from_state_device(st: sx.SplayState, n_levels: int,
     beyond ``width`` truncate (largest keys) — undetectable here, but
     counted by the refresh paths' ``overflow_count``."""
     keys, rel_h = _alive_slots(st)
-    slot_ids = jnp.arange(st.capacity, dtype=jnp.int32)
-    ks, hs, sl = jax.lax.sort((keys, rel_h, slot_ids), num_keys=1)
+    sl = sx.key_order(keys, keys != PAD_KEY)
+    ks, hs = keys[sl], rel_h[sl]
     if st.capacity < width:                # small states pad out
         pad = width - st.capacity
         ks = jnp.pad(ks, (0, pad), constant_values=PAD_KEY)
@@ -373,11 +373,11 @@ def refresh_device(st: sx.SplayState, prev: DeviceLevelArrays,
     n_new = jnp.minimum(n_new_raw, kk)
 
     def extract_new(_):
-        neg = jnp.where(is_new, -k_slot, -jnp.int32(PAD_KEY))
-        vals, new_slots = jax.lax.top_k(neg, kk)
-        ns = jnp.where(jnp.arange(kk) < n_new, -vals, PAD_KEY)
+        new_slots = sx.key_order(k_slot, is_new)[:kk]
+        ns = jnp.where(jnp.arange(kk) < n_new,
+                       jnp.take(k_slot, new_slots), PAD_KEY)
         new_h = (jnp.take(st.top, new_slots) - st.zl).astype(jnp.int32)
-        return ns, new_h, new_slots.astype(jnp.int32)
+        return ns, new_h, new_slots
 
     def no_new(_):
         z = jnp.zeros((kk,), jnp.int32)
@@ -517,11 +517,11 @@ def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
     n_new = jnp.clip(kk - left, 0, jnp.minimum(raw, kk))
 
     def extract_new(_):
-        neg = jnp.where(is_new, -k_slot, -jnp.int32(PAD_KEY))
-        vals, new_slots = jax.lax.top_k(neg, kk)
-        ns = jnp.where(jnp.arange(kk) < n_new, -vals, PAD_KEY)
+        new_slots = sx.key_order(k_slot, is_new)[:kk]
+        ns = jnp.where(jnp.arange(kk) < n_new,
+                       jnp.take(k_slot, new_slots), PAD_KEY)
         new_h = (jnp.take(st.top, new_slots) - st.zl).astype(jnp.int32)
-        return ns, new_h, new_slots.astype(jnp.int32)
+        return ns, new_h, new_slots
 
     def no_new(_):
         z = jnp.zeros((kk,), jnp.int32)
@@ -689,9 +689,8 @@ def _sharded_refresh_fn(mesh, axis: str, n_levels: int, width: int,
     body = functools.partial(
         _refresh_shard_body, axis=axis, n_shards=S, n_levels=n_levels,
         width=width, max_new=max_new, split=split)
-    fn = shd.shard_map_compat(body, mesh=mesh,
-                              in_specs=(P(), specs),
-                              out_specs=(specs, P()))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), specs),
+                       out_specs=(specs, P()), check_vma=False)
     return jax.jit(fn)
 
 

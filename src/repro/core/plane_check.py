@@ -192,7 +192,7 @@ def _audit_device(st: sx.SplayState, plane: dix.DeviceLevelArrays,
 
     # -- state <-> plane membership agreement ----------------------------
     skeys, _ = dix._alive_slots(st)
-    sk = jnp.sort(skeys)                            # live prefix, PAD tail
+    sk = sx.sort_values(skeys)                      # live prefix, PAD tail
     cs = jnp.cumsum(bot_live.astype(jnp.int32))
     n_plane = cs[W - 1]
     take = dix._compact_take(cs, W)

@@ -133,9 +133,21 @@ def _get_hits(st: SplayState, i, h):
     return st.selfhits[i] + _whits(st, i, h)
 
 
-def _fill_down(st: SplayState, i, h) -> SplayState:
-    """Materialize slot i's levels down to h (vectorized updateZeroLevel)."""
+def _masked_set(x, idx, val, do):
+    """``x.at[idx].set(val)`` where ``do`` holds, else ``x`` unchanged.
+
+    The update phase is written with these element-wise selects instead
+    of ``lax.cond`` over the state: XLA moves the whole carried state
+    (two ``[L+1, C]`` arrays) through every conditional, which on the TPU
+    costs milliseconds per operation at ``C ~ 10^5``."""
+    return x.at[idx].set(jnp.where(do, val, x[idx]))
+
+
+def _fill_down(st: SplayState, i, h, do=True) -> SplayState:
+    """Materialize slot i's levels down to h (vectorized updateZeroLevel);
+    a no-op where ``do`` is false."""
     zl_i = st.nzero[i]
+    h = jnp.where(do, h, zl_i)
     lvls = jnp.arange(st.nxt.shape[0])
     mask = (lvls >= h) & (lvls < zl_i)
     col_nxt = jnp.where(mask, st.nxt[zl_i, i], st.nxt[:, i])
@@ -159,6 +171,13 @@ def find(st: SplayState, k) -> Tuple[jax.Array, jax.Array]:
     """Return (slot, steps): slot of the node with key k if physically
     present else -1. Counts horizontal moves + level descents (the paper's
     'average length of a path' metric)."""
+    slot, steps, _ = _find(st, k)
+    return slot, steps
+
+
+def _find(st: SplayState, k):
+    """:func:`find` plus where its walk ended: for an absent key, the
+    bottom-level predecessor an insert links the new node behind."""
     ml1 = st.max_level - 1
 
     def cond(c):
@@ -181,7 +200,7 @@ def find(st: SplayState, k) -> Tuple[jax.Array, jax.Array]:
     # found can also become true exactly at loop exit (descended past bottom)
     found = found | (st.key[pred] == k)
     slot = jnp.where(found & (pred != HEAD), pred, -1)
-    return slot.astype(jnp.int32), steps
+    return slot.astype(jnp.int32), steps, pred
 
 
 def find_batch(st: SplayState, ks) -> Tuple[jax.Array, jax.Array]:
@@ -193,7 +212,7 @@ def find_batch(st: SplayState, ks) -> Tuple[jax.Array, jax.Array]:
 # the forward-pass update (counters + ascent/descent), Section 5
 # ---------------------------------------------------------------------------
 
-def _update(st: SplayState, k, w=None) -> SplayState:
+def _update(st: SplayState, k, w=None, active=True) -> SplayState:
     """Forward-pass rebalance for a physically-present key k.
 
     ``w`` is the hit weight (default 1): the batched-update aggregation
@@ -201,21 +220,26 @@ def _update(st: SplayState, k, w=None) -> SplayState:
     hit-operations into ONE traversal by adding ``w`` everywhere the
     unit pass adds 1 (m, the parent subtree counters, selfhits).  The
     ascent/descent checks then see the epoch-final counters — the
-    flat-combining analogue of the paper's combined update phase."""
+    flat-combining analogue of the paper's combined update phase.
+
+    ``active`` (traced bool) gates the whole pass: false leaves the
+    state untouched.  Every branch of the pass is a masked element
+    update (:func:`_masked_set`), never a ``lax.cond`` over the state."""
     L = st.max_level
     ml1 = L - 1
     one = jnp.ones((), st.m.dtype) if w is None else w.astype(st.m.dtype)
-    st = st._replace(m=st.m + one)
+    zero = jnp.zeros((), st.m.dtype)
+    st = st._replace(m=st.m + jnp.where(active, one, zero))
     curr_m = st.m
 
     def asc_sum(s, pp, curh):
         return _whits(s, pp, curh + 1) - _whits(s, pp, curh)
 
-    def promote_cascade(s: SplayState, curr, pp):
+    def promote_cascade(s: SplayState, curr, pp, act):
         """Promote curr up while the ascent condition holds."""
         def cond(c):
             s, curh, _ = c
-            ok = (curh + 1 < L) & (curh < s.top[pp])
+            ok = act & (curh + 1 < L) & (curh < s.top[pp])
             thr = _shift(curr_m, ml1 - curh - 1)
             return ok & (asc_sum(s, pp, curh) > thr)
 
@@ -236,82 +260,64 @@ def _update(st: SplayState, k, w=None) -> SplayState:
             cond, body, (s, s.top[curr], False))
         return s, promoted
 
-    def demote(s: SplayState, curr, pred, h):
-        s = s._replace(zl=jnp.where(h == s.zl, s.zl - 1, s.zl))
-        s = _fill_down(s, curr, h - 1)
-        s = _fill_down(s, pred, h - 1)
+    def demote(s: SplayState, curr, pred, h, do):
+        s = s._replace(zl=jnp.where(do & (h == s.zl), s.zl - 1, s.zl))
+        s = _fill_down(s, curr, h - 1, do)
+        s = _fill_down(s, pred, h - 1, do)
         gh_curr = s.selfhits[curr] + s.hits[h, curr]
-        s = s._replace(
-            hits=s.hits.at[h, pred].add(gh_curr).at[h, curr].set(0))
-        s = s._replace(
-            nxt=s.nxt.at[h, pred].set(s.nxt[h, curr]).at[h, curr].set(-1),
-            top=s.top.at[curr].set(h - 1))
-        return s
+        hits = s.hits.at[h, pred].add(jnp.where(do, gh_curr, zero))
+        nxt = _masked_set(s.nxt, (h, pred), s.nxt[h, curr], do)
+        return s._replace(
+            hits=_masked_set(hits, (h, curr), 0, do),
+            nxt=_masked_set(nxt, (h, curr), -1, do),
+            top=_masked_set(s.top, curr, h - 1, do))
 
     def body(c):
         s, h, pred, pp, found, done, scanned = c
         curr = _eff_next(s, pred, h)
         gt = s.key[curr] > k
 
-        # ---- branch A: end of scan at this level -------------------------
+        # ---- branch A (gt): end of scan at this level --------------------
         # Two sub-cases, mirroring the oracle's control flow exactly:
         #   * level entry (nothing scanned yet): pred is the parent of k at
         #     this level -> increment its subtree counter;
         #   * scan exit (something scanned): the parent was already counted
         #     inside the scan via is_parent -> descend with no increment.
-        def branch_a(s):
-            def incr(s):
-                s = _fill_down(s, pred, h)
-                s = s._replace(hits=s.hits.at[h, pred].add(one))
-                return s
-            s = jax.lax.cond(found | scanned, lambda s: s, incr, s)
-            return s, h - 1, pred, pred, found, found, jnp.array(False)
+        incr = gt & ~(found | scanned)
+        s = _fill_down(s, pred, h, incr)
+        s = s._replace(hits=s.hits.at[h, pred].add(jnp.where(incr, one, zero)))
 
-        # ---- branch B: process curr --------------------------------------
-        def branch_b(s):
-            nxt_key = s.key[_eff_next(s, curr, h)]
-            is_parent = nxt_key > k
-            is_target = s.key[curr] == k
+        # ---- branch B (~gt): process curr --------------------------------
+        b = ~gt
+        is_parent = s.key[_eff_next(s, curr, h)] > k
+        is_target = s.key[curr] == k
+        hit_self = b & is_parent & is_target
+        hit_sub = b & is_parent & ~is_target
+        s = s._replace(selfhits=s.selfhits.at[curr].add(
+            jnp.where(hit_self, one, zero)))
+        s = _fill_down(s, curr, h, hit_sub)
+        s = s._replace(hits=s.hits.at[h, curr].add(
+            jnp.where(hit_sub, one, zero)))
 
-            def hit_self(s):
-                return s._replace(selfhits=s.selfhits.at[curr].add(one))
+        s, promoted = promote_cascade(s, curr, pp, b)
+        nk = s.key[_eff_next(s, curr, h)]
+        thr = _shift(curr_m, ml1 - h)
+        desc = (b & ~promoted & (s.top[curr] == h) & (nk <= k) &
+                (_get_hits(s, curr, h) + _get_hits(s, pred, h) <= thr))
+        s = demote(s, curr, pred, h, desc)
 
-            def hit_sub(s):
-                s = _fill_down(s, curr, h)
-                return s._replace(hits=s.hits.at[h, curr].add(one))
-
-            s = jax.lax.cond(is_parent & is_target, hit_self, lambda s: s, s)
-            s = jax.lax.cond(is_parent & ~is_target, hit_sub, lambda s: s, s)
-            new_found = found | (is_parent & is_target)
-
-            s, promoted = promote_cascade(s, curr, pp)
-
-            def after_promo(s):
-                return s, h, curr, curr, new_found, jnp.array(False), \
-                    jnp.array(True)
-
-            def after_no_promo(s):
-                nk = s.key[_eff_next(s, curr, h)]
-                thr = _shift(curr_m, ml1 - h)
-                desc = ((s.top[curr] == h) & (nk <= k) &
-                        (_get_hits(s, curr, h) + _get_hits(s, pred, h) <= thr))
-                s = jax.lax.cond(
-                    desc, lambda s: demote(s, curr, pred, h), lambda s: s, s)
-                pred2 = jnp.where(desc, pred, curr)
-                return s, h, pred2, pp, new_found, jnp.array(False), \
-                    jnp.array(True)
-
-            return jax.lax.cond(promoted, after_promo, after_no_promo, s)
-
-        return jax.lax.cond(gt, branch_a, branch_b, s)
+        return (s, jnp.where(gt, h - 1, h),
+                jnp.where(gt | desc, pred, curr),
+                jnp.where(gt, pred, jnp.where(promoted, curr, pp)),
+                found | hit_self, gt & found, b)
 
     def cond(c):
         s, h, pred, pp, found, done, scanned = c
         return (~done) & (h >= s.zl)
 
     init = (st, jnp.array(ml1, jnp.int32), jnp.array(HEAD, jnp.int32),
-            jnp.array(HEAD, jnp.int32), jnp.array(False), jnp.array(False),
-            jnp.array(False))
+            jnp.array(HEAD, jnp.int32), jnp.array(False),
+            ~jnp.asarray(active), jnp.array(False))
     st, *_ = jax.lax.while_loop(cond, body, init)
     return st
 
@@ -320,111 +326,27 @@ def _update(st: SplayState, k, w=None) -> SplayState:
 # physical insert at the bottom level
 # ---------------------------------------------------------------------------
 
-def _link_bottom(st: SplayState, k) -> SplayState:
+def _link_bottom(st: SplayState, k, pred, do=True) -> SplayState:
+    """Link a new node for ``k`` behind ``pred`` at the bottom level
+    (``pred`` from :func:`_find`'s walk for the absent key)."""
     zl = st.zl
-    ml1 = st.max_level - 1
-
-    def cond(c):
-        pred, h = c
-        return h >= zl
-
-    def body(c):
-        pred, h = c
-        curr = _eff_next(st, pred, h)
-        adv = st.key[curr] <= k
-        return jnp.where(adv, curr, pred), jnp.where(adv, h, h - 1)
-
-    pred, _ = jax.lax.while_loop(
-        cond, body, (jnp.array(HEAD, jnp.int32), jnp.array(ml1, jnp.int32)))
-    st = _fill_down(st, pred, zl)
+    st = _fill_down(st, pred, zl, do)
     j = st.n_alloc
-    st = st._replace(
-        key=st.key.at[j].set(k.astype(st.key.dtype)),
-        nxt=st.nxt.at[zl, j].set(st.nxt[zl, pred]).at[zl, pred].set(j),
-        top=st.top.at[j].set(zl),
-        nzero=st.nzero.at[j].set(zl),
-        selfhits=st.selfhits.at[j].set(0),
-        deleted=st.deleted.at[j].set(False),
-        n_alloc=st.n_alloc + 1)
-    return st
+    nxt = _masked_set(st.nxt, (zl, j), st.nxt[zl, pred], do)
+    return st._replace(
+        key=_masked_set(st.key, j, k.astype(st.key.dtype), do),
+        nxt=_masked_set(nxt, (zl, pred), j, do),
+        top=_masked_set(st.top, j, zl, do),
+        nzero=_masked_set(st.nzero, j, zl, do),
+        selfhits=_masked_set(st.selfhits, j, 0, do),
+        deleted=_masked_set(st.deleted, j, False, do),
+        n_alloc=st.n_alloc + jnp.where(do, 1, 0).astype(jnp.int32))
 
 
 # ---------------------------------------------------------------------------
 # public operations.  `upd` is the pre-sampled Bernoulli(p) coin for the
 # relaxed rebalancing of Section 4 (pass True for the exact algorithm).
 # ---------------------------------------------------------------------------
-
-def contains(st: SplayState, k, upd) -> Tuple[SplayState, jax.Array, jax.Array]:
-    slot, steps = find(st, k)
-    present = slot >= 0
-    live = present & ~st.deleted[jnp.maximum(slot, 0)]
-    one = jnp.ones((), st.m.dtype)
-
-    def do_upd(s):
-        s = _update(s, k)
-        # hit on a marked node counts toward deleted hits
-        s = s._replace(dhits=jnp.where(present & ~live, s.dhits + one, s.dhits))
-        return s
-
-    st = jax.lax.cond(present & upd, do_upd, lambda s: s, st)
-    st = _maybe_rebuild(st)
-    return st, live, steps
-
-
-def insert(st: SplayState, k, upd) -> Tuple[SplayState, jax.Array, jax.Array]:
-    slot, steps = find(st, k)
-    present = slot >= 0
-    slot_c = jnp.maximum(slot, 0)
-    marked = present & st.deleted[slot_c]
-
-    def case_revive(s):  # unmark + unconditional rebalance
-        s = s._replace(
-            deleted=s.deleted.at[slot_c].set(False),
-            dhits=s.dhits - s.selfhits[slot_c],
-            size=s.size + 1)
-        return _update(s, k)
-
-    def case_exists(s):  # unsuccessful insert: relaxed visit
-        return jax.lax.cond(upd, lambda x: _update(x, k), lambda x: x, s)
-
-    def case_new(s):
-        s = _link_bottom(s, k)
-        s = s._replace(size=s.size + 1)
-        return _update(s, k)
-
-    st = jax.lax.cond(
-        marked, case_revive,
-        lambda s: jax.lax.cond(present, case_exists, case_new, s), st)
-    return st, ~present | marked, steps
-
-
-def delete(st: SplayState, k, upd) -> Tuple[SplayState, jax.Array, jax.Array]:
-    slot, steps = find(st, k)
-    present = slot >= 0
-    slot_c = jnp.maximum(slot, 0)
-    marked = present & st.deleted[slot_c]
-    success = present & ~marked
-    one = jnp.ones((), st.m.dtype)
-
-    def case_success(s):
-        s = s._replace(deleted=s.deleted.at[slot_c].set(True),
-                       size=s.size - 1)
-        s = _update(s, k)
-        s = s._replace(dhits=s.dhits + s.selfhits[slot_c])
-        return s
-
-    def case_marked(s):  # unsuccessful delete on marked node: relaxed visit
-        def u(x):
-            x = _update(x, k)
-            return x._replace(dhits=x.dhits + one)
-        return jax.lax.cond(upd, u, lambda x: x, s)
-
-    st = jax.lax.cond(
-        success, case_success,
-        lambda s: jax.lax.cond(marked, case_marked, lambda x: x, s), st)
-    st = _maybe_rebuild(st)
-    return st, success, steps
-
 
 def _live_mask(st: SplayState) -> jax.Array:
     """bool [C]: the slots whose keys the ordered queries (and the index
@@ -433,6 +355,79 @@ def _live_mask(st: SplayState) -> jax.Array:
     idx = jnp.arange(st.capacity)
     return ((idx >= 2) & (idx < st.n_alloc) & (~st.deleted)
             & (st.key < POS_INF_32))
+
+
+def _pred_key(st: SplayState, k) -> jax.Array:
+    """The largest live key ``<= k`` (``NEG_INF_32`` when none)."""
+    mask = _live_mask(st) & (st.key <= k)
+    return jnp.max(jnp.where(mask, st.key, NEG_INF_32)).astype(jnp.int32)
+
+
+def _prefix_count(st: SplayState, k) -> jax.Array:
+    """``|{live k' : k' <= k}|``."""
+    return jnp.sum((_live_mask(st) & (st.key <= k)).astype(jnp.int32))
+
+
+def _apply_op(st: SplayState, kind, k, upd) -> Tuple[SplayState,
+                                                      jax.Array, jax.Array]:
+    """One operation of any kind (traced ``kind``), without the rebuild
+    check: (state, int32 result, path_len).  The set operations are
+    masked updates around one :func:`_update` pass, so one program with
+    no conditional over the state serves every kind:
+
+    * contains: rebalance a present key when the coin ``upd`` says so
+      (a hit on a marked node counts toward the deleted hits);
+    * insert: revive a marked node, or link a new one at the bottom
+      (both rebalance unconditionally), or visit an existing one;
+    * delete: mark a live node (rebalance, then its selfhits join the
+      deleted hits), or visit a marked one;
+    * ``OP_PRED`` / ``OP_RANGE``: pure reads of the live key set."""
+    slot, steps, pred = _find(st, k)
+    present = slot >= 0
+    slot_c = jnp.maximum(slot, 0)
+    marked = present & st.deleted[slot_c]
+    live = present & ~marked
+    one = jnp.ones((), st.m.dtype)
+    zero = jnp.zeros((), st.m.dtype)
+    is_c, is_i, is_d = (kind == OP_CONTAINS, kind == OP_INSERT,
+                        kind == OP_DELETE)
+    revive, new, gone = is_i & marked, is_i & ~present, is_d & live
+
+    st = st._replace(
+        deleted=_masked_set(st.deleted, slot_c, gone, revive | gone),
+        dhits=st.dhits - jnp.where(revive, st.selfhits[slot_c], zero),
+        size=st.size + (revive | new).astype(jnp.int32)
+        - gone.astype(jnp.int32))
+    st = _link_bottom(st, k, pred, new)
+    st = _update(st, k, active=(is_i & (upd | ~present | marked)) | gone
+                 | ((is_c | is_d) & present & upd))
+    st = st._replace(dhits=st.dhits + jnp.where(
+        gone, st.selfhits[slot_c],
+        jnp.where((is_c | is_d) & marked & upd, one, zero)))
+
+    res = jnp.where(is_c, live, jnp.where(is_i, ~present | marked, gone))
+    res = jax.lax.switch(jnp.maximum(kind - OP_DELETE, 0), [
+        lambda: res.astype(jnp.int32),
+        lambda: _pred_key(st, k),
+        lambda: _prefix_count(st, k)])
+    return st, res, steps
+
+
+def contains(st: SplayState, k, upd) -> Tuple[SplayState, jax.Array,
+                                               jax.Array]:
+    st, live, steps = _apply_op(st, OP_CONTAINS, k, upd)
+    return _maybe_rebuild(st), live.astype(bool), steps
+
+
+def insert(st: SplayState, k, upd) -> Tuple[SplayState, jax.Array, jax.Array]:
+    st, ok, steps = _apply_op(st, OP_INSERT, k, upd)
+    return st, ok.astype(bool), steps
+
+
+def delete(st: SplayState, k, upd) -> Tuple[SplayState, jax.Array,
+                                             jax.Array]:
+    st, ok, steps = _apply_op(st, OP_DELETE, k, upd)
+    return _maybe_rebuild(st), ok.astype(bool), steps
 
 
 def predecessor(st: SplayState, k, upd=None) -> Tuple[SplayState,
@@ -448,9 +443,7 @@ def predecessor(st: SplayState, k, upd=None) -> Tuple[SplayState,
     metric as ``contains``)."""
     del upd
     _, steps = find(st, k)
-    mask = _live_mask(st) & (st.key <= k)
-    res = jnp.max(jnp.where(mask, st.key, NEG_INF_32))
-    return st, res.astype(jnp.int32), steps
+    return st, _pred_key(st, k), steps
 
 
 def rank_count(st: SplayState, k, upd=None) -> Tuple[SplayState,
@@ -462,8 +455,7 @@ def rank_count(st: SplayState, k, upd=None) -> Tuple[SplayState,
     returns (state, count, path_len) like the other op branches."""
     del upd
     _, steps = find(st, k)
-    res = jnp.sum((_live_mask(st) & (st.key <= k)).astype(jnp.int32))
-    return st, res, steps
+    return st, _prefix_count(st, k), steps
 
 
 # ---------------------------------------------------------------------------
@@ -472,6 +464,35 @@ def rank_count(st: SplayState, k, upd=None) -> Tuple[SplayState,
 # (top-down) every segment whose hit total H satisfies bit_length(H)-1 == r
 # splits at its weighted median (the middle cell of the virtual array T).
 # ---------------------------------------------------------------------------
+
+def sort_values(x):
+    """``jnp.sort`` of a 1-D int32 vector, as one unstable single-operand
+    sort padded to a power of two with ``POS_INF_32`` (the result keeps
+    the first ``len(x)`` lanes).  The TPU compiler takes tens of seconds
+    over a stable, multi-operand or ``top_k`` sort of 10^5 lanes, and a
+    few over this one."""
+    c = x.shape[0]
+    p2 = 1 << max(c - 1, 1).bit_length()
+    return jax.lax.sort(jnp.pad(x.astype(jnp.int32), (0, p2 - c),
+                                constant_values=POS_INF_32),
+                        is_stable=False)[:c]
+
+
+def key_order(keys, alive):
+    """The slot order of a stable ``argsort(where(alive, keys, +INF))``
+    for distinct alive keys: alive slots by key, then the other slots
+    by index — from one :func:`sort_values`, a searchsorted and a
+    permutation scatter."""
+    c = keys.shape[0]
+    sk = jnp.where(alive, keys, POS_INF_32).astype(jnp.int32)
+    srt = sort_values(sk)
+    n = jnp.sum(alive.astype(jnp.int32))
+    rest = jnp.cumsum((~alive).astype(jnp.int32)) - 1
+    pos = jnp.where(alive, jnp.searchsorted(srt, sk).astype(jnp.int32),
+                    n + rest)
+    return jnp.zeros((c,), jnp.int32).at[pos].set(
+        jnp.arange(c, dtype=jnp.int32), unique_indices=True)
+
 
 def _maybe_rebuild(st: SplayState) -> SplayState:
     trig = (st.m > 0) & (2 * st.dhits >= st.m)
@@ -487,8 +508,7 @@ def rebuild(st: SplayState) -> SplayState:
     # gather alive nodes in key order
     is_node = (jnp.arange(C) >= 2) & (jnp.arange(C) < st.n_alloc)
     alive = is_node & ~st.deleted & (st.key < POS_INF_32)
-    sort_key = jnp.where(alive, st.key, POS_INF_32)
-    order = jnp.argsort(sort_key)                      # alive first, by key
+    order = key_order(st.key, alive)                   # alive first, by key
     keys_s = st.key[order]
     sh_s = jnp.where(alive[order], st.selfhits[order], 0)
     alive_s = alive[order]
@@ -519,16 +539,14 @@ def rebuild(st: SplayState) -> SplayState:
         # boundaries: positions already assigned height > r
         bnd = rel > r
         # segment start prefix value: max over j<=i of (bnd? pref[j] : 0)
-        start_w = jax.lax.associative_scan(
-            jnp.maximum, jnp.where(bnd, pref, jnp.zeros_like(pref)))
+        start_w = jax.lax.cummax(jnp.where(bnd, pref, jnp.zeros_like(pref)))
         # shift right: segment of i starts after the last boundary strictly
         # before i
         start_w = jnp.concatenate(
             [jnp.zeros((1,), cnt_dt), start_w[:-1]])
         # segment end prefix value: min over j>=i of (bnd? pref0[j] : M)
         end_base = jnp.where(bnd, pref0, jnp.full_like(pref0, big_m))
-        end_w = jax.lax.associative_scan(
-            jnp.minimum, end_base, reverse=True)
+        end_w = jax.lax.cummin(end_base, reverse=True)
         end_w = jnp.concatenate([end_w[1:], jnp.full((1,), big_m, cnt_dt)])
         seg_h = end_w - start_w
         fires = (~bnd) & alive_s & (rel < 0) & (
@@ -544,66 +562,54 @@ def rebuild(st: SplayState) -> SplayState:
     rel = jnp.where(alive_s, jnp.maximum(rel, 0), -1)
     top_new = jnp.where(alive_s, zl_new + rel, 0)
 
-    # fresh layout: alive nodes occupy slots 2..2+n in key order
-    slot_of_pos = jnp.where(alive_s, idx + 2, 0).astype(jnp.int32)
+    # fresh layout: the alive nodes are a key-ordered prefix of the sorted
+    # positions (at most C - 2 of them), and position p lands in slot
+    # p + 2 — so every per-slot array is its per-position array shifted
+    # right by two lanes behind the HEAD and TAIL columns.  Written as
+    # shifts, not scatters or gathers: the TPU compiler takes many
+    # minutes over [L+1, C] gathers indexed by a scan at C ~ 10^5.
+    def by_slot(head, tail, rows, fill):
+        rows = jnp.where(alive_s, rows, fill)
+        lead = jnp.stack([jnp.broadcast_to(head, rows.shape[:-1]),
+                          jnp.broadcast_to(tail, rows.shape[:-1])], -1)
+        return jnp.concatenate([lead.astype(rows.dtype), rows[..., :C - 2]],
+                               axis=-1)
 
-    # dead writes routed out of bounds and dropped
-    dst = jnp.where(alive_s, slot_of_pos, C).astype(jnp.int32)
-
-    new_key = jnp.full((C,), POS_INF_32, st.key.dtype)
-    new_key = new_key.at[HEAD].set(NEG_INF_32)
-    new_key = new_key.at[dst].set(keys_s, mode="drop")
-
-    new_sh = jnp.zeros((C,), cnt_dt)
-    new_sh = new_sh.at[dst].set(sh_s, mode="drop")
-    new_sh = new_sh.at[HEAD].set(1).at[TAIL].set(1)
-
-    new_top = jnp.zeros((C,), jnp.int32)
-    new_top = new_top.at[dst].set(top_new, mode="drop")
-    new_top = new_top.at[HEAD].set(L).at[TAIL].set(L)
-
-    new_nzero = jnp.full((C,), L, jnp.int32)
-    new_nzero = new_nzero.at[dst].set(
-        jnp.full((C,), 1, jnp.int32) * zl_new, mode="drop")
-    new_nzero = new_nzero.at[HEAD].set(zl_new).at[TAIL].set(L)
+    new_key = by_slot(NEG_INF_32, POS_INF_32, keys_s.astype(st.key.dtype),
+                      POS_INF_32)
+    new_sh = by_slot(1, 1, sh_s.astype(cnt_dt), 0)
+    new_top = by_slot(L, L, top_new.astype(jnp.int32), 0)
+    new_nzero = by_slot(zl_new, L, jnp.broadcast_to(zl_new, (C,)), L)
 
     # per-level links + interval-sum hit counters
     lvls = jnp.arange(L + 1, dtype=jnp.int32)[:, None]          # [L+1, 1]
     at_lvl = alive_s[None, :] & (top_new[None, :] >= lvls)      # [L+1, C]
-    # next alive position at this level, scanning right-to-left
-    pos_or_inf = jnp.where(at_lvl, idx[None, :], C + 7)
-    nxt_pos = jax.lax.associative_scan(
-        jnp.minimum, pos_or_inf, reverse=True, axis=1)
+    # next alive position at this level and its exclusive hit prefix,
+    # scanning right-to-left (pref0 is non-decreasing, so the min over
+    # the later positions is the nearest one's)
+    nxt_pos = jax.lax.cummin(jnp.where(at_lvl, idx[None, :], C + 7),
+                             axis=1, reverse=True)
+    nxt_pref0 = jax.lax.cummin(jnp.where(at_lvl, pref0[None, :], big_m),
+                               axis=1, reverse=True)
     nxt_pos_excl = jnp.concatenate(
         [nxt_pos[:, 1:], jnp.full((L + 1, 1), C + 7)], axis=1)
     # successor slot (tail if none)
-    succ_slot = jnp.where(
-        nxt_pos_excl <= C - 1,
-        jnp.take(slot_of_pos, jnp.minimum(nxt_pos_excl, C - 1)),
-        TAIL).astype(jnp.int32)
+    succ_slot = jnp.where(nxt_pos_excl <= C - 1, nxt_pos_excl + 2,
+                          TAIL).astype(jnp.int32)
     # interval sum (this, succ): pref0[succ_pos] - pref[this]
-    succ_pref0 = jnp.where(
-        nxt_pos_excl <= C - 1,
-        jnp.take(pref0, jnp.minimum(nxt_pos_excl, C - 1)), big_m)
+    succ_pref0 = jnp.concatenate(
+        [nxt_pref0[:, 1:], jnp.full((L + 1, 1), big_m, cnt_dt)], axis=1)
     seg_hits = (succ_pref0 - pref[None, :]).astype(cnt_dt)
 
     write_mask = at_lvl & (lvls >= zl_new)
-    dst2 = jnp.where(write_mask, slot_of_pos[None, :], C).astype(jnp.int32)
-    lvl_idx = jnp.broadcast_to(lvls, (L + 1, C))
-    new_nxt = jnp.full((L + 1, C), -1, jnp.int32)
-    new_nxt = new_nxt.at[lvl_idx, dst2].set(succ_slot, mode="drop")
-    new_hits = jnp.zeros((L + 1, C), cnt_dt)
-    new_hits = new_hits.at[lvl_idx, dst2].set(seg_hits, mode="drop")
+    new_nxt = by_slot(-1, -1, jnp.where(write_mask, succ_slot, -1), -1)
+    new_hits = by_slot(0, 0, jnp.where(write_mask, seg_hits, 0), 0)
 
     # head links: first alive position at each level (or tail)
     first_pos = nxt_pos[:, 0]
-    head_succ = jnp.where(
-        first_pos <= C - 1,
-        jnp.take(slot_of_pos, jnp.minimum(first_pos, C - 1)),
-        TAIL).astype(jnp.int32)
-    head_hits = jnp.where(
-        first_pos <= C - 1,
-        jnp.take(pref0, jnp.minimum(first_pos, C - 1)), big_m).astype(cnt_dt)
+    head_succ = jnp.where(first_pos <= C - 1, first_pos + 2,
+                          TAIL).astype(jnp.int32)
+    head_hits = nxt_pref0[:, 0].astype(cnt_dt)
     head_lvl_mask = (lvls[:, 0] >= zl_new) & (lvls[:, 0] <= ml1)
     new_nxt = new_nxt.at[:, HEAD].set(
         jnp.where(head_lvl_mask, head_succ, -1))
@@ -627,27 +633,16 @@ def rebuild(st: SplayState) -> SplayState:
 
 @jax.jit
 def run_ops(st: SplayState, kinds, keys, upd_mask):
-    """Apply a stream of operations (scan; lax.switch per op kind).
-    Returns final state plus per-op (result int32, path_len).  The
-    result lane carries the op's answer: 0/1 verdicts for
-    contains/insert/delete, the predecessor key for ``OP_PRED``, the
-    prefix-range count for ``OP_RANGE`` (see the op-kind constants)."""
+    """Apply a stream of operations (scan; one :func:`_apply_op` per op,
+    whatever its kind, then the rebuild check).  Returns final state
+    plus per-op (result int32, path_len).  The result lane carries the
+    op's answer: 0/1 verdicts for contains/insert/delete, the
+    predecessor key for ``OP_PRED``, the prefix-range count for
+    ``OP_RANGE`` (see the op-kind constants)."""
 
     def step(s, op):
-        kind, k, u = op
-
-        def as_i32(fn):
-            def run(a):
-                s_out, res, plen = fn(a[0], a[1], a[2])
-                return s_out, res.astype(jnp.int32), plen
-            return run
-
-        s_out, res, plen = jax.lax.switch(
-            kind,
-            [as_i32(contains), as_i32(insert), as_i32(delete),
-             as_i32(predecessor), as_i32(rank_count)],
-            (s, k, u))
-        return s_out, (res, plen)
+        s, res, plen = _apply_op(s, *op)
+        return _maybe_rebuild(s), (res, plen)
 
     st, (res, plen) = jax.lax.scan(step, st, (kinds, keys, upd_mask))
     return st, res, plen
@@ -733,13 +728,8 @@ def run_contains_batch(st: SplayState, keys, upd_mask,
 
         def agg_step(s, op):
             k, wk, wmk = op
-
-            def u(x):
-                x = _update(x, k, wk)
-                return x._replace(dhits=x.dhits + wmk)
-
-            s = jax.lax.cond(wk > 0, u, lambda x: x, s)
-            return s, ()
+            s = _update(s, k, wk, active=wk > 0)
+            return s._replace(dhits=s.dhits + wmk), ()
 
         st, _ = jax.lax.scan(agg_step, st, (uk, w, wm))
         st = _maybe_rebuild(st)
@@ -747,13 +737,9 @@ def run_contains_batch(st: SplayState, keys, upd_mask,
 
     def upd_step(s, op):
         k, do, pres, mk = op
-
-        def u(x):
-            x = _update(x, k)
-            return x._replace(dhits=jnp.where(mk, x.dhits + one, x.dhits))
-
-        s = jax.lax.cond(do & pres, u, lambda x: x, s)
-        return s, ()
+        s = _update(s, k, active=do & pres)
+        return s._replace(dhits=jnp.where(do & pres & mk, s.dhits + one,
+                                          s.dhits)), ()
 
     st, _ = jax.lax.scan(upd_step, st, (keys, upd_mask, present, marked))
     st = _maybe_rebuild(st)

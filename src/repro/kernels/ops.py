@@ -35,8 +35,8 @@ def splay_search(level_keys, queries, query_block: int = 256,
     precomputed rank_map/widths skip the on-the-fly window derivation.
     A concretely width-sharded plane dispatches to the sharded search
     (``sharded=None`` auto-detects; True/False force either path —
-    DESIGN.md §5.5).  ``pipelined=None`` picks the §5.8 windowed-DMA
-    kernel exactly when compiling (TPU); True/False force it."""
+    DESIGN.md §5.5).  ``pipelined=True`` runs the §5.8 windowed-DMA
+    kernel (interpret mode only); ``None``/``False`` the tiered one."""
     return ssk.splay_search(
         level_keys, queries, query_block=query_block,
         interpret=not on_tpu(), rank_map=rank_map, widths=widths,

@@ -6,23 +6,21 @@ compares against rows top-down (row 0 = hottest).
 
 Three kernels live here:
 
-``splay_search`` — the tiered pipeline (DESIGN.md §5.2).  Grid
-``(query_blocks, n_levels)``; the level matrix and the rank map are tiled
-*per row* (``pl.BlockSpec((1, width), ...)``), so one row of each operand
-(level row + rank-map row, plus the two [QB] window scratch vectors) is
-VMEM resident per grid step and the footprint is O(W) instead of
-O(L·W).  The row index_map goes through a scalar-prefetched fetch
-schedule that aliases statically-empty rows (padding above the tallest
-key) to the next live row — consecutive identical block indices suppress
-the duplicate DMA on the compiled (TPU) path; interpret mode computes
-the same schedule but models no DMA.  Within a row the full-width
-``row <= q`` compare is replaced by rank-windowed descent: the
-predecessor index ``p`` found at level r bounds the level-r+1
-predecessor inside ``[rank_map[r, p], rank_map[r, p + 1])`` (rows are
-nested), and a masked binary refinement locates it in O(log window)
-probes instead of O(W) compares.  The ``[lo, hi)`` window is carried
-across grid steps in VMEM scratch; ``found``/``level_found`` accumulate
-in revisited output blocks.
+``splay_search`` — the tiered pipeline (DESIGN.md §5.2), the descent
+that runs on the chip.  Grid ``(query_blocks, n_levels)``; the level
+matrix is tiled *per row*, viewed as ``[L, W/128, 128]`` (one row
+block plus its ``[1, W/128]`` chunk-first sample row VMEM resident per
+grid step: O(W) instead of O(L·W)).  The row index_map goes through a
+scalar-prefetched fetch schedule that aliases statically-empty rows
+(padding above the tallest key) to the next live row — consecutive
+identical block indices suppress the duplicate DMA on the compiled
+(TPU) path; interpret mode computes the same schedule but models no
+DMA.  Within a row the predecessor index is a two-stage count with no
+per-query gather (the TPU compiler lowers none from a ``[W]`` row): the
+queries are compared against the samples to pick their 128-lane chunk,
+a one-hot MXU product fetches that chunk, and a compare-and-count inside
+it gives the offset.  ``found``/``level_found`` accumulate in revisited
+output blocks.
 
 ``splay_search_pipelined`` — the foresight-pipelined descent (DESIGN.md
 §5.8): operands stay in HBM (``memory_space=ANY``) and the kernel
@@ -31,11 +29,12 @@ only the block's live ``[lo, hi)`` window union per level, launching
 the level-r+1 fetch before level-r's compute and suppressing every
 remaining row DMA once the whole block is resolved (membership hit, or
 a width-1 bottom-row window projection via the ``bot_rank`` companion).
-Bit-identical to the tiered kernel — which stays the interpret-mode
-oracle — while streaming O(window) instead of O(W) bytes per row, and
-0 bytes for rows below the block's resolution depth.  ``splay_search``
-takes ``pipelined=True/False/None`` (None: pipelined exactly when
-compiling) and the sharded paths thread the same flag through their
+Bit-identical to the tiered kernel while streaming O(window) instead
+of O(W) bytes per row, and 0 bytes for rows below the block's
+resolution depth.  Interpret mode only: its per-query probes are
+``[QB]``-from-``[W]`` gathers, which the TPU compiler refuses.
+``splay_search`` takes ``pipelined=True`` to run it (``None``/``False``:
+tiered) and the sharded paths thread the same flag through their
 per-shard descents.
 
 ``splay_search_full`` — the seed kernel, kept as the measured baseline:
@@ -265,66 +264,96 @@ def _fetch_schedule(widths, n_levels):
     return jax.lax.associative_scan(jnp.minimum, cand, reverse=True)
 
 
+def tiered_row_bytes(n_levels: int, width: int) -> int:
+    """Bytes the tiered descent streams per query block: every level's
+    key row, padded to whole 128-lane chunks (the sample rows add
+    1/128 of that and are left out)."""
+    return n_levels * (-(-width // CHUNK) * CHUNK) * 4
+
+
+def descent_kind(width: int, pipelined: bool = None) -> str:
+    """Which descent kernel :func:`splay_search` runs on a plane of this
+    width: ``"tiered"`` unless the pipelined descent is asked for
+    (``pipelined=True``, interpret mode only), which itself hands
+    widths past its tile budget to the tiered one."""
+    if not pipelined:
+        return "tiered"
+    if width // math.gcd(width, 256) > _MAX_PIPE_TILES:
+        return "tiered (pipelined width fallback)"
+    return "pipelined"
+
+
 # ---------------------------------------------------------------------------
-# tiered kernel: per-row streaming + rank-windowed descent
+# tiered kernel: per-row streaming + two-stage compare-and-count probe
 # ---------------------------------------------------------------------------
 
-def _kernel_tiered(fetch_ref, widths_ref, q_ref, row_ref, rm_ref,
-                   found_ref, rank_ref, level_ref, lo_ref, hi_ref, *,
-                   n_levels: int, width: int, n_steps: int):
+# Lanes per row chunk: a row of width W is viewed as [W/CHUNK, CHUNK]
+# (one vreg lane-width per chunk), and its chunk-first keys form the
+# [1, W/CHUNK] sample row of the coarse stage.
+CHUNK = 128
+
+
+def _kernel_tiered(fetch_ref, widths_ref, q_ref, samp_ref, row_ref,
+                   found_ref, rank_ref, level_ref, *, n_levels: int,
+                   n_chunks: int):
+    """One (query block, level) grid step.  The predecessor index of
+    each query in row r is a count, found without any per-query gather
+    (Mosaic lowers no ``[QB]``-from-``[W]`` gather):
+
+      1. coarse — compare the ``[QB, 1]`` queries against the row's
+         ``[1, W/128]`` chunk-first samples and count: ``c`` samples are
+         ``<= q``, so q's predecessor lies in chunk ``j = c - 1``;
+      2. chunk fetch — a one-hot ``[QB, W/128]`` matrix times the row's
+         four byte planes (``[W/128, 128]`` bf16 each, MXU, f32
+         accumulate — exact: one 0..255 term per output) reassembles
+         each query's 128-lane chunk;
+      3. fine — compare-and-count inside the chunk gives the offset.
+
+    Rows are sorted and +INF padded, so the count IS the binary
+    search's predecessor index (clamped to the live width, as the
+    window bound did), and q is in the row iff it is in its chunk."""
     del fetch_ref  # consumed by the index_maps only
     r = pl.program_id(1)
-    q = q_ref[...]                                     # [QB]
+    q = q_ref[...]                                     # [QB, 1]
     qb = q.shape[0]
+    w_r = widths_ref[r]
 
     @pl.when(r == 0)
     def _init():
-        found_ref[...] = jnp.zeros((qb,), jnp.bool_)
-        level_ref[...] = jnp.full((qb,), n_levels, jnp.int32)
-        rank_ref[...] = jnp.zeros((qb,), jnp.int32)
-        lo_ref[...] = jnp.full((qb,), -1, jnp.int32)
-        hi_ref[...] = jnp.full((qb,), widths_ref[0], jnp.int32)
+        found_ref[...] = jnp.zeros((qb, 1), jnp.int32)
+        level_ref[...] = jnp.full((qb, 1), n_levels, jnp.int32)
+        rank_ref[...] = jnp.full((qb, 1), -1, jnp.int32)
 
-    row = row_ref[0, :]                                # [W] (one level row)
+    # empty rows alias the next live row's block (no DMA); skip them
+    @pl.when(w_r > 0)
+    def _probe():
+        samp = samp_ref[0]                             # [1, S]
+        c = jnp.sum((samp <= q).astype(jnp.int32), axis=1,
+                    keepdims=True)                     # [QB, 1]
+        j = jnp.maximum(c - 1, 0)
+        onehot = (jax.lax.broadcasted_iota(jnp.int32, (qb, n_chunks), 1)
+                  == j).astype(jnp.float32).astype(jnp.bfloat16)
+        row = row_ref[0]                               # [S, 128]
+        chunk = jnp.zeros((qb, CHUNK), jnp.int32)
+        for b in range(4):
+            plane = ((row >> (8 * b)) & 0xFF).astype(jnp.float32)
+            part = jnp.dot(onehot, plane.astype(jnp.bfloat16),
+                           preferred_element_type=jnp.float32)
+            chunk = chunk | (part.astype(jnp.int32) << (8 * b))
+        le = jnp.sum((chunk <= q).astype(jnp.int32), axis=1,
+                     keepdims=True)
+        eq = jnp.sum((chunk == q).astype(jnp.int32), axis=1,
+                     keepdims=True)
+        live = c > 0
+        p = jnp.minimum(jnp.where(live, j * CHUNK + le - 1, -1), w_r - 1)
+        hit = live & (eq > 0) & (q != PAD_KEY)
+        found = found_ref[...]
+        level_ref[...] = jnp.where(hit & (found == 0), r, level_ref[...])
+        found_ref[...] = found | hit.astype(jnp.int32)
 
-    # Masked binary refinement inside the inherited rank window [lo, hi):
-    # invariant row[lo] <= q (lo == -1: virtual -inf) and row[hi] > q
-    # (hi >= live width: +INF padding).  All probes are [QB] gathers.
-    def step(_, c):
-        lo, hi = c
-        active = hi - lo > 1
-        mid = (lo + hi) // 2
-        vals = jnp.take(row, jnp.clip(mid, 0, width - 1))
-        le = vals <= q
-        lo2 = jnp.where(active & le, mid, lo)
-        hi2 = jnp.where(active & ~le, mid, hi)
-        return lo2, hi2
-
-    p, _ = jax.lax.fori_loop(0, n_steps, step, (lo_ref[...], hi_ref[...]))
-
-    pred = jnp.take(row, jnp.clip(p, 0, width - 1))
-    hit = (p >= 0) & (pred == q)
-    found = found_ref[...]
-    level_ref[...] = jnp.where(hit & ~found, r, level_ref[...])
-    found_ref[...] = found | hit
-
-    @pl.when(r == n_levels - 1)
-    def _emit_rank():
-        rank_ref[...] = p                              # bottom-row rank
-
-    @pl.when(r < n_levels - 1)
-    def _descend():
-        # Window for the next row: the nested-rows invariant puts the
-        # level-(r+1) predecessor inside [rank_map[p], rank_map[p + 1]).
-        rm = rm_ref[0, :]
-        row_empty = widths_ref[r] == 0
-        next_w = widths_ref[jnp.minimum(r + 1, n_levels - 1)]
-        lo_n = jnp.where(p >= 0, jnp.take(rm, jnp.clip(p, 0, width - 1)),
-                         -1)
-        hi_n = jnp.where((p + 1 >= width) | row_empty, next_w,
-                         jnp.take(rm, jnp.clip(p + 1, 0, width - 1)))
-        lo_ref[...] = lo_n
-        hi_ref[...] = hi_n
+        @pl.when(r == n_levels - 1)
+        def _emit_rank():
+            rank_ref[...] = p                          # bottom-row rank
 
 
 def splay_search(level_keys, queries, query_block: int =
@@ -351,11 +380,10 @@ def splay_search(level_keys, queries, query_block: int =
     to the ``"batch"`` logical axis when a mesh is active.
 
     ``pipelined`` picks the descent kernel (DESIGN.md §5.8): ``True``
-    the foresight-pipelined windowed-DMA kernel, ``False`` the tiered
-    per-row stream, ``None`` (default) backend-adaptive — pipelined
-    exactly when compiling (``not interpret``), so interpret-mode runs
-    keep the tiered kernel as the oracle.  Answers are bit-identical
-    either way (asserted in ``tests/test_pipelined_search.py``)."""
+    the foresight-pipelined windowed-DMA kernel (interpret mode only;
+    :func:`descent_kind` names what answers), ``False``/``None`` the
+    tiered per-row stream.  Answers are bit-identical either way
+    (asserted in ``tests/test_pipelined_search.py``)."""
     nq = jnp.asarray(queries).shape[0]
     _check_query_block(query_block, nq)
     if hasattr(level_keys, "rank_map"):        # index plane struct
@@ -380,8 +408,6 @@ def splay_search(level_keys, queries, query_block: int =
     else:
         bot_rank = None
     queries = shd.constrain(jnp.asarray(queries), "batch")
-    if pipelined is None:
-        pipelined = not interpret
     if pipelined:
         f, r, lv, _ = _splay_search_pipelined_arrays(
             level_keys, queries, query_block=query_block,
@@ -390,15 +416,14 @@ def splay_search(level_keys, queries, query_block: int =
         return f, r, lv
     return _splay_search_arrays(level_keys, queries,
                                 query_block=query_block,
-                                interpret=interpret, rank_map=rank_map,
-                                widths=widths)
+                                interpret=interpret, widths=widths)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("query_block", "interpret"))
 def _splay_search_arrays(level_keys, queries, query_block: int =
                          DEFAULT_QUERY_BLOCK, interpret: bool = True,
-                         rank_map=None, widths=None):
+                         widths=None):
     n_levels, width = level_keys.shape
     nq = queries.shape[0]
     if nq == 0:
@@ -409,47 +434,42 @@ def _splay_search_arrays(level_keys, queries, query_block: int =
         queries = jnp.pad(queries, (0, pad), constant_values=PAD_KEY - 1)
     nq_p = nq + pad
 
-    if rank_map is None:
-        rank_map = rank_windows(level_keys)
     if widths is None:
         widths = row_widths(level_keys)
     fetch = _fetch_schedule(widths, n_levels)
+    keys = jnp.asarray(level_keys, jnp.int32)
+    wpad = (-width) % CHUNK
+    if wpad:
+        keys = jnp.pad(keys, ((0, 0), (0, wpad)), constant_values=PAD_KEY)
+    n_chunks = (width + wpad) // CHUNK
+    rows = keys.reshape(n_levels, n_chunks, CHUNK)
+    samples = rows[:, :, 0].reshape(n_levels, 1, n_chunks)
 
-    n_steps = max(int(width + 1).bit_length(), 1)
-    rm_top = max(n_levels - 2, 0)
     kernel = functools.partial(_kernel_tiered, n_levels=n_levels,
-                               width=width, n_steps=n_steps)
+                               n_chunks=n_chunks)
+    qspec = pl.BlockSpec((query_block, 1), lambda i, r, f, w: (i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nq_p // query_block, n_levels),
         in_specs=[
-            pl.BlockSpec((query_block,), lambda i, r, f, w: (i,)),
-            pl.BlockSpec((1, width), lambda i, r, f, w: (f[r], 0)),
-            pl.BlockSpec((1, width),
-                         lambda i, r, f, w: (jnp.minimum(f[r], rm_top), 0)),
+            qspec,
+            pl.BlockSpec((1, 1, n_chunks), lambda i, r, f, w: (f[r], 0, 0)),
+            pl.BlockSpec((1, n_chunks, CHUNK),
+                         lambda i, r, f, w: (f[r], 0, 0)),
         ],
-        out_specs=(
-            pl.BlockSpec((query_block,), lambda i, r, f, w: (i,)),
-            pl.BlockSpec((query_block,), lambda i, r, f, w: (i,)),
-            pl.BlockSpec((query_block,), lambda i, r, f, w: (i,)),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((query_block,), jnp.int32),     # lo (window start)
-            pltpu.VMEM((query_block,), jnp.int32),     # hi (window end)
-        ],
+        out_specs=(qspec, qspec, qspec),
     )
-    out_shapes = (
-        jax.ShapeDtypeStruct((nq_p,), jnp.bool_),
-        jax.ShapeDtypeStruct((nq_p,), jnp.int32),
-        jax.ShapeDtypeStruct((nq_p,), jnp.int32),
-    )
+    out_shape = jax.ShapeDtypeStruct((nq_p, 1), jnp.int32)
     found, rank, lvl = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=out_shapes,
+        out_shape=(out_shape,) * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(fetch, widths, queries, level_keys, rank_map)
-    return found[:nq], rank[:nq], lvl[:nq]
+        name="splay_search_tiered",
+    )(fetch, widths, queries.reshape(nq_p, 1), samples, rows)
+    return found[:nq, 0] > 0, rank[:nq, 0], lvl[:nq, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -651,6 +671,12 @@ def _splay_search_pipelined_arrays(level_keys, queries, query_block: int =
                                    DEFAULT_QUERY_BLOCK,
                                    interpret: bool = True, rank_map=None,
                                    widths=None, bot_rank=None):
+    if not interpret:
+        raise ValueError(
+            "the pipelined descent runs in interpret mode only: its "
+            "per-query probes are [QB]-from-[W] gathers, which the TPU "
+            "compiler does not lower — use the tiered descent "
+            "(pipelined=False/None) on the chip")
     n_levels, width = level_keys.shape
     nq = queries.shape[0]
     if nq == 0:
@@ -673,9 +699,9 @@ def _splay_search_pipelined_arrays(level_keys, queries, query_block: int =
         # whole-row byte model (keys + rank map rows, 4 bytes a lane)
         f, r, lv = _splay_search_arrays(
             level_keys, queries, query_block=query_block,
-            interpret=interpret, rank_map=rank_map, widths=widths)
-        return f, r, lv, jnp.full((n_blocks,), 2 * n_levels * width * 4,
-                                  jnp.int32)
+            interpret=interpret, widths=widths)
+        return f, r, lv, jnp.full((n_blocks,), tiered_row_bytes(
+            n_levels, width), jnp.int32)
     if pad:
         queries = jnp.pad(queries, (0, pad), constant_values=PAD_KEY - 1)
     n_steps = max(int(width + 1).bit_length(), 1)
@@ -688,9 +714,9 @@ def _splay_search_pipelined_arrays(level_keys, queries, query_block: int =
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((query_block,), lambda i, w: (i,)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=(
             pl.BlockSpec((query_block,), lambda i, w: (i,)),
@@ -820,8 +846,7 @@ def _descend_local(local, queries, *, query_block: int, interpret: bool,
         return f, r, lv
     return _splay_search_arrays(
         local.keys, queries, query_block=query_block,
-        interpret=interpret, rank_map=local.rank_map,
-        widths=local.widths)
+        interpret=interpret, widths=local.widths)
 
 
 def _local_subplane(plane, *, n_levels: int):
@@ -1085,9 +1110,8 @@ def _sharded_search_fn(mesh, axis: str, n_levels: int, query_block: int,
         _search_shard_body, axis=axis, n_levels=n_levels,
         query_block=query_block, interpret=interpret,
         pipelined=pipelined)
-    fn = shd.shard_map_compat(body, mesh=mesh,
-                              in_specs=(specs, P()),
-                              out_specs=(P(), P(), P(), P()))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(specs, P()),
+                       out_specs=(P(), P(), P(), P()), check_vma=False)
     return jax.jit(fn)
 
 
@@ -1107,10 +1131,11 @@ def _routed_search_fn(mesh, axis: str, n_levels: int, query_block: int,
         _routed_shard_body, axis=axis, n_shards=mesh.shape[axis],
         n_levels=n_levels, capacity=capacity, query_block=query_block,
         interpret=interpret, n_live=n_live, pipelined=pipelined)
-    fn = shd.shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(specs, P(axis)),
-        out_specs=(P(axis), P(axis), P(axis), P(), P(), P()))
+        out_specs=(P(axis), P(axis), P(axis), P(), P(), P()),
+        check_vma=False)
     return jax.jit(fn)
 
 
@@ -1219,8 +1244,6 @@ def splay_search_sharded(level_keys, queries, query_block: int =
             f"splay_search_sharded: slack must be >= 1.0, got {slack}")
     nq = jnp.asarray(queries).shape[0]
     _check_query_block(query_block, nq)
-    if pipelined is None:
-        pipelined = not interpret
     pipelined = bool(pipelined)
     plane = _as_device_plane(plane)
     if mesh is None:
@@ -1488,8 +1511,8 @@ def _select_fn(mesh, axis: str, n_levels: int):
     specs = shd.index_plane_specs(DeviceLevelArrays, axis)
     body = functools.partial(_select_shard_body, axis=axis,
                              n_levels=n_levels)
-    fn = shd.shard_map_compat(body, mesh=mesh, in_specs=(specs, P()),
-                              out_specs=P())
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(specs, P()),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)
 
 
@@ -1501,8 +1524,8 @@ def _topk_fn(mesh, axis: str, n_levels: int, k: int):
     specs = shd.index_plane_specs(DeviceLevelArrays, axis)
     body = functools.partial(_topk_shard_body, axis=axis,
                              n_levels=n_levels, k=k)
-    fn = shd.shard_map_compat(body, mesh=mesh, in_specs=(specs, P()),
-                              out_specs=(P(), P(), P()))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(specs, P()),
+                       out_specs=(P(), P(), P()), check_vma=False)
     return jax.jit(fn)
 
 

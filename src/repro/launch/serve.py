@@ -26,6 +26,8 @@ from repro.configs import registry
 from repro.core import workload
 from repro.models import model_zoo as zoo
 from repro.serve.engine import Engine, Request
+from repro.launch import compile_cache
+from repro.launch.mesh import make_auto_mesh
 
 
 def splay_demo(args) -> dict:
@@ -82,7 +84,7 @@ def splay_demo(args) -> dict:
     n_dev = len(jax.devices())
     if n_dev > 1 and W % n_dev == 0:
         from repro.kernels import ops as kops
-        mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+        mesh = make_auto_mesh((1, n_dev), ("data", "model"))
         plane_s = shd.shard_index_plane(plane, mesh)
 
         # end-to-end sharded serving (DESIGN.md §5.5–§5.6):
@@ -238,6 +240,7 @@ def main(argv=None):
                     help="run the plane fsck every K lookup epochs on "
                          "the device index (0 = off)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.splay_demo:
         return splay_demo(args)

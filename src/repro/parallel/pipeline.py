@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import shard_map_compat
-
 
 def pipeline_forward(x, stage_params, stage_fn: Callable, mesh,
                      n_microbatches: int, axis: str = "pod"):
@@ -77,9 +75,9 @@ def pipeline_forward(x, stage_params, stage_fn: Callable, mesh,
 
     spec_x = P()          # batch replicated across the pipe axis
     spec_p = P(axis)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         stage_worker, mesh=mesh,
-        in_specs=(spec_p, spec_x), out_specs=spec_x)
+        in_specs=(spec_p, spec_x), out_specs=spec_x, check_vma=False)
     return fn(stage_params, x)
 
 

@@ -20,14 +20,14 @@ width-sharded layout), and :func:`plane_width_mesh` (detect that layout
 on a concrete plane — the search wrapper's dispatch seam).
 :func:`mass_split_bounds` solves the §5.6 mass-weighted shard-boundary
 placement (the access-balanced alternative to equal lane counts).
-:func:`shard_map_compat` papers over the ``check_rep``/``check_vma``
-rename so every shard_map in the repo goes through one shim.
+Every shard_map in the repo is ``jax.shard_map(..., check_vma=False)``:
+the bodies return deliberately-replicated outputs (all-reduced scalars,
+all-gathered widths) that the static checker cannot prove.
 """
 
 from __future__ import annotations
 
 import contextlib
-import inspect
 import threading
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -36,27 +36,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 Rules = Dict[str, Optional[Tuple[str, ...]]]
-
-# newer jax exposes jax.shard_map; the replication-check kwarg was renamed
-# check_rep -> check_vma along the way, so key the choice off the actual
-# signature rather than the attribute (0.5.x has jax.shard_map+check_rep)
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax 0.4.x only
-    from jax.experimental.shard_map import shard_map as _shard_map
-_SHARD_MAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else {"check_rep": False})
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions (replication checking disabled:
-    the bodies in this repo return deliberately-replicated outputs — e.g.
-    all-reduced scalars, all-gathered widths — that the static checker
-    cannot prove)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **_SHARD_MAP_KW)
 
 # -- default rule tables -----------------------------------------------------
 

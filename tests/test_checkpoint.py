@@ -11,6 +11,7 @@ import pytest
 from repro.train import checkpoint as ck
 from repro.train import elastic
 from repro.train import straggler
+from repro.launch.mesh import make_auto_mesh
 
 
 def _tree():
@@ -79,7 +80,7 @@ def test_elastic_grid_and_microbatch():
 
 
 def test_elastic_reshard_roundtrip():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_auto_mesh((1, 1), ("data", "model"))
     flat = {"params/a": np.arange(16.0).reshape(4, 4)}
     specs = {"params/a": jax.sharding.PartitionSpec("data", None)}
     out = elastic.reshard(flat, specs, mesh)

@@ -28,6 +28,7 @@ from repro.core import splaylist as sx
 from repro.core import workload as wl
 from repro.kernels import ops as kops
 from repro.kernels import splay_search as ssk
+from repro.launch.mesh import make_auto_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PAD, NEG = ssk.PAD_KEY, ssk.NEG_INF_KEY
@@ -281,7 +282,7 @@ def test_duplicate_boundary_keys_on_sparse_segmented_plane():
     from repro.parallel import sharding as shd
     keys = [5, 9, 700]
     st, plane = _plane(keys, n_levels=6, width=16, cap=64)
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_auto_mesh((1, 4), ("data", "model"))
     pl = shd.shard_index_plane(plane, mesh)
     for split in ("lanes", "mass"):
         ps, ovf = dix.refresh_device_sharded(st, pl, mesh=mesh,

@@ -90,8 +90,7 @@ def _fixture(width, alpha, nq, seed=0):
 def test_pipelined_dispatch_seam():
     """``splay_search(pipelined=True)`` returns the same triple as the
     4-tuple entry point minus the bytes counter, and ``pipelined=None``
-    resolves to the tiered kernel under interpret mode (the oracle
-    default)."""
+    resolves to the tiered kernel (on every backend)."""
     L, qs = _fixture(128, 1.0, 64, seed=5)
     qsj = jnp.asarray(qs)
     out_p = ssk.splay_search(L, qsj, query_block=32, sharded=False,
@@ -102,6 +101,27 @@ def test_pipelined_dispatch_seam():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     for a, b in zip(out_d, out_4[:3]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("width,pipelined,kind", [
+    (131072, None, "tiered"),
+    (131072, False, "tiered"),
+    (4096, True, "pipelined"),
+    (257, True, "tiered (pipelined width fallback)"),
+    (131072, True, "tiered (pipelined width fallback)"),
+])
+def test_descent_kind_names_the_kernel(width, pipelined, kind):
+    assert ssk.descent_kind(width, pipelined) == kind
+
+
+def test_pipelined_refuses_to_compile():
+    """The pipelined probes are gathers the TPU compiler refuses, so a
+    compiled (non-interpret) request raises instead of swapping kernels."""
+    L, qs = _fixture(128, 1.0, 8, seed=5)
+    with pytest.raises(ValueError, match="interpret mode only"):
+        ssk._splay_search_pipelined_arrays(jnp.asarray(L.keys),
+                                           jnp.asarray(qs), query_block=8,
+                                           interpret=False)
 
 
 # ---------------------------------------------------------------------------
@@ -145,14 +165,15 @@ def test_hot_members_stream_fewer_bytes():
 def test_untileable_width_falls_back_to_tiered():
     """A width with no DMA tile <= 256 inside the 64-tile budget (257 is
     prime) falls back to the tiered stream and reports its whole-row
-    byte model."""
+    byte model: every level's key row, padded to whole 128-lane chunks
+    (257 -> 384 lanes)."""
     rng = np.random.default_rng(13)
     keys = np.sort(rng.choice(10 ** 6, 200, replace=False)).astype(np.int32)
     h = np.minimum(rng.geometric(0.5, 200) - 1, 5).astype(np.int32)
     plane = _device_plane(keys, h, 257, 6)
     qs = np.concatenate([keys[:20], rng.integers(0, 10 ** 6, 20)])
     nb = _assert_parity(plane, qs, qb=16)
-    assert (nb == 2 * 6 * 257 * 4).all(), nb
+    assert (nb == 6 * 384 * 4).all(), nb
 
 
 # ---------------------------------------------------------------------------

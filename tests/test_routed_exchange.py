@@ -23,6 +23,7 @@ from repro.kernels import splay_search as ssk
 from repro.parallel import sharding as shd
 
 from conftest import seed_splay_state as _seed_state  # noqa: E402
+from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
 
 def _plane(pool, n_levels=12, width=252, cap=512):
@@ -272,7 +273,7 @@ def test_overflow_and_spill_same_epoch_sharded():
     W = 128 if 128 % n_dev == 0 else n_dev * (128 // n_dev)
     st = _seed_state(pool, cap=512)
     plane = dix.from_state_device(st, n_levels=12, width=W)
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    mesh = make_auto_mesh((1, n_dev), ("data", "model"))
     plane_s = shd.shard_index_plane(plane, mesh)
     E, B = 3, 32
     keys = np.resize(np.asarray(pool, np.int32), (E, B))
@@ -312,7 +313,7 @@ def test_rebuild_while_segmented_plane():
     pool = list(range(0, 2 * (W - 8), 2))                # W-8 alive
     st = _seed_state(pool, cap=2 * W)
     plane = dix.from_state_device(st, n_levels=12, width=W)
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    mesh = make_auto_mesh((1, n_dev), ("data", "model"))
     plane_s = shd.shard_index_plane(plane, mesh)
     E, B = 4, 32                                         # size+B > W
     rng = np.random.default_rng(0)

@@ -26,6 +26,7 @@ from repro.core import splaylist as sx
 from repro.parallel import sharding as shd
 
 from conftest import seed_splay_state as _seed_state  # noqa: E402
+from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -69,7 +70,7 @@ def test_index_plane_specs_and_shard_helper():
     assert specs.heights == specs.slots == P("model")
     # single-device mesh: helper round-trips values; indivisible width
     # returns the plane unchanged
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_auto_mesh((1, 1), ("data", "model"))
     plane = dix.build_device(
         jnp.asarray(np.arange(0, 128, 2, dtype=np.int32)),
         jnp.asarray(np.zeros(64, np.int32)), n_levels=3)

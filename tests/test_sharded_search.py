@@ -32,6 +32,7 @@ from repro.kernels import splay_search as ssk
 from repro.parallel import sharding as shd
 
 from conftest import seed_splay_state as _seed_state  # noqa: E402
+from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -81,7 +82,7 @@ def test_plane_width_mesh_detection():
     tracers, single-shard meshes; the mesh for the sharded layout."""
     plane = _plane(list(range(0, 80, 2)))
     assert shd.plane_width_mesh(plane) is None
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh1 = make_auto_mesh((1, 1), ("data", "model"))
     assert shd.plane_width_mesh(
         shd.shard_index_plane(plane, mesh1)) is None   # 1 shard
 

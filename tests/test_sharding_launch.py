@@ -11,10 +11,11 @@ import pytest
 
 from repro.parallel import sharding as shd
 from repro.launch import roofline as rf
+from repro.launch.mesh import make_auto_mesh
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_auto_mesh((1, 1), ("data", "model"))
 
 
 def test_resolve_spec_divisibility_fallback():
@@ -122,9 +123,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import make_auto_mesh
 from repro.parallel.pipeline import pipeline_forward, split_stages
 
-mesh = jax.make_mesh((4, 2), ("pod", "model"))
+mesh = make_auto_mesh((4, 2), ("pod", "model"))
 L, D, B = 8, 16, 8
 rng = np.random.default_rng(0)
 w = jnp.asarray(rng.normal(size=(L, D, D)).astype(np.float32) * 0.3)

@@ -1,0 +1,94 @@
+"""The main-path descent compiled for a described TPU v5e (no chip).
+
+Interpret mode runs a Pallas kernel without ever asking the TPU
+compiler, so these tests compile the kernels ahead of time for a
+``v5e:2x2`` topology: the tiered descent at the paper's deployment
+width (``W = 131072``, ``L = 17``) and at ``W = 4096``, and the routed
+sharded search on a 4-device mesh of the same topology.  Each asserts
+that the kernel made it into the executable (``tpu_custom_call``).
+The pipelined descent is interpret-only and has no test here.
+
+The topology is described inside a fixture (never at import), so every
+test worker collects the same tests and only the one running this file
+loads the TPU compiler; the fixture skips where no topology can be
+described.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import device_index as dix
+from repro.kernels import splay_search as ssk
+from repro.launch.mesh import make_auto_mesh
+from repro.parallel import sharding as shd
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    """A described-topology compile cannot be read back without a chip:
+    keep it out of the persistent cache."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _plane_shapes(n_levels: int, width: int):
+    return jax.eval_shape(
+        lambda: dix.build_device(jnp.zeros((width,), jnp.int32),
+                                 jnp.zeros((width,), jnp.int32),
+                                 n_levels=n_levels))
+
+
+@pytest.mark.parametrize("n_levels,width,nq", [
+    (17, 131072, 1024),          # the paper's 10^5-key deployment
+    (6, 4096, 1000),             # a plane that fits on-chip memory
+])
+def test_tiered_descent_compiles(topo, n_levels, width, nq):
+    one = SingleDeviceSharding(topo.devices[0])
+    keys = jax.ShapeDtypeStruct((n_levels, width), jnp.int32, sharding=one)
+    widths = jax.ShapeDtypeStruct((n_levels,), jnp.int32, sharding=one)
+    queries = jax.ShapeDtypeStruct((nq,), jnp.int32, sharding=one)
+    fn = jax.jit(lambda k, q, w: ssk._splay_search_arrays(
+        k, q, query_block=ssk.DEFAULT_QUERY_BLOCK, interpret=False,
+        widths=w))
+    compiled = fn.lower(keys, queries, widths).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_routed_sharded_search_compiles(topo):
+    n_levels, width, nq, axis = 17, 16384, 4096, "model"
+    mesh = make_auto_mesh((1, 4), ("data", axis), devices=topo.devices)
+    specs = shd.index_plane_specs(dix.DeviceLevelArrays, axis)
+    plane = jax.tree_util.tree_map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        _plane_shapes(n_levels, width), specs)
+    queries = jax.ShapeDtypeStruct((nq,), jnp.int32,
+                                   sharding=NamedSharding(mesh, P(axis)))
+    fn = ssk._routed_search_fn(
+        mesh, axis, n_levels, ssk.DEFAULT_QUERY_BLOCK, False,
+        ssk.route_capacity(nq, 4), nq, False)
+    compiled = fn.lower(plane, queries).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-to-all" in text
