@@ -142,25 +142,24 @@ def _assemble_device(keys_sorted: jax.Array, rel_h: jax.Array,
     widths = cs[:, width - 1]
 
     col = jnp.arange(width, dtype=jnp.int32)
-    take = jax.vmap(functools.partial(_compact_take, width=width))(cs)
     live = col[None, :] < widths[:, None]
-    rows = jnp.where(live, jnp.take(keys_sorted, take), PAD_KEY)
-
     # rank map: the key at (r, j) sits in row r+1 at that row's prefix
     # count minus one (nested rows); pad entries close the descent
     # window at the next row's live width; bottom row is the identity.
     cs_next = jnp.concatenate(
         [cs[1:], jnp.ones((1, width), jnp.int32)], axis=0)
-    rank_live = jnp.take_along_axis(cs_next, take, axis=1) - 1
+    with jax.named_scope("splay.compact"):
+        take = jax.vmap(functools.partial(_compact_take, width=width))(cs)
+        rows = jnp.where(live, jnp.take(keys_sorted, take), PAD_KEY)
+        rank_live = jnp.take_along_axis(cs_next, take, axis=1) - 1
+        # bottom rank rides the same compaction gather: keys_sorted IS
+        # the bottom row, so the member picked for lane (r, j) sits in
+        # the bottom row at its keys_sorted index — `take` itself.
+        bot_rank = jnp.where(live, take, widths[n_levels - 1])
     pad_default = jnp.concatenate(
         [widths[1:], jnp.zeros((1,), jnp.int32)])
     rank_map = jnp.where(live, rank_live, pad_default[:, None])
     rank_map = rank_map.at[n_levels - 1].set(col)
-
-    # bottom rank rides the same compaction gather: keys_sorted IS the
-    # bottom row, so the member picked for lane (r, j) sits in the
-    # bottom row at its keys_sorted index — `take` itself.
-    bot_rank = jnp.where(live, take, widths[n_levels - 1])
 
     heights = jnp.where(alive, rel_h, 0).astype(jnp.int32)
     return DeviceLevelArrays(
@@ -258,29 +257,31 @@ def _merge_rows(bottom, surv, old_h, slots_eff, ns, new_h, new_slots,
     surv_i = surv.astype(jnp.int32)
     cs_s = jnp.cumsum(surv_i)
     n_old = cs_s[width - 1]
-    take_a = _compact_take(cs_s, width)
     acol = jnp.arange(width, dtype=jnp.int32)
-    a_k = jnp.where(acol < n_old, jnp.take(bottom, take_a), PAD_KEY)
-    a_h = jnp.take(old_h, take_a)
-    a_s = jnp.take(slots_eff, take_a)
+    with jax.named_scope("splay.compact"):
+        take_a = _compact_take(cs_s, width)
+        a_k = jnp.where(acol < n_old, jnp.take(bottom, take_a), PAD_KEY)
+        a_h = jnp.take(old_h, take_a)
+        a_s = jnp.take(slots_eff, take_a)
 
     # merged position of survivor i; strictly increasing (pad lanes
     # continue past the live prefix), so it is searchsorted-invertible
     pos_a = (acol + jnp.searchsorted(ns, a_k).astype(jnp.int32))
-    a_of = jnp.searchsorted(pos_a, col).astype(jnp.int32)
-    a_ofc = jnp.minimum(a_of, width - 1)
-    from_a = jnp.take(pos_a, a_ofc) == col
-    b_of = jnp.minimum(col - jnp.minimum(a_of, col), kk - 1)
+    with jax.named_scope("splay.compact"):
+        a_of = jnp.searchsorted(pos_a, col).astype(jnp.int32)
+        a_ofc = jnp.minimum(a_of, width - 1)
+        from_a = jnp.take(pos_a, a_ofc) == col
+        b_of = jnp.minimum(col - jnp.minimum(a_of, col), kk - 1)
 
-    n_tot = n_old + n_new
-    merged_k = jnp.where(
-        col < n_tot,
-        jnp.where(from_a, jnp.take(a_k, a_ofc), jnp.take(ns, b_of)),
-        PAD_KEY)
-    merged_h = jnp.where(from_a, jnp.take(a_h, a_ofc),
-                         jnp.take(new_h, b_of))
-    merged_s = jnp.where(from_a, jnp.take(a_s, a_ofc),
-                         jnp.take(new_slots, b_of))
+        n_tot = n_old + n_new
+        merged_k = jnp.where(
+            col < n_tot,
+            jnp.where(from_a, jnp.take(a_k, a_ofc), jnp.take(ns, b_of)),
+            PAD_KEY)
+        merged_h = jnp.where(from_a, jnp.take(a_h, a_ofc),
+                             jnp.take(new_h, b_of))
+        merged_s = jnp.where(from_a, jnp.take(a_s, a_ofc),
+                             jnp.take(new_slots, b_of))
     return merged_k, merged_h, merged_s
 
 

@@ -41,6 +41,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Tuple
 
+import jax
 import numpy as np
 
 from repro.kernels.splay_search import DEFAULT_ROUTE_SLACK, route_capacity
@@ -349,10 +350,13 @@ def run_serving_controlled(st, plane, kinds, keys, upd_mask,
         st, plane, r, p, ov, sp, oc = out
         res.append(r); plen.append(p); ovf.append(ov)
         spl.append(sp); occ.append(oc)
-        # host mirror of run_serving's overflow machine (§5.4)
-        pending, pressed = overflow_machine_step(
-            int(ov), int(st.size), B, width, pressed)
-        state = controller_step(cfg, state, int(sp), np.asarray(oc), B)
+        with jax.profiler.TraceAnnotation("splay.controller.step",
+                                          epochs=1, batch=B):
+            # host mirror of run_serving's overflow machine (§5.4)
+            pending, pressed = overflow_machine_step(
+                int(ov), int(st.size), B, width, pressed)
+            state = controller_step(cfg, state, int(sp), np.asarray(oc),
+                                    B)
         states.append(state)
     stack = lambda xs: np.stack([np.asarray(x) for x in xs])
     return (st, plane, stack(res), stack(plen), stack(ovf),

@@ -19,6 +19,7 @@ sentinel level on top; slot 0 = head, slot 1 = tail):
     zl        int32[]       current bottom level of the list
     n_alloc   int32[]       bump allocator
     size      int32[]       unmarked key count
+    counters  int32[K]      cumulative work counters, named by ``COUNTERS``
 
 Counters use ``count_dtype`` (default int32: exact for m < 2^30; pass
 int64 under jax_enable_x64 for longer runs).  Threshold comparisons are
@@ -61,6 +62,22 @@ OP_RANGE = 4
 HEAD = 0
 TAIL = 1
 
+# Names, in order, of ``SplayState.counters``: cumulative int32 counts of
+# the serving path's work, carried in the state (no host sync to keep
+# them) and read on the host by :func:`serving_counters`.  They wrap
+# modulo 2^32; a reader takes differences of two readings.
+#   epochs              serving epochs run
+#   fold_steps          scan steps of the update fold (B per fold)
+#   fold_active         fold steps that carried an update: unique keys
+#                       with weight > 0 (aggregated), lanes with
+#                       ``upd & present`` (per-lane), every lane (run_ops)
+#   state_rebuilds      rebuilds of the state (``_maybe_rebuild`` fired)
+#   plane_rebuilds      epochs whose plane came from a full rebuild
+#   plane_rows_rebuilt  plane rows the epochs' refreshes recomputed
+#   plane_rows_changed  plane rows whose keys or width an epoch changed
+COUNTERS = ("epochs", "fold_steps", "fold_active", "state_rebuilds",
+            "plane_rebuilds", "plane_rows_rebuilt", "plane_rows_changed")
+
 
 class SplayState(NamedTuple):
     key: jax.Array        # [C]
@@ -75,6 +92,7 @@ class SplayState(NamedTuple):
     zl: jax.Array         # scalar int32
     n_alloc: jax.Array    # scalar int32
     size: jax.Array       # scalar int32
+    counters: jax.Array   # int32 [len(COUNTERS)]
 
     @property
     def max_level(self) -> int:
@@ -109,7 +127,22 @@ def make(capacity: int, max_level: int = 32,
         key=key, nxt=nxt, hits=hits, selfhits=selfhits, top=top,
         nzero=nzero, deleted=deleted, m=zero, dhits=zero,
         zl=jnp.array(ml1, jnp.int32), n_alloc=jnp.array(2, jnp.int32),
-        size=jnp.array(0, jnp.int32))
+        size=jnp.array(0, jnp.int32),
+        counters=jnp.zeros((len(COUNTERS),), jnp.int32))
+
+
+def _count(st: SplayState, **by) -> SplayState:
+    """``st`` with ``by[name]`` added to each named counter."""
+    assert set(by) <= set(COUNTERS), by
+    inc = jnp.stack([jnp.asarray(by.get(c, 0)).astype(jnp.int32)
+                     for c in COUNTERS])
+    return st._replace(counters=st.counters + inc)
+
+
+def serving_counters(st: SplayState) -> dict:
+    """The state's counters on the host, ``{name: int}`` in ``COUNTERS``
+    order (one device read of ``len(COUNTERS)`` int32)."""
+    return dict(zip(COUNTERS, np.asarray(st.counters).tolist()))
 
 
 # ---------------------------------------------------------------------------
@@ -496,7 +529,12 @@ def key_order(keys, alive):
 
 def _maybe_rebuild(st: SplayState) -> SplayState:
     trig = (st.m > 0) & (2 * st.dhits >= st.m)
-    return jax.lax.cond(trig, rebuild, lambda s: s, st)
+
+    def fire(s):
+        with jax.named_scope("splay.state_rebuild"):
+            return _count(rebuild(s), state_rebuilds=1)
+
+    return jax.lax.cond(trig, fire, lambda s: s, st)
 
 
 def rebuild(st: SplayState) -> SplayState:
@@ -624,7 +662,7 @@ def rebuild(st: SplayState) -> SplayState:
         top=new_top, nzero=new_nzero, deleted=new_deleted,
         m=big_m, dhits=jnp.zeros((), cnt_dt),
         zl=zl_new.astype(jnp.int32), n_alloc=(n + 2).astype(jnp.int32),
-        size=n.astype(jnp.int32))
+        size=n.astype(jnp.int32), counters=st.counters)
 
 
 # ---------------------------------------------------------------------------
@@ -645,7 +683,9 @@ def run_ops(st: SplayState, kinds, keys, upd_mask):
         return _maybe_rebuild(s), (res, plen)
 
     st, (res, plen) = jax.lax.scan(step, st, (kinds, keys, upd_mask))
-    return st, res, plen
+    # every op of the stream walks and answers: each step is active
+    n = keys.shape[0]
+    return _count(st, fold_steps=n, fold_active=n), res, plen
 
 
 def pad_op_batch(kinds, keys, upd_mask, batch: int):
@@ -732,6 +772,7 @@ def run_contains_batch(st: SplayState, keys, upd_mask,
             return s._replace(dhits=s.dhits + wmk), ()
 
         st, _ = jax.lax.scan(agg_step, st, (uk, w, wm))
+        st = _count(st, fold_steps=B, fold_active=jnp.sum(w > 0))
         st = _maybe_rebuild(st)
         return st, present & ~marked, steps
 
@@ -742,6 +783,8 @@ def run_contains_batch(st: SplayState, keys, upd_mask,
                                           s.dhits)), ()
 
     st, _ = jax.lax.scan(upd_step, st, (keys, upd_mask, present, marked))
+    st = _count(st, fold_steps=keys.shape[0],
+                fold_active=jnp.sum(upd_mask & present))
     st = _maybe_rebuild(st)
     return st, present & ~marked, steps
 
@@ -908,38 +951,43 @@ def _run_epoch(st: SplayState, plane, kinds, keys, upd_mask,
                              "aggregate=True")
         from repro.kernels import ops as kops
         from repro.kernels import splay_search as ssk
-        if sharded:
-            res, rank, plen, rstats = kops.splay_search_sharded(
-                plane, keys, mesh=mesh, axis=axis, routed=routed,
-                capacity=route_capacity,
-                slack=(route_slack if route_slack is not None
-                       else ssk.DEFAULT_ROUTE_SLACK),
-                return_stats=True)
-            spill = rstats.spill
-            occupancy = rstats.occupancy
-        else:
-            res, rank, plen = kops.splay_search(plane, keys,
-                                                sharded=False)
+        with jax.named_scope("splay.descent"):
+            if sharded:
+                res, rank, plen, rstats = kops.splay_search_sharded(
+                    plane, keys, mesh=mesh, axis=axis, routed=routed,
+                    capacity=route_capacity,
+                    slack=(route_slack if route_slack is not None
+                           else ssk.DEFAULT_ROUTE_SLACK),
+                    return_stats=True)
+                spill = rstats.spill
+                occupancy = rstats.occupancy
+            else:
+                res, rank, plen = kops.splay_search(plane, keys,
+                                                    sharded=False)
         upd_eff = upd_mask
         if ordered:
             # ordered lanes: answers off the same descent's bottom-row
             # rank (DESIGN.md §5.10); pure reads, so they carry no hit
             # weight into the rebalance fold (matches run_ops exactly)
-            pred_keys = kops.splay_select(
-                plane, rank, sharded=sharded,
-                mesh=(mesh if sharded else None), axis=axis)
+            with jax.named_scope("splay.select"):
+                pred_keys = kops.splay_select(
+                    plane, rank, sharded=sharded,
+                    mesh=(mesh if sharded else None), axis=axis)
             res = jnp.where(
                 kinds == OP_PRED,
                 jnp.where(rank >= 0, pred_keys, jnp.int32(NEG_INF_32)),
                 jnp.where(kinds == OP_RANGE, rank + 1,
                           res.astype(jnp.int32)))
             upd_eff = upd_mask & (kinds == OP_CONTAINS)
-        st, _, _ = run_contains_batch(st, keys, upd_eff, aggregate=True)
+        with jax.named_scope("splay.fold"):
+            st, _, _ = run_contains_batch(st, keys, upd_eff, aggregate=True)
     elif aggregate:
-        st, res, plen = run_contains_batch(st, keys, upd_mask,
-                                           aggregate=True)
+        with jax.named_scope("splay.fold"):
+            st, res, plen = run_contains_batch(st, keys, upd_mask,
+                                               aggregate=True)
     else:
-        st, res, plen = run_ops(st, kinds, keys, upd_mask)
+        with jax.named_scope("splay.fold"):
+            st, res, plen = run_ops(st, kinds, keys, upd_mask)
     res = res.astype(jnp.int32)
     if max_new is None:
         # an epoch cannot insert more keys than it has ops: bound the
@@ -947,22 +995,30 @@ def _run_epoch(st: SplayState, plane, kinds, keys, upd_mask,
         max_new = keys.shape[0]
 
     def full_rebuild(_):
-        pl = dix.from_state_device(st, n_levels=n_levels, width=width)
-        # a full build drops nothing the plane can hold; only alive
-        # counts beyond the (static) width remain unrepresentable
-        ovf = jnp.maximum(st.size - width, 0).astype(jnp.int32)
-        return pl, ovf
+        with jax.named_scope("splay.plane_rebuild"):
+            pl = dix.from_state_device(st, n_levels=n_levels, width=width)
+            # a full build drops nothing the plane can hold; only alive
+            # counts beyond the (static) width remain unrepresentable
+            ovf = jnp.maximum(st.size - width, 0).astype(jnp.int32)
+            return pl, ovf
 
     def incremental(_):
-        if sharded:
-            return dix.refresh_device_sharded(st, plane, max_new=max_new,
-                                              mesh=mesh, axis=axis,
-                                              split=split)
-        return dix.refresh_device(st, plane, max_new=max_new,
-                                  return_overflow=True)
+        with jax.named_scope("splay.refresh"):
+            if sharded:
+                return dix.refresh_device_sharded(
+                    st, plane, max_new=max_new, mesh=mesh, axis=axis,
+                    split=split)
+            return dix.refresh_device(st, plane, max_new=max_new,
+                                      return_overflow=True)
 
+    plane_in = plane
     plane, overflow = jax.lax.cond(rebuild, full_rebuild, incremental,
                                    operand=None)
+    # every refresh recomputes all rows; count those it actually changed
+    changed = jnp.sum(jnp.any(plane.keys != plane_in.keys, axis=1)
+                      | (plane.widths != plane_in.widths))
+    st = _count(st, epochs=1, plane_rebuilds=rebuild,
+                plane_rows_rebuilt=n_levels, plane_rows_changed=changed)
     if sharded:
         # keep the carry in the width-sharded layout whichever branch
         # produced it (the rebuild branch is replicated math)
@@ -981,15 +1037,18 @@ def run_epoch(st: SplayState, plane, kinds, keys, upd_mask,
               plane_search: bool = False, split: str = "lanes",
               route_capacity: int = None, route_slack: float = None,
               ordered: bool = False, routed: bool = True):
-    _check_plane_dispatch(plane, mesh, axis, split)
-    _check_route_args(route_capacity, route_slack)
-    return _run_epoch(st, plane, kinds, keys, upd_mask,
-                      aggregate=aggregate, max_new=max_new,
-                      rebuild=rebuild, mesh=mesh, axis=axis,
-                      plane_search=plane_search, split=split,
-                      route_capacity=route_capacity,
-                      route_slack=route_slack, ordered=ordered,
-                      routed=routed)
+    span = dict(epochs=1, batch=np.shape(keys)[0])
+    with jax.profiler.TraceAnnotation("splay.serve.guard", **span):
+        _check_plane_dispatch(plane, mesh, axis, split)
+        _check_route_args(route_capacity, route_slack)
+    with jax.profiler.TraceAnnotation("splay.serve.dispatch", **span):
+        return _run_epoch(st, plane, kinds, keys, upd_mask,
+                          aggregate=aggregate, max_new=max_new,
+                          rebuild=rebuild, mesh=mesh, axis=axis,
+                          plane_search=plane_search, split=split,
+                          route_capacity=route_capacity,
+                          route_slack=route_slack, ordered=ordered,
+                          routed=routed)
 
 
 run_epoch.__doc__ = _run_epoch.__doc__
@@ -1076,15 +1135,19 @@ def run_serving(st: SplayState, plane, kinds, keys, upd_mask,
                 plane_search: bool = False, split: str = "lanes",
                 route_capacity: int = None, route_slack: float = None,
                 ordered: bool = False, routed: bool = True):
-    _check_plane_dispatch(plane, mesh, axis, split)
-    _check_route_args(route_capacity, route_slack)
-    return _run_serving(st, plane, kinds, keys, upd_mask,
-                        aggregate=aggregate, max_new=max_new,
-                        mesh=mesh, axis=axis,
-                        plane_search=plane_search, split=split,
-                        route_capacity=route_capacity,
-                        route_slack=route_slack, ordered=ordered,
-                        routed=routed)
+    n_epochs, batch = np.shape(keys)
+    span = dict(epochs=n_epochs, batch=batch)
+    with jax.profiler.TraceAnnotation("splay.serve.guard", **span):
+        _check_plane_dispatch(plane, mesh, axis, split)
+        _check_route_args(route_capacity, route_slack)
+    with jax.profiler.TraceAnnotation("splay.serve.dispatch", **span):
+        return _run_serving(st, plane, kinds, keys, upd_mask,
+                            aggregate=aggregate, max_new=max_new,
+                            mesh=mesh, axis=axis,
+                            plane_search=plane_search, split=split,
+                            route_capacity=route_capacity,
+                            route_slack=route_slack, ordered=ordered,
+                            routed=routed)
 
 
 run_serving.__doc__ = _run_serving.__doc__

@@ -50,6 +50,9 @@ def test_differential_mixed_ops_with_rebuilds():
     assert oracle.deleted_hits == int(st.dhits)
     assert oracle.zero_level == int(st.zl)
     assert oracle.rebuilds >= 1   # the stream must exercise rebuild
+    got = sx.serving_counters(st)
+    assert got["state_rebuilds"] == oracle.rebuilds
+    assert got["fold_steps"] == got["fold_active"] == len(stream)
 
 
 def test_differential_contains_only_skewed():
