@@ -16,10 +16,9 @@ module keeps the same layout but makes it live where it is consumed:
     ``level_arrays._assemble``);
   * :func:`refresh_device` — jitted incremental rebuild: alive
     keys/heights are read from the state *on device*, inserted keys are
-    merged into the previous sorted bottom row by ``top_k`` +
-    ``searchsorted`` rank arithmetic (deletions are masked out by
-    absence), and the prefix-sum re-layering reruns — no
-    full-membership sort, no host transfer, no shape change; with
+    merged into the previous sorted bottom row by one sort (deletions
+    are masked out by absence), and the prefix-sum re-layering reruns —
+    no full-membership sort, no host transfer, no shape change; with
     ``return_overflow=True`` it also reports the alive keys it could
     not represent (DESIGN.md §5.4 rebuild protocol);
   * :func:`refresh_device_sharded` — the same pipeline under
@@ -33,13 +32,19 @@ module keeps the same layout but makes it live where it is consumed:
     emitting a *segmented* plane whose routed-search load balances
     under skew.
 
-Scatter- and sort-free by construction (the hot path): XLA lowers
-gathers, cumsums and ``top_k`` to tight vectorized loops on every
-backend, while generic scatters and multi-operand sorts degrade to
-element-wise code on CPU and are serialization points on TPU.  The one
-data-dependent reorder left — sorting the epoch's newly inserted keys
-among themselves — is a bounded ``top_k`` (``max_new``, the epoch batch
-size), not an O(n log n) pass over the key set.
+Stream compaction is a sort that carries its payloads: each row of the
+plane is laid out by one ``lax.sort`` along the width axis (the row's
+members keyed by column, the rest lifted past them), and the insert
+merge is one sort of the survivors with the new keys.  The sort brings
+the keys, heights, slots and next-row prefix counts along, so no
+per-lane gather reads through a permutation afterwards.  The inverse
+prefix sum it replaces (:func:`_compact_take`, a binary search per
+output lane, kept for the plane auditor and as the tests' oracle) runs
+one dependent gather per lane per search round: on a v5e at
+``L = 17, W = 131072`` it took 451 ms a call where the sort takes
+4.4 ms (a scatter of each payload to its prefix count took 33 ms).
+The epoch's newly inserted keys are ordered by ``splaylist.key_order``
+and the smallest ``max_new`` kept.
 
 Shape-stability contract: a plane's ``(n_levels, width)`` is fixed at
 creation and every ``refresh_device`` preserves it, so jit caches
@@ -128,10 +133,11 @@ def _assemble_device(keys_sorted: jax.Array, rel_h: jax.Array,
     """The mask/prefix-sum construction of ``level_arrays._assemble`` on
     device: ``keys_sorted`` [W] holds the live keys sorted ascending in a
     prefix, PAD_KEY after; ``rel_h``/``slots`` [W] are aligned (pad lanes
-    ignored).  Row compaction is gather-only: the in-row position is the
-    prefix count (as on host), and the member picked for output lane
-    (r, j) is the inverse of that prefix sum — one vmapped searchsorted
-    instead of an [L, W] scatter."""
+    ignored).  Row compaction is one sort along the width axis: each
+    lane's key is its column, lifted by ``W`` where the row does not
+    hold it, so the row's members come first in bottom-row order, and
+    the sort carries the bottom-row key and the next row's prefix count
+    with them — nothing is gathered afterwards."""
     width = keys_sorted.shape[0]
     alive = keys_sorted != PAD_KEY
     h = jnp.where(alive, rel_h, -1)
@@ -149,16 +155,17 @@ def _assemble_device(keys_sorted: jax.Array, rel_h: jax.Array,
     cs_next = jnp.concatenate(
         [cs[1:], jnp.ones((1, width), jnp.int32)], axis=0)
     with jax.named_scope("splay.compact"):
-        take = jax.vmap(functools.partial(_compact_take, width=width))(cs)
-        rows = jnp.where(live, jnp.take(keys_sorted, take), PAD_KEY)
-        rank_live = jnp.take_along_axis(cs_next, take, axis=1) - 1
-        # bottom rank rides the same compaction gather: keys_sorted IS
-        # the bottom row, so the member picked for lane (r, j) sits in
-        # the bottom row at its keys_sorted index — `take` itself.
+        # the sorted lane keys on live lanes are `take`, the bottom-row
+        # index of the member at (r, j) — which is also its bottom rank
+        take, cs_taken, rows = jax.lax.sort(
+            (jnp.where(mask, col, width + col), cs_next,
+             jnp.broadcast_to(keys_sorted, (n_levels, width))),
+            dimension=1, num_keys=1, is_stable=False)
+        rows = jnp.where(live, rows, PAD_KEY)
         bot_rank = jnp.where(live, take, widths[n_levels - 1])
     pad_default = jnp.concatenate(
         [widths[1:], jnp.zeros((1,), jnp.int32)])
-    rank_map = jnp.where(live, rank_live, pad_default[:, None])
+    rank_map = jnp.where(live, cs_taken - 1, pad_default[:, None])
     rank_map = rank_map.at[n_levels - 1].set(col)
 
     heights = jnp.where(alive, rel_h, 0).astype(jnp.int32)
@@ -239,50 +246,27 @@ def from_state_device(st: sx.SplayState, n_levels: int,
 
 
 def _merge_rows(bottom, surv, old_h, slots_eff, ns, new_h, new_slots,
-                n_new, width, kk, out_len=None):
+                out_len):
     """Two-way merge of the surviving previous bottom row with the
-    sorted inserted keys, gather-only: compact the survivors (inverse
-    prefix sum), place each survivor at (survivors before it) + (new
-    keys below it), and read the merged row back through one
-    searchsorted over those positions.
+    sorted inserted keys: one sort of the ``[W + kk]`` concatenation of
+    the survivors (dead lanes PAD_KEY) and ``ns``, carrying heights and
+    slots.  Inserted keys are never in the previous bottom row, so live
+    keys never tie; the ``n_old + n_new`` live keys come first and every
+    lane after them is PAD_KEY (their heights and slots are unspecified
+    and never read).
 
     ``out_len`` is the emitted row length — ``width`` for the replicated
     refresh (merged lanes beyond it are truncated, flagged upstream as
     overflow), ``width + kk`` for the per-shard merge of the sharded
     refresh, whose local segment must never truncate (the global
     redistribution repacks it)."""
-    if out_len is None:
-        out_len = width
-    col = jnp.arange(out_len, dtype=jnp.int32)
-    surv_i = surv.astype(jnp.int32)
-    cs_s = jnp.cumsum(surv_i)
-    n_old = cs_s[width - 1]
-    acol = jnp.arange(width, dtype=jnp.int32)
     with jax.named_scope("splay.compact"):
-        take_a = _compact_take(cs_s, width)
-        a_k = jnp.where(acol < n_old, jnp.take(bottom, take_a), PAD_KEY)
-        a_h = jnp.take(old_h, take_a)
-        a_s = jnp.take(slots_eff, take_a)
-
-    # merged position of survivor i; strictly increasing (pad lanes
-    # continue past the live prefix), so it is searchsorted-invertible
-    pos_a = (acol + jnp.searchsorted(ns, a_k).astype(jnp.int32))
-    with jax.named_scope("splay.compact"):
-        a_of = jnp.searchsorted(pos_a, col).astype(jnp.int32)
-        a_ofc = jnp.minimum(a_of, width - 1)
-        from_a = jnp.take(pos_a, a_ofc) == col
-        b_of = jnp.minimum(col - jnp.minimum(a_of, col), kk - 1)
-
-        n_tot = n_old + n_new
-        merged_k = jnp.where(
-            col < n_tot,
-            jnp.where(from_a, jnp.take(a_k, a_ofc), jnp.take(ns, b_of)),
-            PAD_KEY)
-        merged_h = jnp.where(from_a, jnp.take(a_h, a_ofc),
-                             jnp.take(new_h, b_of))
-        merged_s = jnp.where(from_a, jnp.take(a_s, a_ofc),
-                             jnp.take(new_slots, b_of))
-    return merged_k, merged_h, merged_s
+        merged = jax.lax.sort(
+            (jnp.concatenate([jnp.where(surv, bottom, PAD_KEY), ns]),
+             jnp.concatenate([old_h, new_h]),
+             jnp.concatenate([slots_eff, new_slots])),
+            num_keys=1, is_stable=False)
+    return tuple(m[:out_len] for m in merged)
 
 
 @functools.partial(jax.jit,
@@ -299,11 +283,12 @@ def refresh_device(st: sx.SplayState, prev: DeviceLevelArrays,
       2. surviving old keys keep their relative order — their heights
          come back through the plane's slot map (pure gathers); deleted
          keys are masked out by absence;
-      3. the newly inserted keys are extracted *sorted* by one bounded
-         ``top_k`` (``max_new`` — size it by the number of inserts since
+      3. the newly inserted keys are extracted *sorted*, at most
+         ``max_new`` of them (size it by the number of inserts since
          the last refresh; the *smallest* keys are kept, inserts beyond
          the bound are dropped from the plane until the next full
-         build), then placed by mirrored rank arithmetic;
+         build), then merged with the survivors by one sort that
+         carries heights and slots;
       4. the prefix-sum re-layering reruns on the merged row.
 
     The slot map is validated against the state (``rebuild`` compacts
@@ -388,7 +373,7 @@ def refresh_device(st: sx.SplayState, prev: DeviceLevelArrays,
                                         operand=None)
 
     # height-only epoch (the common serving case): the merge is the
-    # identity over the previous bottom row — skip the rank arithmetic
+    # identity over the previous bottom row — skip the merge sort
     n_old = jnp.sum(surv.astype(jnp.int32))
 
     def identity_merge(_):
@@ -396,7 +381,7 @@ def refresh_device(st: sx.SplayState, prev: DeviceLevelArrays,
 
     def merge(_):
         return _merge_rows(bottom, surv, old_h, slots_eff, ns, new_h,
-                           new_slots, n_new, width, kk)
+                           new_slots, width)
 
     merged_k, merged_h, merged_s = jax.lax.cond(
         (n_new == 0) & (n_old == w_bot), identity_merge, merge,
@@ -439,8 +424,9 @@ def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
     blocks) + O(W) transient bottom-row/composed-row buffers (the
     [L, W] rectangle is never materialized on one shard — the composed
     prefix sum streams one row per scan step); compute for the per-lane
-    stages (classification gathers, merge, compaction searchsorted,
-    rank emission) O((L·W/S)·log W + capacity); wire O(W + S·max_new)
+    stages (classification gathers, compaction searchsorted, rank
+    emission) O((L·W/S)·log W + capacity), the local merge one sort of
+    W/S + max_new lanes; wire O(W + S·max_new)
     for the segment exchange plus O(W) received per level row of the
     streamed composition."""
     S = n_shards
@@ -535,8 +521,7 @@ def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
     # global repack below owns the width-overflow accounting)
     m_len = wl + kk
     seg_k, seg_h, seg_s = _merge_rows(
-        bot_l, surv, old_h, slots_eff, ns, new_h, new_slots,
-        n_new, wl, kk, out_len=m_len)
+        bot_l, surv, old_h, slots_eff, ns, new_h, new_slots, m_len)
     c = jnp.sum(surv.astype(jnp.int32)) + n_new
 
     # ---- redistribution: exclusive scan of segment counts composes the

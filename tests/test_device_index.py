@@ -328,3 +328,138 @@ def test_from_state_device_pads_small_states():
         jnp.asarray(np.asarray([6], np.int32)), jnp.ones((1,), bool))
     plane = dix.refresh_device(st, plane, max_new=8)
     _assert_plane_equal(plane, la.from_state(st, min_levels=12, width=256))
+
+
+# ---------------------------------------------------------------------------
+# row compaction and insert merge by sort, against the binary-search oracle
+# ---------------------------------------------------------------------------
+
+def _assemble_by_search(keys_sorted, rel_h, n_levels):
+    """The plane's rows by the inverse prefix sum: ``_compact_take``'s
+    binary search per output lane, then numpy gathers through it.
+    Returns ``(keys, widths, rank_map, bot_rank, live)``."""
+    ks, h = np.asarray(keys_sorted), np.asarray(rel_h)
+    width = ks.size
+    h = np.where(ks != dix.PAD_KEY, h, -1)
+    mask = h[None, :] >= (n_levels - 1 - np.arange(n_levels))[:, None]
+    cs = np.cumsum(mask, axis=1).astype(np.int32)
+    widths = cs[:, -1]
+    take = np.stack([np.asarray(dix._compact_take(jnp.asarray(c), width))
+                     for c in cs])
+    live = np.arange(width)[None, :] < widths[:, None]
+    keys = np.where(live, ks[take], dix.PAD_KEY)
+    cs_next = np.concatenate([cs[1:], np.ones((1, width), np.int32)])
+    rank_map = np.where(live, np.take_along_axis(cs_next, take, 1) - 1,
+                        np.append(widths[1:], 0)[:, None])
+    rank_map[-1] = np.arange(width)
+    return keys, widths, rank_map, take, live
+
+
+@pytest.mark.parametrize("n,width,n_levels,heights", [
+    (0, 64, 4, "geometric"),            # empty plane
+    (256, 256, 6, "geometric"),         # full width
+    (100, 128, 5, "top"),               # every key in the top row
+    (211, 300, 7, "geometric"),         # width not a power of two
+    (90, 128, 12, "low"),               # levels above the largest height
+    (120, 160, 5, "high"),              # heights past the top saturate
+])
+def test_sorted_compaction_matches_search_oracle(n, width, n_levels,
+                                                 heights):
+    rng = np.random.default_rng(n + width)
+    ks = np.full(width, dix.PAD_KEY, np.int32)
+    ks[:n] = np.sort(rng.choice(10 ** 6, n, replace=False))
+    h = {"geometric": np.minimum(rng.geometric(0.5, width) - 1,
+                                 n_levels - 1),
+         "top": np.full(width, n_levels - 1),
+         "low": rng.integers(0, 3, width),
+         "high": rng.integers(0, 2 * n_levels, width)}[heights]
+    h = h.astype(np.int32)
+    slots = rng.permutation(width).astype(np.int32)
+    plane = jax.jit(dix._assemble_device, static_argnums=3)(
+        jnp.asarray(ks), jnp.asarray(h), jnp.asarray(slots), n_levels)
+    keys, widths, rank_map, bot_rank, live = _assemble_by_search(
+        ks, h, n_levels)
+    np.testing.assert_array_equal(np.asarray(plane.keys), keys)
+    np.testing.assert_array_equal(np.asarray(plane.widths), widths)
+    np.testing.assert_array_equal(np.asarray(plane.rank_map), rank_map)
+    np.testing.assert_array_equal(np.asarray(plane.bot_rank)[live],
+                                  bot_rank[live])
+    np.testing.assert_array_equal(np.asarray(plane.heights),
+                                  np.where(ks != dix.PAD_KEY, h, 0))
+    np.testing.assert_array_equal(np.asarray(plane.slots)[:n], slots[:n])
+    np.testing.assert_array_equal(np.asarray(plane.local_bot), ks)
+    np.testing.assert_array_equal(np.asarray(plane.local_live),
+                                  (ks != dix.PAD_KEY).astype(np.int32))
+    assert int(plane.local_ok[0]) == 0
+    assert int(widths[-1]) == n
+
+
+def _slot_state(keys, heights, deleted, cap, max_level):
+    """A state holding ``keys`` in slots ``2 ..`` with relative heights
+    ``heights``, ``deleted`` marking slots dead: the fields the refresh
+    reads (``key``, ``top``, ``zl``, ``deleted``, ``n_alloc``)."""
+    st = sx.make(capacity=cap, max_level=max_level)
+    n = len(keys)
+    key = np.asarray(st.key).copy()
+    top = np.asarray(st.top).copy()
+    dead = np.zeros(cap, bool)
+    key[2:2 + n] = keys
+    top[2:2 + n] = int(st.zl) + np.asarray(heights)
+    dead[2:2 + n] = deleted
+    return st._replace(key=jnp.asarray(key), top=jnp.asarray(top),
+                       deleted=jnp.asarray(dead),
+                       n_alloc=jnp.int32(2 + n))
+
+
+@pytest.mark.parametrize("case", [
+    "new_fill_max_new",       # n_new == max_new
+    "truncated",              # n_old + n_new > width
+    "over_max_new",           # more inserts than max_new
+    "all_survivors_deleted",
+    "inserts_outside",        # below the first key and above the last
+])
+def test_refresh_merge_by_sort(case):
+    """One incremental refresh with inserts against the host build of
+    the keys it can hold: the merged bottom row, every row above it,
+    the live bottom ranks and slots, and the overflow count."""
+    cap, L, W, kk = 128, 6, 48, 8
+    rng = np.random.default_rng(len(case))
+    n_old = {"truncated": 44}.get(case, 30)
+    old = rng.choice(np.arange(100, 1000, 2), n_old, replace=False)
+    new = rng.choice(np.arange(101, 1000, 2),
+                     {"over_max_new": 12}.get(case, kk), replace=False)
+    if case == "inserts_outside":
+        new = np.asarray([3, 50, 99, 1001, 5000, 2 ** 31 - 2])
+    dead = np.zeros(n_old, bool)
+    if case == "all_survivors_deleted":
+        dead[:] = True
+    elif case != "truncated":
+        dead[rng.choice(n_old, 3, replace=False)] = True
+    keys = np.concatenate([old, new]).astype(np.int32)
+    hts = rng.integers(0, L, keys.size).astype(np.int32)
+    st0 = _slot_state(old, hts[:n_old], np.zeros(n_old, bool), cap, L)
+    plane0 = dix.from_state_device(st0, n_levels=L, width=W)
+    st1 = _slot_state(keys, hts, np.concatenate(
+        [dead, np.zeros(new.size, bool)]), cap, L)
+    plane, ovf = dix.refresh_device(st1, plane0, max_new=kk,
+                                    return_overflow=True)
+
+    # what the plane can hold: the survivors and the smallest max_new
+    # inserts, the smallest `W` of those
+    kept_new = np.argsort(new)[:kk]
+    held = np.concatenate([np.nonzero(~dead)[0], n_old + kept_new])
+    held = held[np.argsort(keys[held])]
+    expect_ovf = (new.size - kept_new.size) + max(held.size - W, 0)
+    held = held[:W]
+    assert int(ovf) == expect_ovf
+    _assert_plane_equal(plane, la.build(keys[held], hts[held],
+                                        min_levels=L, width=W), case)
+    w_bot = int(plane.widths[-1])
+    bottom = np.asarray(plane.keys)[-1]
+    np.testing.assert_array_equal(np.asarray(plane.slots)[:w_bot],
+                                  2 + held)
+    rows = np.asarray(plane.keys)
+    live = np.arange(W)[None, :] < np.asarray(plane.widths)[:, None]
+    np.testing.assert_array_equal(
+        np.asarray(plane.bot_rank)[live],
+        np.searchsorted(bottom[:w_bot], rows[live]))

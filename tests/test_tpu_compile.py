@@ -6,7 +6,9 @@ compiler, so these tests compile the kernels ahead of time for a
 width (``W = 131072``, ``L = 17``) and at ``W = 4096``, and the routed
 sharded search on a 4-device mesh of the same topology.  Each asserts
 that the kernel made it into the executable (``tpu_custom_call``).
-The pipelined descent is interpret-only and has no test here.
+The pipelined descent is interpret-only and has no test here.  The
+plane refresh is compiled at the same deployment shape, to check that
+its row compaction stays a sort with no per-lane gather.
 
 The topology is described inside a fixture (never at import), so every
 test worker collects the same tests and only the one running this file
@@ -14,7 +16,9 @@ loads the TPU compiler; the fixture skips where no topology can be
 described.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import device_index as dix
+from repro.core import splaylist as sx
 from repro.kernels import splay_search as ssk
 from repro.launch.mesh import make_auto_mesh
 from repro.parallel import sharding as shd
@@ -92,3 +97,29 @@ def test_routed_sharded_search_compiles(topo):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-to-all" in text
+
+
+def test_refresh_compaction_sorts_without_per_lane_gathers(topo):
+    """The plane refresh at the paper's cell shape lays its rows out by
+    a sort along the width axis: no gather in the compiled program has
+    one index per lane of the ``[L, W]`` rectangle (a binary search per
+    output lane, or a gather through a compaction permutation, has)."""
+    n_levels, width, capacity, max_new = 17, 131072, 131074, 1024
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            tree)
+
+    st = place(jax.eval_shape(lambda: sx.make(capacity, n_levels)))
+    plane = place(_plane_shapes(n_levels, width))
+    text = dix.refresh_device.lower(
+        st, plane, max_new=max_new).compile().as_text()
+    gathers = [math.prod(int(d) for d in dims.split(",") if d)
+               for dims in re.findall(r"= \w+\[([\d,]*)\]\S* gather\(",
+                                      text)]
+    assert gathers, "no gather parsed: the HLO text format changed"
+    assert n_levels * width not in gathers
+    rect = rf"s32\[{n_levels},{width}\]"
+    assert re.search(rf"= \({rect}\S*(, {rect}\S*)*\) sort\(", text)
