@@ -398,9 +398,9 @@ def refresh_device(st: sx.SplayState, prev: DeviceLevelArrays,
 # width-sharded refresh (DESIGN.md §5.4): the same pipeline under shard_map
 # ---------------------------------------------------------------------------
 
-def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
-                        axis: str, n_shards: int, n_levels: int,
-                        width: int, max_new: int, split: str = "lanes"):
+def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
+                          axis: str, n_shards: int, n_levels: int,
+                          width: int, max_new: int, split: str = "lanes"):
     """Per-shard body of :func:`refresh_device_sharded` (runs under
     ``shard_map``; ``prev`` leaves are this shard's blocks, the state is
     replicated).  Stages mirror the replicated refresh — classification,
@@ -419,6 +419,12 @@ def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
          arbitrarily far (a delete burst can empty whole shards), so the
          packed global bottom row is rebuilt from the bounded per-shard
          segments rather than fixed-radius halos.
+
+    Every collective and the arithmetic that composes its result sit in
+    the ``splay.redistribute`` scope, so a profile tells the exchange
+    apart from each shard's own classification, merge and compaction.
+    The function's name carries ``refresh_device``: a profile reader
+    that names layers by jitted function puts it in the refresh.
 
     Budget per shard and epoch: resident state O(L·W/S) (its plane
     blocks) + O(W) transient bottom-row/composed-row buffers (the
@@ -449,12 +455,13 @@ def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
     # The same helper builds the search's query-routing table — refresh
     # and search must agree on ownership for every layout.
     from repro.parallel import sharding as shd
-    raw = jax.lax.all_gather(
-        jnp.where(ax == 0, jnp.int32(sx.NEG_INF_32), bot_l[0]), axis)
-    bounds = shd.suffix_min_bounds(raw)
-    lo = bounds[ax]
-    hi = jnp.where(ax == S - 1, jnp.int32(PAD_KEY),
-                   bounds[jnp.minimum(ax + 1, S - 1)])
+    with jax.named_scope("splay.redistribute"):
+        raw = jax.lax.all_gather(
+            jnp.where(ax == 0, jnp.int32(sx.NEG_INF_32), bot_l[0]), axis)
+        bounds = shd.suffix_min_bounds(raw)
+        lo = bounds[ax]
+        hi = jnp.where(ax == S - 1, jnp.int32(PAD_KEY),
+                       bounds[jnp.minimum(ax + 1, S - 1)])
 
     # ---- slot-map validation (staleness is a global verdict, psum'd,
     # so every shard takes the same branch as the replicated refresh).
@@ -464,8 +471,9 @@ def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
     lane = col_l < jnp.sum((bot_l != PAD_KEY).astype(jnp.int32))
     sc = jnp.clip(prev.slots, 0, cap - 1)
     match = lane & (jnp.take(st.key, sc).astype(jnp.int32) == bot_l)
-    stale = jax.lax.psum(
-        jnp.any(lane & ~match).astype(jnp.int32), axis) > 0
+    with jax.named_scope("splay.redistribute"):
+        stale = jax.lax.psum(
+            jnp.any(lane & ~match).astype(jnp.int32), axis) > 0
 
     # ---- state-side classification, restricted to the owned range
     k_slot, _ = _alive_slots(st)
@@ -498,9 +506,10 @@ def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
     # an exclusive scan of raw counts reproduces the replicated drop
     # semantics exactly.
     raw = jnp.sum(is_new.astype(jnp.int32))
-    raws = jax.lax.all_gather(raw, axis)               # [S]
-    left = jnp.sum(jnp.where(jnp.arange(S) < ax, raws, 0))
-    total_raw = jnp.sum(raws)
+    with jax.named_scope("splay.redistribute"):
+        raws = jax.lax.all_gather(raw, axis)           # [S]
+        left = jnp.sum(jnp.where(jnp.arange(S) < ax, raws, 0))
+        total_raw = jnp.sum(raws)
     n_new = jnp.clip(kk - left, 0, jnp.minimum(raw, kk))
 
     def extract_new(_):
@@ -527,20 +536,22 @@ def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
     # ---- redistribution: exclusive scan of segment counts composes the
     # global packed bottom row; each output lane gathers from the shard
     # segment that covers its global rank
-    counts = jax.lax.all_gather(c, axis)               # [S]
-    cum = jnp.cumsum(counts)
-    offs = cum - counts
-    total = cum[S - 1]
-    segs_k = jax.lax.all_gather(seg_k, axis)           # [S, m_len]
-    segs_h = jax.lax.all_gather(seg_h, axis)
-    segs_s = jax.lax.all_gather(seg_s, axis)
+    with jax.named_scope("splay.redistribute"):
+        counts = jax.lax.all_gather(c, axis)           # [S]
+        cum = jnp.cumsum(counts)
+        offs = cum - counts
+        total = cum[S - 1]
+        segs_k = jax.lax.all_gather(seg_k, axis)       # [S, m_len]
+        segs_h = jax.lax.all_gather(seg_h, axis)
+        segs_s = jax.lax.all_gather(seg_s, axis)
 
     def pick(segs, pos, fill):
-        t = jnp.searchsorted(cum, pos, side="right").astype(jnp.int32)
-        tc = jnp.clip(t, 0, S - 1)
-        li = jnp.clip(pos - jnp.take(offs, tc), 0, m_len - 1)
-        v = jnp.take(segs.reshape(S * m_len), tc * m_len + li)
-        return jnp.where(pos < total, v, fill)
+        with jax.named_scope("splay.redistribute"):
+            t = jnp.searchsorted(cum, pos, side="right").astype(jnp.int32)
+            tc = jnp.clip(t, 0, S - 1)
+            li = jnp.clip(pos - jnp.take(offs, tc), 0, m_len - 1)
+            v = jnp.take(segs.reshape(S * m_len), tc * m_len + li)
+            return jnp.where(pos < total, v, fill)
 
     pos_g = jnp.arange(width, dtype=jnp.int32)
     keys_g = pick(segs_k, pos_g, jnp.int32(PAD_KEY))   # [W] merged row
@@ -584,7 +595,8 @@ def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
         h_seg = jnp.where(seg_live, jnp.take(hts_g, src), 0)
         s_seg = jnp.where(seg_live, jnp.take(slot_g, src), -1)
         local = _assemble_device(k_seg, h_seg, s_seg, n_levels)
-        widths_g = jax.lax.psum(local.widths, axis)
+        with jax.named_scope("splay.redistribute"):
+            widths_g = jax.lax.psum(local.widths, axis)
         # keys/rank_map/bot_rank ARE this shard's local sub-plane here —
         # record the segment they were assembled from and set the
         # residency bit, so the sharded search consumes them directly
@@ -614,9 +626,10 @@ def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
     mask_own = h_own[None, :] >= row_min_h[:, None]    # [L, wl]
     cs_own = jnp.cumsum(mask_own, axis=1, dtype=jnp.int32)
     tot_own = cs_own[:, wl - 1]                        # [L]
-    tots = jax.lax.all_gather(tot_own, axis)           # [S, L]
-    row_offs = jnp.cumsum(tots, axis=0) - tots         # [S, L] exclusive
-    widths_g = jnp.sum(tots, axis=0)                   # [L] global
+    with jax.named_scope("splay.redistribute"):
+        tots = jax.lax.all_gather(tot_own, axis)       # [S, L]
+        row_offs = jnp.cumsum(tots, axis=0) - tots     # [S, L] exclusive
+        widths_g = jnp.sum(tots, axis=0)               # [L] global
 
     # ---- own output columns, one row per scan step: compaction gather
     # + rank emission.  The member for a global output lane can live in
@@ -625,8 +638,9 @@ def _refresh_shard_body(st: sx.SplayState, prev: DeviceLevelArrays, *,
     # sum, i.e. the NEXT step's cs_row — carried via prev_take.
     def level_step(prev_take, inp):
         cs_own_r, offs_r = inp                         # [wl], [S]
-        blocks = jax.lax.all_gather(cs_own_r, axis)    # [S, wl]
-        cs_row = (blocks + offs_r[:, None]).reshape(width)
+        with jax.named_scope("splay.redistribute"):
+            blocks = jax.lax.all_gather(cs_own_r, axis)    # [S, wl]
+            cs_row = (blocks + offs_r[:, None]).reshape(width)
         take_r = jnp.minimum(
             jnp.searchsorted(cs_row, col_g + 1).astype(jnp.int32),
             width - 1)
@@ -673,7 +687,7 @@ def _sharded_refresh_fn(mesh, axis: str, n_levels: int, width: int,
     S = mesh.shape[axis]
     specs = shd.index_plane_specs(DeviceLevelArrays, axis)
     body = functools.partial(
-        _refresh_shard_body, axis=axis, n_shards=S, n_levels=n_levels,
+        _refresh_device_shard, axis=axis, n_shards=S, n_levels=n_levels,
         width=width, max_new=max_new, split=split)
     fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), specs),
                        out_specs=(specs, P()), check_vma=False)

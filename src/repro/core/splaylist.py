@@ -75,8 +75,12 @@ TAIL = 1
 #   plane_rebuilds      epochs whose plane came from a full rebuild
 #   plane_rows_rebuilt  plane rows the epochs' refreshes recomputed
 #   plane_rows_changed  plane rows whose keys or width an epoch changed
+#   route_queries       lanes sent through the routed query exchange
+#                       (the width-sharded plane search)
+#   route_spilled       of them, lanes answered on its spill path
 COUNTERS = ("epochs", "fold_steps", "fold_active", "state_rebuilds",
-            "plane_rebuilds", "plane_rows_rebuilt", "plane_rows_changed")
+            "plane_rebuilds", "plane_rows_rebuilt", "plane_rows_changed",
+            "route_queries", "route_spilled")
 
 
 class SplayState(NamedTuple):
@@ -818,6 +822,41 @@ def _check_plane_dispatch(plane, mesh, axis, split):
             "rebuild with from_state_device before meshless serving")
 
 
+def _place(st: SplayState, plane, mesh, axis):
+    """The ``(state, plane, mesh)`` an epoch runs on.  An explicit
+    ``mesh`` wins; ``None`` means the plane's own width-sharded mesh
+    (``sharding.plane_width_mesh``).  A concrete plane on no such mesh
+    and wider than ``splay_search.MAX_DESCENT_WIDTH`` — the widest plane
+    one device's descent compiles — is laid out width-sharded
+    (``sharding.shard_index_plane``) over the ``(1, S)`` mesh of the
+    fewest local devices whose blocks fit (``sharding.width_mesh``), the
+    state replicated on the same mesh; the epochs keep that layout, so
+    this happens once.  A plane that fits stays where it is, whatever
+    the device count.  Raises ``ValueError`` when a device would have to
+    descend a block wider than the limit."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.kernels import splay_search as ssk
+    from repro.parallel import sharding as shd
+    width = plane.keys.shape[1]
+    limit = ssk.MAX_DESCENT_WIDTH
+    if mesh is None:
+        mesh = shd.plane_width_mesh(plane, axis)
+    if (mesh is None and width > limit
+            and not isinstance(plane.keys, jax.core.Tracer)):
+        mesh = shd.width_mesh(width, limit, axis)
+        plane = shd.shard_index_plane(plane, mesh, axis)
+        st = jax.device_put(st, NamedSharding(mesh, P()))
+    shards = (mesh.shape[axis] if mesh is not None and axis in mesh.shape
+              and width % mesh.shape[axis] == 0 else 1)
+    if width // shards > limit:
+        raise ValueError(
+            f"a {width}-lane plane in {shards} block(s) leaves "
+            f"{width // shards} lanes to one device's descent, more than "
+            f"the {limit} it compiles for: pass a mesh with more devices "
+            f"on the '{axis}' axis")
+    return st, plane, mesh
+
+
 def _check_route_args(route_capacity, route_slack):
     """Host-side guard for the routed exchange's sizing knobs, applied
     even on meshless runs (where they are inert) so nonsense never jits
@@ -877,7 +916,14 @@ def _run_epoch(st: SplayState, plane, kinds, keys, upd_mask,
     the exchange's per-shard receive block
     (``kernels.splay_search.route_capacity`` by default); queries past
     it spill to the masked full-batch trace — answers stay exact, the
-    epoch just pays the replicated-trace cost for that batch.
+    epoch just pays the replicated-trace cost for that batch.  In the
+    host wrappers ``run_epoch``/``run_serving``, ``mesh=None`` means the
+    plane's own mesh, and a plane wider than
+    ``kernels.splay_search.MAX_DESCENT_WIDTH`` is laid out width-sharded
+    over the local devices on the first call (``_place``); with a mesh
+    the state and the batch stay replicated, so the fold runs whole on
+    every device.  ``route_queries``/``route_spilled`` count the lanes
+    the routed exchange sent and spilled.
 
     ``plane_search`` (static; requires ``aggregate=True`` — the whole
     batch must be read-only: ``OP_CONTAINS`` lanes, plus
@@ -941,6 +987,12 @@ def _run_epoch(st: SplayState, plane, kinds, keys, upd_mask,
     n_levels, width = plane.keys.shape
     sharded = (mesh is not None and axis in mesh.shape
                and width % mesh.shape[axis] == 0)
+    if sharded:
+        # the state and the batch stay whole on every device: the fold
+        # runs as on one chip, with no collective inside it
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        st, kinds, keys, upd_mask = jax.lax.with_sharding_constraint(
+            (st, kinds, keys, upd_mask), NamedSharding(mesh, P()))
     spill = jnp.zeros((), jnp.int32)
     occupancy = jnp.zeros((1,), jnp.int32)
     if plane_search:
@@ -1017,17 +1069,21 @@ def _run_epoch(st: SplayState, plane, kinds, keys, upd_mask,
     # every refresh recomputes all rows; count those it actually changed
     changed = jnp.sum(jnp.any(plane.keys != plane_in.keys, axis=1)
                       | (plane.widths != plane_in.widths))
+    routed_lanes = keys.shape[0] if (plane_search and sharded
+                                     and routed) else 0
     st = _count(st, epochs=1, plane_rebuilds=rebuild,
-                plane_rows_rebuilt=n_levels, plane_rows_changed=changed)
+                plane_rows_rebuilt=n_levels, plane_rows_changed=changed,
+                route_queries=routed_lanes, route_spilled=spill)
     if sharded:
         # keep the carry in the width-sharded layout whichever branch
-        # produced it (the rebuild branch is replicated math)
-        from jax.sharding import NamedSharding
+        # produced it (the rebuild branch is replicated math), and the
+        # state replicated
         from repro.parallel import sharding as shd
         specs = shd.index_plane_specs(type(plane), axis)
         plane = type(plane)(*(
             jax.lax.with_sharding_constraint(x, NamedSharding(mesh, s))
             for x, s in zip(plane, specs)))
+        st = jax.lax.with_sharding_constraint(st, NamedSharding(mesh, P()))
     return st, plane, res, plen, overflow, spill, occupancy
 
 
@@ -1039,6 +1095,7 @@ def run_epoch(st: SplayState, plane, kinds, keys, upd_mask,
               ordered: bool = False, routed: bool = True):
     span = dict(epochs=1, batch=np.shape(keys)[0])
     with jax.profiler.TraceAnnotation("splay.serve.guard", **span):
+        st, plane, mesh = _place(st, plane, mesh, axis)
         _check_plane_dispatch(plane, mesh, axis, split)
         _check_route_args(route_capacity, route_slack)
     with jax.profiler.TraceAnnotation("splay.serve.dispatch", **span):
@@ -1087,6 +1144,10 @@ def _run_serving(st: SplayState, plane, kinds, keys, upd_mask,
     boundaries at the hit-counter mass quantiles, so the exchange's
     occupancy tracks the workload as it drifts (a rebuild-recovery
     epoch emits the packed layout; the next refresh re-splits).
+
+    ``mesh=None`` in ``run_serving`` means the plane's own mesh, as in
+    :func:`run_epoch` (a plane too wide for one device's descent is
+    laid out width-sharded on the first call).
 
     Overflow state machine (DESIGN.md §5.4): an epoch whose refresh
     reports nonzero overflow arms a pending flag, and the *next*
@@ -1138,6 +1199,7 @@ def run_serving(st: SplayState, plane, kinds, keys, upd_mask,
     n_epochs, batch = np.shape(keys)
     span = dict(epochs=n_epochs, batch=batch)
     with jax.profiler.TraceAnnotation("splay.serve.guard", **span):
+        st, plane, mesh = _place(st, plane, mesh, axis)
         _check_plane_dispatch(plane, mesh, axis, split)
         _check_route_args(route_capacity, route_slack)
     with jax.profiler.TraceAnnotation("splay.serve.dispatch", **span):

@@ -100,6 +100,13 @@ PAD_KEY = 2 ** 31 - 1
 NEG_INF_KEY = -(2 ** 31) + 1        # splaylist.NEG_INF_32 (head sentinel)
 DEFAULT_QUERY_BLOCK = 256
 DEFAULT_ROUTE_SLACK = 1.5
+# The widest plane the one-chip tiered descent compiles on a TPU v5e:
+# its row block, sample row and one-hot chunk fetch fit the scoped VMEM
+# at 2^20 lanes, and Mosaic refuses 2^21 and wider (RESOURCE_EXHAUSTED
+# ... vmem).  A wider plane is served width-sharded, each device
+# descending a block of at most this many lanes
+# (``splaylist.run_serving``).
+MAX_DESCENT_WIDTH = 2 ** 20
 
 
 class RouteStats(NamedTuple):
@@ -990,78 +997,84 @@ def _routed_shard_body(plane, q_loc, *, axis: str, n_shards: int,
     ax = jax.lax.axis_index(axis).astype(jnp.int32)
     fill = jnp.int32(PAD_KEY - 1)                      # inert query value
 
-    bot = plane.keys[n_levels - 1]
-    bounds, lifts = _route_tables(bot, axis)
-    lift = lifts[ax]
     local, assembled = _local_subplane(plane, n_levels=n_levels)
 
-    # ---- 1. owner-bucket the local slice.  Batch-padding fill lanes
-    # (global index >= n_live, appended by the wrapper when q % S != 0)
-    # get owner -1: never bucketed, never exchanged, never counted in
-    # the pair-count matrix — so occupancy and spill reflect real
-    # queries only, and pads can't push a shard over capacity.
-    gidx = ax * qs + jnp.arange(qs, dtype=jnp.int32)
-    owner = jnp.where(gidx < n_live, _owner_of(bounds, q_loc),
-                      jnp.int32(-1))                   # [qs]
-    onehot = (owner[:, None]
-              == jnp.arange(S, dtype=jnp.int32)[None, :])
-    cs = jnp.cumsum(onehot.astype(jnp.int32), axis=0)  # [qs, S]
-    cnt = cs[qs - 1]                                   # [S] per-dest count
-    pos = jnp.take_along_axis(cs, owner[:, None].astype(jnp.int32),
-                              axis=1)[:, 0] - 1        # bucket position
-    lane = jnp.arange(capacity, dtype=jnp.int32)
+    # steps 1, 2 and 4 are the exchange, scoped ``splay.route``: the
+    # routing table, owner bucketing, the count all_gather and the query
+    # and answer all_to_alls
+    with jax.named_scope("splay.route"):
+        bounds, lifts = _route_tables(plane.keys[n_levels - 1], axis)
+        lift = lifts[ax]
 
-    def bucket(cs_d):
-        # inverse prefix sum: lane c of dest d's bucket holds the c-th
-        # owned query (same gather formulation as _compact_take)
-        take = jnp.minimum(
-            jnp.searchsorted(cs_d, lane + 1).astype(jnp.int32), qs - 1)
-        return jnp.take(q_loc, take)
+        # ---- 1. owner-bucket the local slice.  Batch-padding fill lanes
+        # (global index >= n_live, appended by the wrapper when q % S != 0)
+        # get owner -1: never bucketed, never exchanged, never counted in
+        # the pair-count matrix — so occupancy and spill reflect real
+        # queries only, and pads can't push a shard over capacity.
+        gidx = ax * qs + jnp.arange(qs, dtype=jnp.int32)
+        owner = jnp.where(gidx < n_live, _owner_of(bounds, q_loc),
+                          jnp.int32(-1))               # [qs]
+        onehot = (owner[:, None]
+                  == jnp.arange(S, dtype=jnp.int32)[None, :])
+        cs = jnp.cumsum(onehot.astype(jnp.int32), axis=0)   # [qs, S]
+        cnt = cs[qs - 1]                               # [S] per-dest count
+        pos = jnp.take_along_axis(cs, owner[:, None].astype(jnp.int32),
+                                  axis=1)[:, 0] - 1    # bucket position
+        lane = jnp.arange(capacity, dtype=jnp.int32)
 
-    send = jnp.where(lane[None, :] < jnp.minimum(cnt, capacity)[:, None],
-                     jax.vmap(bucket)(jnp.transpose(cs)), fill)
+        def bucket(cs_d):
+            # inverse prefix sum: lane c of dest d's bucket holds the c-th
+            # owned query (same gather formulation as _compact_take)
+            take = jnp.minimum(
+                jnp.searchsorted(cs_d, lane + 1).astype(jnp.int32), qs - 1)
+            return jnp.take(q_loc, take)
 
-    # ---- 2. exchange + destination-side compaction -----------------------
-    recv = jax.lax.all_to_all(send, axis, split_axis=0, concat_axis=0,
-                              tiled=True)              # [S, cap] by src
-    pair_cnt = jax.lax.all_gather(cnt, axis)           # [S_src, S_dst]
-    rcv_cnt = jnp.minimum(pair_cnt[:, ax], capacity)   # [S] live per row
-    cum_r = jnp.cumsum(rcv_cnt)
-    occ = cum_r[S - 1]                                 # my occupancy
-    src_of = jnp.searchsorted(cum_r, lane,
-                              side="right").astype(jnp.int32)
-    src_c = jnp.minimum(src_of, S - 1)
-    lane_of = lane - (jnp.take(cum_r, src_c) - jnp.take(rcv_cnt, src_c))
-    kq = jnp.where(lane < jnp.minimum(occ, capacity),
-                   recv[src_c, jnp.clip(lane_of, 0, capacity - 1)],
-                   fill)                               # [cap] kernel batch
+        send = jnp.where(
+            lane[None, :] < jnp.minimum(cnt, capacity)[:, None],
+            jax.vmap(bucket)(jnp.transpose(cs)), fill)
+
+        # ---- 2. exchange + destination-side compaction -------------------
+        recv = jax.lax.all_to_all(send, axis, split_axis=0, concat_axis=0,
+                                  tiled=True)          # [S, cap] by src
+        pair_cnt = jax.lax.all_gather(cnt, axis)       # [S_src, S_dst]
+        rcv_cnt = jnp.minimum(pair_cnt[:, ax], capacity)   # live per row
+        cum_r = jnp.cumsum(rcv_cnt)
+        occ = cum_r[S - 1]                             # my occupancy
+        src_of = jnp.searchsorted(cum_r, lane,
+                                  side="right").astype(jnp.int32)
+        src_c = jnp.minimum(src_of, S - 1)
+        lane_of = lane - (jnp.take(cum_r, src_c) - jnp.take(rcv_cnt, src_c))
+        kq = jnp.where(lane < jnp.minimum(occ, capacity),
+                       recv[src_c, jnp.clip(lane_of, 0, capacity - 1)],
+                       fill)                           # [cap] kernel batch
 
     # ---- 3. the tiered descent over the compacted O(q/S) block -----------
     f, r, lv = _descend_local(local, kq, query_block=query_block,
                               interpret=interpret, pipelined=pipelined)
     rank_g = jnp.where(r >= 0, r + lift, -1)
 
-    # ---- 4. positional un-exchange ---------------------------------------
-    off_r = cum_r - rcv_cnt                            # [S] excl offsets
-    gpos = off_r[:, None] + lane[None, :]              # [S, cap]
-    live_r = lane[None, :] < rcv_cnt[:, None]
-    valid = live_r & (gpos < capacity)
-    gp = jnp.clip(gpos, 0, capacity - 1)
-    back = jnp.stack([jnp.take(f.astype(jnp.int32), gp),
-                      jnp.take(rank_g, gp), jnp.take(lv, gp),
-                      valid.astype(jnp.int32)])        # [4, S, cap]
-    home = jax.lax.all_to_all(back, axis, split_axis=1, concat_axis=1,
-                              tiled=True)              # [4, S, cap] by dst
-    idx = (jnp.clip(owner, 0, S - 1) * capacity
-           + jnp.minimum(jnp.maximum(pos, 0), capacity - 1))
-    flat = home.reshape(4, S * capacity)
-    # pad lanes (owner -1) read a garbage-but-in-bounds slot; their ok
-    # value is irrelevant (the wrapper slices them off) and they are
-    # excluded from the pair-count-derived spill/occupancy below
-    ok = (pos < capacity) & (jnp.take(flat[3], idx) > 0)
-    f_rt = jnp.take(flat[0], idx) > 0
-    r_rt = jnp.take(flat[1], idx)
-    l_rt = jnp.take(flat[2], idx)
+    with jax.named_scope("splay.route"):
+        # ---- 4. positional un-exchange -----------------------------------
+        off_r = cum_r - rcv_cnt                        # [S] excl offsets
+        gpos = off_r[:, None] + lane[None, :]          # [S, cap]
+        live_r = lane[None, :] < rcv_cnt[:, None]
+        valid = live_r & (gpos < capacity)
+        gp = jnp.clip(gpos, 0, capacity - 1)
+        back = jnp.stack([jnp.take(f.astype(jnp.int32), gp),
+                          jnp.take(rank_g, gp), jnp.take(lv, gp),
+                          valid.astype(jnp.int32)])    # [4, S, cap]
+        home = jax.lax.all_to_all(back, axis, split_axis=1, concat_axis=1,
+                                  tiled=True)          # [4, S, cap] by dst
+        idx = (jnp.clip(owner, 0, S - 1) * capacity
+               + jnp.minimum(jnp.maximum(pos, 0), capacity - 1))
+        flat = home.reshape(4, S * capacity)
+        # pad lanes (owner -1) read a garbage-but-in-bounds slot; their ok
+        # value is irrelevant (the wrapper slices them off) and they are
+        # excluded from the pair-count-derived spill/occupancy below
+        ok = (pos < capacity) & (jnp.take(flat[3], idx) > 0)
+        f_rt = jnp.take(flat[0], idx) > 0
+        r_rt = jnp.take(flat[1], idx)
+        l_rt = jnp.take(flat[2], idx)
 
     # ---- 5. spill: replicate-and-mask trace, entered only when
     # needed.  The spill count and occupancy both derive from the

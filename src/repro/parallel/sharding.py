@@ -18,6 +18,9 @@ sharded refresh's and sharded search's ``shard_map`` use),
 :func:`shard_index_plane` (``device_put`` a host-built plane into the
 width-sharded layout), and :func:`plane_width_mesh` (detect that layout
 on a concrete plane — the search wrapper's dispatch seam).
+:func:`width_shards` / :func:`width_mesh` pick the mesh a plane too wide
+for one device's descent is laid out on, from its width and the devices
+present.
 :func:`mass_split_bounds` solves the §5.6 mass-weighted shard-boundary
 placement (the access-balanced alternative to equal lane counts).
 Every shard_map in the repo is ``jax.shard_map(..., check_vma=False)``:
@@ -34,6 +37,8 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from repro.launch.mesh import make_auto_mesh
 
 Rules = Dict[str, Optional[Tuple[str, ...]]]
 
@@ -255,6 +260,33 @@ def plane_width_mesh(plane, axis: str = "model") -> Optional[Mesh]:
     if keys.shape[-1] % mesh.shape[axis]:
         return None
     return mesh
+
+
+def width_shards(width: int, n_devices: int, max_width: int) -> int:
+    """The fewest shards ``S`` a ``width``-lane plane splits into so
+    that each block holds at most ``max_width`` lanes: the smallest
+    common divisor of ``width`` and ``n_devices`` with
+    ``width / S <= max_width`` (1 when the plane fits whole).  Raises
+    ``ValueError`` when no such divisor exists — the plane is too wide
+    for the devices present."""
+    for s in range(1, n_devices + 1):
+        if n_devices % s == 0 and width % s == 0 and width // s <= max_width:
+            return s
+    raise ValueError(
+        f"a {width}-lane plane needs blocks of at most {max_width} lanes, "
+        f"and {n_devices} device(s) cannot hold it in equal blocks that "
+        f"small: serve it on more devices or build a narrower plane")
+
+
+def width_mesh(width: int, max_width: int, axis: str = "model",
+               devices=None) -> Mesh:
+    """The ``(1, S)`` Auto mesh (axes ``("data", axis)``) over the first
+    ``S = width_shards(...)`` of ``devices`` (the local devices by
+    default) that a ``width``-lane plane is laid out on when it is wider
+    than ``max_width``.  Raises like :func:`width_shards`."""
+    devices = list(jax.local_devices() if devices is None else devices)
+    s = width_shards(width, len(devices), max_width)
+    return make_auto_mesh((1, s), ("data", axis), devices=devices[:s])
 
 
 def shard_index_plane(plane, mesh: Optional[Mesh] = None,

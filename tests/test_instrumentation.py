@@ -125,6 +125,10 @@ def _recount(st, plane, kinds, keys, upd, flags, oracle=None):
         want["plane_rebuilds"] += int(pending)
         want["plane_rows_rebuilt"] += L
         want["plane_rows_changed"] += _changed_rows(plane, plane2)
+        # one device: nothing goes through the routed exchange (the
+        # four-device counts are in tests/test_serving_layout.py)
+        want["route_queries"] += 0
+        want["route_spilled"] += 0
         if flags.get("aggregate"):
             hit = {int(k) for k, u in zip(keys[e], upd[e])
                    if u and int(k) in live}

@@ -3,8 +3,12 @@
 Interpret mode runs a Pallas kernel without ever asking the TPU
 compiler, so these tests compile the kernels ahead of time for a
 ``v5e:2x2`` topology: the tiered descent at the paper's deployment
-width (``W = 131072``, ``L = 17``) and at ``W = 4096``, and the routed
-sharded search on a 4-device mesh of the same topology.  Each asserts
+width (``W = 131072``, ``L = 17``) and at ``W = 4096``, the routed
+sharded search on a 4-device mesh of the same topology, and both the
+routed search and the sharded refresh at the four-chip cell's shape
+(``W = 2^22``, ``L = 22``).  The widest plane one device's descent
+compiles, ``splay_search.MAX_DESCENT_WIDTH``, is checked against the
+compiler: twice as wide runs out of VMEM.  Each asserts
 that the kernel made it into the executable (``tpu_custom_call``).
 The pipelined descent is interpret-only and has no test here.  The
 plane refresh is compiled at the same deployment shape, to check that
@@ -97,6 +101,68 @@ def test_routed_sharded_search_compiles(topo):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-to-all" in text
+
+
+def _sharded_plane_shapes(topo, n_levels: int, width: int, axis: str):
+    mesh = make_auto_mesh((1, 4), ("data", axis), devices=topo.devices)
+    specs = shd.index_plane_specs(dix.DeviceLevelArrays, axis)
+    plane = jax.tree_util.tree_map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        _plane_shapes(n_levels, width), specs)
+    return mesh, plane
+
+
+def test_descent_limit_is_the_widest_plane_one_device_compiles(topo):
+    """``MAX_DESCENT_WIDTH`` lanes compile on one v5e; twice as many run
+    out of VMEM, which is why wider planes are served width-sharded."""
+    one = SingleDeviceSharding(topo.devices[0])
+    fn = jax.jit(lambda k, q, w: ssk._splay_search_arrays(
+        k, q, query_block=ssk.DEFAULT_QUERY_BLOCK, interpret=False,
+        widths=w))
+
+    def lower(width):
+        return fn.lower(
+            jax.ShapeDtypeStruct((22, width), jnp.int32, sharding=one),
+            jax.ShapeDtypeStruct((1024,), jnp.int32, sharding=one),
+            jax.ShapeDtypeStruct((22,), jnp.int32, sharding=one))
+
+    assert "tpu_custom_call" in lower(
+        ssk.MAX_DESCENT_WIDTH).compile().as_text()
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED.*vmem"):
+        lower(2 * ssk.MAX_DESCENT_WIDTH).compile()
+
+
+def test_routed_sharded_search_compiles_at_the_four_chip_cell_shape(topo):
+    """The routed search of ``paper4m-ro-99-1``: a 2^22-lane plane of 22
+    levels over four devices, 2^20 lanes a shard, one 1024-op batch —
+    its spill path (the masked trace over the whole batch) included."""
+    n_levels, width, nq, axis = 22, 2 ** 22, 1024, "model"
+    mesh, plane = _sharded_plane_shapes(topo, n_levels, width, axis)
+    queries = jax.ShapeDtypeStruct((nq,), jnp.int32,
+                                   sharding=NamedSharding(mesh, P(axis)))
+    fn = ssk._routed_search_fn(
+        mesh, axis, n_levels, ssk.DEFAULT_QUERY_BLOCK, False,
+        ssk.route_capacity(nq, 4), nq, False)
+    text = fn.lower(plane, queries).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-to-all" in text
+    assert width // 4 == ssk.MAX_DESCENT_WIDTH
+
+
+def test_sharded_refresh_compiles_at_the_four_chip_cell_shape(topo):
+    """The lanes-split sharded refresh of ``paper4m-ro-99-1``: the
+    replicated 4,194,306-slot state against the 2^22-lane plane, each
+    level row's prefix sum composed by an all_gather."""
+    n_levels, width, axis = 22, 2 ** 22, "model"
+    mesh, plane = _sharded_plane_shapes(topo, n_levels, width, axis)
+    st = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=NamedSharding(mesh, P())),
+        jax.eval_shape(lambda: sx.make(width + 2, n_levels)))
+    fn = dix._sharded_refresh_fn(mesh, axis, n_levels, width, 1024,
+                                 "lanes")
+    assert "all-gather" in fn.lower(st, plane).compile().as_text()
 
 
 def test_refresh_compaction_sorts_without_per_lane_gathers(topo):
