@@ -10,9 +10,10 @@ cannot be done from an already-initialized parent process):
 ``--parity`` drives insert/delete/height-churn operation streams through
 ``device_index.refresh_device_sharded`` on 1/2/4-way meshes and asserts
 the plane is bit-identical to the replicated ``refresh_device`` chain on
-(keys, widths, heights, rank_map) every epoch — plus the
-transient-empty, rebuild-staleness, overflow-burst, and
-indivisible-width-fallback edges.  Exits nonzero on any mismatch.
+(keys, widths, heights, rank_map, bot_rank) every epoch — plus deep,
+uneven rows on 2/4 shards, and the transient-empty, rebuild-staleness,
+overflow-burst, and indivisible-width-fallback edges.  Exits nonzero on
+any mismatch.
 
 ``--bench`` races the sharded refresh on a 1x4 host mesh against the
 replicated refresh over membership-changing epoch streams and prints one
@@ -48,7 +49,7 @@ from repro.kernels import ops as kops                  # noqa: E402
 from repro.parallel import sharding as shd             # noqa: E402
 from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
-CMP_FIELDS = ("keys", "widths", "heights", "rank_map")
+CMP_FIELDS = ("keys", "widths", "heights", "rank_map", "bot_rank")
 
 
 def _seed_state(pool, cap=512, ml=12):
@@ -70,6 +71,72 @@ def _assert_equal(ps, pr, msg):
     np.testing.assert_array_equal(
         np.asarray(ps.slots)[:w_bot], np.asarray(pr.slots)[:w_bot],
         err_msg=f"{msg} field=slots[:w_bot]")
+
+
+def _built_state(keys, heights, deleted, cap, ml):
+    """A state holding ``keys`` in slots ``2..`` with the given relative
+    heights and delete flags: the fields the refresh reads, set
+    directly, so a case can place every row's members by hand."""
+    n = len(keys)
+    key = np.full((cap,), sx.POS_INF_32, np.int32)
+    key[0] = sx.NEG_INF_32
+    key[2:2 + n] = keys
+    top = np.zeros((cap,), np.int32)
+    top[2:2 + n] = heights
+    top[0] = top[1] = ml
+    dead = np.zeros((cap,), bool)
+    dead[2:2 + n] = deleted
+    return sx.make(cap, max_level=ml)._replace(
+        key=jnp.asarray(key), top=jnp.asarray(top),
+        zl=jnp.array(0, jnp.int32),
+        n_alloc=jnp.array(n + 2, jnp.int32), deleted=jnp.asarray(dead))
+
+
+def _deep_uneven_epochs(L):
+    """(keys, heights, deleted) of one growing slot set, three epochs,
+    for ``W = 64``.  First deep, uneven rows: heights up to ``L - 1``;
+    row 0 held by the keys at bottom columns 17, 20 and 25, all in one
+    shard at 2 and at 4 shards; the last of 4 shards holds no key, and
+    shard 2 of 4 no member of rows 0 to ``L - 5``.  Then every height
+    capped at 2, which empties rows 0 to ``L - 4`` (the middle rows
+    among them) while deletes and inserts churn the bottom row; then
+    deep again over the churned row."""
+    rng = np.random.default_rng(16)
+    keys = np.arange(0, 400, 10, dtype=np.int32)     # 40 keys, cols 0..39
+    h = rng.integers(0, 3, len(keys)).astype(np.int32)
+    h[[17, 20, 25]] = L - 1
+    h[[2, 5]] = L - 4
+    h[[35, 38]] = L - 5
+    dead = np.zeros(len(keys), bool)
+    yield keys, h, dead
+    dead = np.concatenate([dead, np.zeros(3, bool)])
+    dead[10:15] = True
+    keys = np.concatenate([keys, np.asarray([5, 215, 395], np.int32)])
+    yield keys, np.minimum(np.concatenate([h, [2, 2, 2]]), 2), dead
+    h = rng.integers(0, 2, len(keys)).astype(np.int32)
+    h[[30, 31, 33]] = L - 1
+    h[[0, 40]] = L - 2
+    yield keys, h, dead
+
+
+def _deep_uneven_case(S, L=8, W=64, cap=96):
+    """The ``_deep_uneven_epochs`` stream through both refreshes on an
+    ``S``-way mesh: bit-identical planes every epoch."""
+    mesh = make_auto_mesh((1, S), ("data", "model"))
+    epochs = list(_deep_uneven_epochs(L))
+    keys, _, dead = epochs[0]
+    st = _built_state(keys, np.zeros(len(keys), np.int32), dead, cap, L)
+    pr = dix.from_state_device(st, n_levels=L, width=W)
+    ps = shd.shard_index_plane(pr, mesh)
+    for i, (keys, h, dead) in enumerate(epochs):
+        st = _built_state(keys, h, dead, cap, L)
+        pr, ovr = dix.refresh_device(st, pr, max_new=16,
+                                     return_overflow=True)
+        ps, ovs = dix.refresh_device_sharded(st, ps, max_new=16,
+                                             mesh=mesh)
+        assert int(ovr) == int(ovs) == 0, (int(ovr), int(ovs))
+        _assert_equal(ps, pr, f"deep-uneven S={S} epoch={i}")
+        yield np.asarray(pr.widths)
 
 
 def _mixed_stream(rng, pool, n_ops):
@@ -110,6 +177,13 @@ def run_parity() -> None:
             _assert_equal(ps, pr, f"S={S} epoch={epoch}")
         print(f"parity S={S}: 8 mixed epochs OK "
               f"(w_bot={int(np.asarray(pr.widths)[-1])})")
+
+    for S in (2, 4):
+        widths = list(_deep_uneven_case(S, L=8))
+        # row 0 holds its three keys, then a middle row runs empty
+        assert widths[0][0] == 3 and widths[1][3] == 0, widths
+        print(f"parity deep-uneven S={S} OK "
+              f"(widths {[w.tolist() for w in widths]})")
 
     mesh = make_auto_mesh((1, 4), ("data", "model"))
 
@@ -225,20 +299,7 @@ def run_bench(width: int = 4096, churn: int = 64, epochs: int = 4,
             slot_keys = np.concatenate([slot_keys, fresh])
             deleted = np.concatenate([deleted, np.zeros(churn, bool)])
         h = rng.integers(0, hmax + 1, len(slot_keys)).astype(np.int32)
-        key = np.full((capacity,), sx.POS_INF_32, np.int32)
-        key[0] = sx.NEG_INF_32
-        key[2:2 + len(slot_keys)] = slot_keys
-        top = np.zeros((capacity,), np.int32)
-        top[2:2 + len(slot_keys)] = h
-        top[0] = top[1] = 8
-        st = sx.make(capacity, max_level=8)._replace(
-            key=jnp.asarray(key), top=jnp.asarray(top),
-            zl=jnp.array(0, jnp.int32),
-            n_alloc=jnp.array(len(slot_keys) + 2, jnp.int32),
-            deleted=jnp.asarray(np.concatenate(
-                [np.zeros(2, bool), deleted,
-                 np.zeros(capacity - 2 - len(deleted), bool)])))
-        states.append(st)
+        states.append(_built_state(slot_keys, h, deleted, capacity, 8))
 
     p0 = dix.from_state_device(states[0], n_levels=n_levels, width=width)
     p0s = shd.shard_index_plane(p0, mesh)

@@ -37,7 +37,9 @@ plane is laid out by one ``lax.sort`` along the width axis (the row's
 members keyed by column, the rest lifted past them), and the insert
 merge is one sort of the survivors with the new keys.  The sort brings
 the keys, heights, slots and next-row prefix counts along, so no
-per-lane gather reads through a permutation afterwards.  The inverse
+per-lane gather reads through a permutation afterwards; the
+width-sharded refresh sorts each shard's own block the same way and
+shifts the resulting runs into place.  The inverse
 prefix sum it replaces (:func:`_compact_take`, a binary search per
 output lane, kept for the plane auditor and as the tests' oracle) runs
 one dependent gather per lane per search round: on a v5e at
@@ -426,15 +428,20 @@ def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
     The function's name carries ``refresh_device``: a profile reader
     that names layers by jitted function puts it in the refresh.
 
+    The re-layering sorts each shard's own ``[L, W/S]`` block once
+    (``_assemble_device``, carrying key, column and next-row rank) and
+    places every level row's runs by whole-run shifts: each shard's
+    members of a row hold a contiguous range of the row's global ranks,
+    so no lane searches the composed ``[W]`` row.
+
     Budget per shard and epoch: resident state O(L·W/S) (its plane
-    blocks) + O(W) transient bottom-row/composed-row buffers (the
-    [L, W] rectangle is never materialized on one shard — the composed
-    prefix sum streams one row per scan step); compute for the per-lane
-    stages (classification gathers, compaction searchsorted, rank
-    emission) O((L·W/S)·log W + capacity), the local merge one sort of
-    W/S + max_new lanes; wire O(W + S·max_new)
-    for the segment exchange plus O(W) received per level row of the
-    streamed composition."""
+    blocks) + O(W) transient buffers (the [L, W] rectangle is never
+    materialized on one shard — the placement streams one row per scan
+    step); compute O(capacity·log(W/S)) for the classification, one
+    sort of ``[L, W/S]`` for the re-layering and O(L·W) lane moves for
+    the placement, the local merge one sort of W/S + max_new lanes;
+    wire O(W + S·max_new) for the segment exchange plus 3·W received
+    per level row of the placement."""
     S = n_shards
     wl = width // S
     cap = st.capacity
@@ -553,9 +560,6 @@ def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
             v = jnp.take(segs.reshape(S * m_len), tc * m_len + li)
             return jnp.where(pos < total, v, fill)
 
-    pos_g = jnp.arange(width, dtype=jnp.int32)
-    keys_g = pick(segs_k, pos_g, jnp.int32(PAD_KEY))   # [W] merged row
-    hts_g = pick(segs_h, pos_g, jnp.int32(0))
     overflow = (jnp.maximum(total_raw - kk, 0)
                 + jnp.maximum(total - width, 0)).astype(jnp.int32)
 
@@ -572,6 +576,9 @@ def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
         # boundaries — searched correctly ONLY by the sharded search
         # (keys/rank_map/heights hold each shard's local sub-plane;
         # widths stays the global per-row live count).
+        pos_g = jnp.arange(width, dtype=jnp.int32)
+        keys_g = pick(segs_k, pos_g, jnp.int32(PAD_KEY))   # [W] merged
+        hts_g = pick(segs_h, pos_g, jnp.int32(0))
         total_c = jnp.minimum(total, width)
         slot_g = pick(segs_s, pos_g, jnp.int32(-1))    # [W] packed slots
         # per-key mass saturates at 2^16 so the int32 cumsum stays
@@ -608,61 +615,64 @@ def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
             local_ok=jnp.ones((1,), jnp.int32))
         return plane, overflow
 
-    slots_own = pick(segs_s, col_g, jnp.int32(-1))     # own lanes only
-
-    # ---- re-layering: per-shard mask/prefix-sum on own columns, then
-    # an exclusive cross-shard scan of per-row totals lifts local ranks
-    # to global ones.  The composed global prefix sum is STREAMED one
-    # level row at a time (lax.scan with an all_gather per row): a shard
-    # holds O(W) transient buffers, never the [L, W] rectangle — that is
-    # what lets the plane outgrow one device's memory.
-    alive_g = keys_g != PAD_KEY
-    h_g = jnp.where(alive_g, hts_g, -1)
-    k_own = jax.lax.dynamic_slice(keys_g, (ax * wl,), (wl,))
-    hraw_own = jax.lax.dynamic_slice(hts_g, (ax * wl,), (wl,))
-    h_own = jnp.where(k_own != PAD_KEY, hraw_own, -1)
-
-    row_min_h = (n_levels - 1 - jnp.arange(n_levels, dtype=jnp.int32))
-    mask_own = h_own[None, :] >= row_min_h[:, None]    # [L, wl]
-    cs_own = jnp.cumsum(mask_own, axis=1, dtype=jnp.int32)
-    tot_own = cs_own[:, wl - 1]                        # [L]
+    # ---- re-layering of the shard's own columns of the packed row.
+    # Each shard lays out its [L, W/S] block by one payload-carrying
+    # sort (_assemble_device, the replicated refresh's compaction): row
+    # r's local members come first, carrying their key, their own
+    # column and their rank in the shard's row r+1.  An exclusive
+    # cross-shard scan of per-row totals then lifts local ranks to
+    # global ones: shard t's row-r run holds the row's members of
+    # global ranks [row_offs[t, r], row_offs[t, r] + tots[t, r]), and
+    # these runs tile the row in shard order.
+    k_own = pick(segs_k, col_g, jnp.int32(PAD_KEY))
+    h_own = pick(segs_h, col_g, jnp.int32(0))
+    slots_own = pick(segs_s, col_g, jnp.int32(-1))
+    local = _assemble_device(k_own, h_own, slots_own, n_levels)
     with jax.named_scope("splay.redistribute"):
-        tots = jax.lax.all_gather(tot_own, axis)       # [S, L]
+        tots = jax.lax.all_gather(local.widths, axis)  # [S, L]
         row_offs = jnp.cumsum(tots, axis=0) - tots     # [S, L] exclusive
         widths_g = jnp.sum(tots, axis=0)               # [L] global
+    offs_up = jnp.concatenate([row_offs[ax, 1:],
+                               jnp.zeros((1,), jnp.int32)])
+    runs = (local.keys, ax * wl + local.bot_rank,
+            local.rank_map + offs_up[:, None])
 
-    # ---- own output columns, one row per scan step: compaction gather
-    # + rank emission.  The member for a global output lane can live in
-    # any shard's columns, so each step gathers that row's composed
-    # prefix sum; the rank of row r's members reads row r+1's composed
-    # sum, i.e. the NEXT step's cs_row — carried via prev_take.
-    def level_step(prev_take, inp):
-        cs_own_r, offs_r = inp                         # [wl], [S]
+    # ---- placement, one level row per scan step (an all_gather of the
+    # row's runs per step: a shard holds O(W) transient buffers, never
+    # the [L, W] rectangle — that is what lets the plane outgrow one
+    # device's memory).  Output lane g of this shard takes run t's
+    # member g - row_offs[t, r]: a shift of each run by a whole offset,
+    # so every run is sliced once at that offset (zero-padded by a
+    # block each side) and kept where it covers.
+    def place_row(_, inp):
+        run_r, offs_r, tots_r = inp                    # [3, wl], [S], [S]
         with jax.named_scope("splay.redistribute"):
-            blocks = jax.lax.all_gather(cs_own_r, axis)    # [S, wl]
-            cs_row = (blocks + offs_r[:, None]).reshape(width)
-        take_r = jnp.minimum(
-            jnp.searchsorted(cs_row, col_g + 1).astype(jnp.int32),
-            width - 1)
-        rank_up = jnp.take(cs_row, prev_take) - 1      # rank of row r-1
-        return take_r, (take_r, rank_up)
+            got = jax.lax.all_gather(jnp.stack(run_r), axis)  # [S,3,wl]
+            got = jnp.pad(got, ((0, 0), (0, 0), (wl, wl)))
+            out = jnp.zeros((3, wl), jnp.int32)
+            for t in range(S):
+                shift = ax * wl - offs_r[t]
+                src = shift + col_l
+                cover = (src >= 0) & (src < tots_r[t])
+                piece = jax.lax.dynamic_slice(
+                    got[t], (0, jnp.clip(shift, -wl, wl) + wl), (3, wl))
+                out = jnp.where(cover[None, :], piece, out)
+        return None, out
 
-    _, (takes, rank_ups) = jax.lax.scan(
-        level_step, jnp.zeros((wl,), jnp.int32),
-        (cs_own, jnp.transpose(row_offs)))
+    _, placed = jax.lax.scan(
+        place_row, None,
+        (runs, jnp.transpose(row_offs), jnp.transpose(tots)))  # [L, 3, wl]
     live = col_g[None, :] < widths_g[:, None]
-    rows_own = jnp.where(live, jnp.take(keys_g, takes), PAD_KEY)
-    # rows 0..L-2: live rank from the next row's composed sum, pad lanes
-    # close the window at the next row's live width; bottom row is the
-    # (global-column) identity
-    rank_own = jnp.where(live[:-1], rank_ups[1:], widths_g[1:, None])
+    rows_own = jnp.where(live, placed[:, 0], PAD_KEY)
+    # bottom rank of own output lanes: the member's global column of the
+    # packed row
+    bot_rank_own = jnp.where(live, placed[:, 1], widths_g[n_levels - 1])
+    # rows 0..L-2: live rank in the next row, pad lanes close the window
+    # at the next row's live width; bottom row is the (global-column)
+    # identity
+    rank_own = jnp.where(live[:-1], placed[:-1, 2], widths_g[1:, None])
     rank_own = jnp.concatenate([rank_own, col_g[None, :]], axis=0)
-
-    heights_own = jnp.where(k_own != PAD_KEY, hraw_own, 0).astype(jnp.int32)
-
-    # bottom rank of own output lanes: `takes` already holds the global
-    # keys_g position of each member, which IS its packed bottom rank
-    bot_rank_own = jnp.where(live, takes, widths_g[n_levels - 1])
+    heights_own = local.heights
 
     plane = DeviceLevelArrays(
         keys=rows_own, widths=widths_g, heights=heights_own,
@@ -745,7 +755,7 @@ def refresh_device_sharded(st: sx.SplayState, prev: DeviceLevelArrays,
 
     Equivalence: on any 1×N host mesh the ``"lanes"`` result is
     bit-identical to the replicated refresh on ``keys``/``widths``/
-    ``heights``/``rank_map`` (asserted in
+    ``heights``/``rank_map``/``bot_rank`` (asserted in
     ``tests/test_sharded_refresh.py``); the ``slots`` companion agrees
     on live lanes (pad lanes are unspecified in both paths and never
     read).  The ``"mass"`` result indexes the same key set (same

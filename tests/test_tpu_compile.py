@@ -6,10 +6,12 @@ compiler, so these tests compile the kernels ahead of time for a
 width (``W = 131072``, ``L = 17``) and at ``W = 4096``, the routed
 sharded search on a 4-device mesh of the same topology, and both the
 routed search and the sharded refresh at the four-chip cell's shape
-(``W = 2^22``, ``L = 22``).  The widest plane one device's descent
-compiles, ``splay_search.MAX_DESCENT_WIDTH``, is checked against the
-compiler: twice as wide runs out of VMEM.  Each asserts
-that the kernel made it into the executable (``tpu_custom_call``).
+(``W = 2^22``, ``L = 22``), whose rows each shard lays out by a sort,
+with no binary search per lane into a ``W``-wide row.  The widest plane
+one device's descent compiles, ``splay_search.MAX_DESCENT_WIDTH``, is
+checked against the compiler: twice as wide runs out of VMEM.  Each
+asserts that the kernel made it into the executable
+(``tpu_custom_call``).
 The pipelined descent is interpret-only and has no test here.  The
 plane refresh is compiled at the same deployment shape, to check that
 its row compaction stays a sort with no per-lane gather.
@@ -150,10 +152,11 @@ def test_routed_sharded_search_compiles_at_the_four_chip_cell_shape(topo):
     assert width // 4 == ssk.MAX_DESCENT_WIDTH
 
 
-def test_sharded_refresh_compiles_at_the_four_chip_cell_shape(topo):
-    """The lanes-split sharded refresh of ``paper4m-ro-99-1``: the
-    replicated 4,194,306-slot state against the 2^22-lane plane, each
-    level row's prefix sum composed by an all_gather."""
+@pytest.fixture(scope="module")
+def four_chip_refresh_text(topo):
+    """The lanes-split sharded refresh of ``paper4m-ro-99-1``, compiled
+    once for both tests below: the replicated 4,194,306-slot state
+    against the 2^22-lane plane of 22 levels over four devices."""
     n_levels, width, axis = 22, 2 ** 22, "model"
     mesh, plane = _sharded_plane_shapes(topo, n_levels, width, axis)
     st = jax.tree_util.tree_map(
@@ -162,7 +165,37 @@ def test_sharded_refresh_compiles_at_the_four_chip_cell_shape(topo):
         jax.eval_shape(lambda: sx.make(width + 2, n_levels)))
     fn = dix._sharded_refresh_fn(mesh, axis, n_levels, width, 1024,
                                  "lanes")
-    assert "all-gather" in fn.lower(st, plane).compile().as_text()
+    return fn.lower(st, plane).compile().as_text()
+
+
+def test_sharded_refresh_compiles_at_the_four_chip_cell_shape(
+        four_chip_refresh_text):
+    """The sharded refresh at the four-chip cell's shape compiles, each
+    level row's runs placed across the shards by an all_gather."""
+    assert "all-gather" in four_chip_refresh_text
+
+
+def test_sharded_refresh_composes_rows_by_a_sort_without_per_lane_search(
+        four_chip_refresh_text):
+    """Each shard lays out its ``[L, W/S]`` block of the plane by one
+    sort along the width axis, and no gather reads a ``W``-wide row (a
+    binary search per lane into the composed prefix sum of a level, or
+    a take of the composed bottom row, does) or emits one index per
+    lane of the block."""
+    n_levels, width, lanes = 22, 2 ** 22, 2 ** 20
+    text = four_chip_refresh_text
+
+    def size(dims):
+        return math.prod(int(d) for d in dims.split(",") if d)
+
+    shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    gathers = re.findall(
+        r"= \w+\[([\d,]*)\]\S* gather\(%([\w.\-]+)", text)
+    assert gathers, "no gather parsed: the HLO text format changed"
+    assert width not in [size(shapes[op]) for _, op in gathers]
+    assert n_levels * lanes not in [size(dims) for dims, _ in gathers]
+    rect = rf"s32\[{n_levels},{lanes}\]"
+    assert re.search(rf"= \({rect}\S*(, {rect}\S*)*\) sort\(", text)
 
 
 def test_refresh_compaction_sorts_without_per_lane_gathers(topo):
