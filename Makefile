@@ -10,8 +10,9 @@ export PYTHONPATH := src:.
 test:
 	$(PY) -m pytest -x -q
 
-# quick perf smoke: kernel race + aggregation + refresh-path races
-# (host vs device_index; sharded vs replicated); writes BENCH_kernels.json
+# quick perf smoke: tiered-kernel timing (checked against the oracle) +
+# aggregation + refresh-path races (host vs device_index; sharded vs
+# replicated); writes BENCH_kernels.json
 bench-smoke:
 	$(PY) benchmarks/run.py --only kernels_bench
 

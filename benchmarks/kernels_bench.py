@@ -1,10 +1,9 @@
 """Kernel micro-benchmarks (CPU: interpret-mode correctness path; the
 derived columns carry the structural metrics that transfer to TPU).
 
-Races the tiered splay-search pipeline (per-row streaming + rank-windowed
-descent, DESIGN.md §5.2) against the retained seed kernel
-(``splay_search_full``: whole level matrix as one resident block,
-full-width compare per level) on Zipf query batches, measures the
+Times the tiered splay-search pipeline (per-row streaming +
+two-stage compare-and-count, DESIGN.md §5.2) on Zipf query batches,
+checked against the ``kernels/ref.py`` oracle, measures the
 batched-update aggregation (one weighted fold per unique key), and races
 the refresh paths (DESIGN.md §5.3): host ``level_arrays.refresh`` (state
 download + numpy argsort + plane re-upload) vs the device-resident
@@ -42,6 +41,7 @@ from repro.core import level_arrays as la
 from repro.core import splaylist as sx
 from repro.core import workload as wl
 from repro.kernels import ops
+from repro.kernels.ref import splay_search_ref
 
 ALPHAS = (0.6, 1.0, 1.4)
 
@@ -64,10 +64,6 @@ def _time(fn, reps: int) -> float:
 def _bytes_model(L: la.LevelArrays, query_block: int, nq: int) -> dict:
     """Per-level bytes-touched estimate for one full batch of nq queries.
 
-    seed kernel: the whole [L, W] matrix is one constant block — it is
-    fetched once and must stay VMEM-resident; every level row is compared
-    full-width by every query.
-
     tiered kernel: one level row (padded to whole 128-lane chunks)
     streams per (query block, live level); statically-empty rows are
     aliased away by the fetch schedule; a query costs W/128 sample
@@ -79,17 +75,14 @@ def _bytes_model(L: la.LevelArrays, query_block: int, nq: int) -> dict:
     q_blocks = max(nq // query_block, 1)
     live = int((L.widths > 0).sum())
     per_level_bytes = [int(width * itemsize) for _ in range(n_levels)]
-    seed_resident = n_levels * width * itemsize
     tiered_streamed = q_blocks * ssk.tiered_row_bytes(live, width)
     return {
         "n_levels": n_levels,
         "width": width,
         "live_levels": live,
         "per_level_row_bytes": per_level_bytes,
-        "seed_vmem_resident_bytes": seed_resident,
         "tiered_vmem_resident_bytes": ssk.tiered_row_bytes(1, width),
         "tiered_streamed_bytes_per_batch": tiered_streamed,
-        "seed_compares_per_query": n_levels * width,
         "tiered_compares_per_query":
             int(n_levels * (-(-width // ssk.CHUNK) + ssk.CHUNK)),
     }
@@ -218,8 +211,7 @@ def _refresh_case(width: int, churn: int, epochs: int, reps: int,
             # the serving loop consumes the plane on device: include the
             # re-upload the host path forces every epoch
             up = tuple(jnp.asarray(x) for x in
-                       (prev.keys, prev.widths, prev.heights,
-                        prev.rank_map))
+                       (prev.keys, prev.widths, prev.heights))
         up[0].block_until_ready()
         return up
 
@@ -238,13 +230,13 @@ def _refresh_case(width: int, churn: int, epochs: int, reps: int,
     final_d = dev_fold()
     ref = la.from_state(states[-1], min_levels=n_levels, width=width)
     assert (np.asarray(final_d.keys) == ref.keys).all()
-    assert (np.asarray(final_d.rank_map) == ref.rank_map).all()
+    assert (np.asarray(final_d.widths) == ref.widths).all()
     assert (np.asarray(final_h[0]) == np.asarray(final_d.keys)).all()
 
     itemsize = 4
     C, L1 = states[0].key.shape[0], states[0].max_level + 1
     state_download = (2 * L1 * C + 5 * C) * itemsize   # to_numpy: all fields
-    plane_upload = (2 * n_levels * width + width + n_levels) * itemsize
+    plane_upload = (n_levels * width + width + n_levels) * itemsize
     mode = "membership" if churn else "height_only"
     emit(f"refresh_{mode}_w{width}", t_dev * 1e6,
          f"host_us={t_host * 1e6:.1f};speedup={t_host / t_dev:.2f};"
@@ -322,57 +314,6 @@ def _probe_env() -> dict:
     env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     return env
-
-
-def _pipelined_case(width: int, nq: int, qb: int, reps: int) -> dict:
-    """§5.8 windowed-DMA descent vs the tiered row-streaming kernel on
-    the hot-Zipf batch (alpha=1.4): bit-identity on every output triple,
-    wall clock, and the streamed-bytes race — the pipelined kernel's own
-    fetch counter (rank-window tiles + block-level early exit) against
-    the tiered kernel's whole-row streaming model from
-    ``_bytes_model``."""
-    from repro.kernels import splay_search as ssk
-    alpha = 1.4
-    L, qs = _zipf_case(width, alpha, nq, seed=14)
-    lvk = jnp.asarray(L.keys)
-    rm = jnp.asarray(L.rank_map)
-    w = jnp.asarray(L.widths)
-    qsj = jnp.asarray(qs)
-    interp = not ops.on_tpu()
-    dt_tier = _time(lambda: ops.splay_search(
-        lvk, qsj, query_block=qb, rank_map=rm, widths=w,
-        sharded=False, pipelined=False), reps)
-    dt_pipe = _time(lambda: ssk.splay_search_pipelined(
-        lvk, qsj, query_block=qb, interpret=interp, rank_map=rm,
-        widths=w), reps)
-    out_t = ops.splay_search(lvk, qsj, query_block=qb, rank_map=rm,
-                             widths=w, sharded=False, pipelined=False)
-    f, r, lv, nb = ssk.splay_search_pipelined(
-        lvk, qsj, query_block=qb, interpret=interp, rank_map=rm,
-        widths=w)
-    for a, b in zip(out_t, (f, r, lv)):
-        assert (np.asarray(a) == np.asarray(b)).all()
-    q_blocks = max(nq // qb, 1)
-    live = int((np.asarray(w) > 0).sum())
-    tiered_bytes = q_blocks * ssk.tiered_row_bytes(live, width)
-    pipe_bytes = int(np.asarray(nb).sum())
-    reduction = tiered_bytes / max(pipe_bytes, 1)
-    emit(f"kernel_splay_search_pipelined_a{alpha}", dt_pipe / nq * 1e6,
-         f"tiered_us={dt_tier / nq * 1e6:.3f};"
-         f"streamed_mb={pipe_bytes / 2**20:.2f}"
-         f"(tiered_model={tiered_bytes / 2**20:.2f});"
-         f"bytes_reduction={reduction:.2f}")
-    return {
-        "alpha": alpha, "width": width, "nq": nq, "query_block": qb,
-        "live_levels": live,
-        "us_per_query_tiered": dt_tier / nq * 1e6,
-        "us_per_query_pipelined": dt_pipe / nq * 1e6,
-        "streamed_bytes_per_batch_tiered_model": tiered_bytes,
-        "streamed_bytes_per_batch_pipelined": pipe_bytes,
-        "bytes_reduction": reduction,
-        "bytes_per_block": [int(x) for x in np.asarray(nb)],
-        "bit_identical": True,
-    }
 
 
 def _drift_case(width: int, nq: int, epochs: int = 10) -> dict:
@@ -500,30 +441,21 @@ def run(quick: bool = False) -> dict:
     for alpha in ALPHAS:
         L, qs = _zipf_case(width, alpha, nq, seed=int(alpha * 10))
         lvk = jnp.asarray(L.keys)
-        rm = jnp.asarray(L.rank_map)
         w = jnp.asarray(L.widths)
         qsj = jnp.asarray(qs)
         dt_tier = _time(lambda: ops.splay_search(
-            lvk, qsj, query_block=qb, rank_map=rm, widths=w), reps)
-        dt_full = _time(lambda: ops.splay_search_full(
-            lvk, qsj, query_block=qb), reps)
-        out_t = ops.splay_search(lvk, qsj, query_block=qb,
-                                 rank_map=rm, widths=w)
-        out_f = ops.splay_search_full(lvk, qsj, query_block=qb)
-        for a, b in zip(out_t, out_f):
+            lvk, qsj, query_block=qb, widths=w), reps)
+        out_t = ops.splay_search(lvk, qsj, query_block=qb, widths=w)
+        for a, b in zip(out_t, splay_search_ref(lvk, qsj)):
             assert (np.asarray(a) == np.asarray(b)).all()
         _, _, lv = out_t
         mean_lv = float(jnp.mean(lv))
         emit(f"kernel_splay_search_tiered_a{alpha}", dt_tier / nq * 1e6,
-             f"full_us={dt_full / nq * 1e6:.3f};"
-             f"speedup={dt_full / dt_tier:.2f};mean_level={mean_lv:.2f}")
+             f"mean_level={mean_lv:.2f}")
         payload["zipf_search"].append({
             "alpha": alpha,
             "ops_per_sec_tiered": nq / dt_tier,
-            "ops_per_sec_seed": nq / dt_full,
             "us_per_query_tiered": dt_tier / nq * 1e6,
-            "us_per_query_seed": dt_full / nq * 1e6,
-            "speedup": dt_full / dt_tier,
             "mean_level_found": mean_lv,
         })
     payload["bytes_model"] = _bytes_model(L, qb, nq)
@@ -557,9 +489,6 @@ def run(quick: bool = False) -> dict:
     # full-gather model — gated in CI from this entry
     payload["search_ordered"] = _ordered_case(
         1024 if quick else 2048, 1024 if quick else 2048)
-    # §5.8 foresight-pipelined descent vs the tiered kernel, hot-Zipf
-    # acceptance point (the streamed-bytes reduction is gated in CI)
-    payload["search_pipelined"] = _pipelined_case(width, nq, qb, reps)
     # closed-loop routing controller through the drift scenarios
     # (DESIGN.md §5.7), also at the acceptance point — the recovery
     # bound (<=1% spill within K epochs of every transition) is gated

@@ -10,7 +10,7 @@ cannot be done from an already-initialized parent process):
 ``--parity`` drives insert/delete/height-churn operation streams through
 ``device_index.refresh_device_sharded`` on 1/2/4-way meshes and asserts
 the plane is bit-identical to the replicated ``refresh_device`` chain on
-(keys, widths, heights, rank_map, bot_rank) every epoch — plus deep,
+(keys, widths, heights) every epoch — plus deep,
 uneven rows on 2/4 shards, and the transient-empty, rebuild-staleness,
 overflow-burst, and indivisible-width-fallback edges.  Exits nonzero on
 any mismatch.
@@ -49,7 +49,7 @@ from repro.kernels import ops as kops                  # noqa: E402
 from repro.parallel import sharding as shd             # noqa: E402
 from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
-CMP_FIELDS = ("keys", "widths", "heights", "rank_map", "bot_rank")
+CMP_FIELDS = ("keys", "widths", "heights")
 
 
 def _seed_state(pool, cap=512, ml=12):

@@ -21,9 +21,8 @@ path), a batch owned entirely by one shard, transient-empty rows, the
 all-empty plane, membership-churn epoch streams interleaving sharded
 refresh + sharded search, mass-weighted re-split epochs (segmented
 planes; boundary-table monotonicity checked each epoch), the §5.8
-pipelined descent inside both shard bodies (lanes + segmented planes,
-``RouteStats.assembled`` pinned 0 on the resident mass steady state
-and > 0 on stale planes), and the end-to-end sharded serving loop
+resident sub-planes (``RouteStats.assembled`` pinned 0 on the resident
+mass steady state and > 0 on stale planes), and the end-to-end sharded serving loop
 (``splaylist.run_serving(plane_search=True, mesh=...)``, lanes and mass
 splits).  Exits nonzero on any mismatch.
 
@@ -69,7 +68,7 @@ from repro.kernels import splay_search as ssk          # noqa: E402
 from repro.parallel import sharding as shd             # noqa: E402
 from repro.launch.mesh import make_auto_mesh           # noqa: E402
 
-CMP_FIELDS = ("keys", "widths", "heights", "rank_map")
+CMP_FIELDS = ("keys", "widths", "heights")
 
 
 def _seed_state(pool, cap=512, ml=12):
@@ -132,14 +131,6 @@ def _search_all_ways(plane_r, plane_s, qs, mesh, spill_cap=None):
     _assert_triple(out_rt[:3], out_re, "routed-vs-replicated")
     _assert_triple(out_mk, out_re, "masked-vs-replicated")
     _assert_triple(out_ga, out_re, "gather-vs-replicated")
-    # §5.8 windowed-DMA descent inside both shard bodies: bit-identical
-    # to the tiered replicated answers on the same plane
-    out_pr = ssk.splay_search_sharded(plane_s, qs, mesh=mesh,
-                                      pipelined=True, return_stats=True)
-    out_pm = ssk.splay_search_sharded(plane_s, qs, mesh=mesh,
-                                      routed=False, pipelined=True)
-    _assert_triple(out_pr[:3], out_re, "pipelined-routed-vs-replicated")
-    _assert_triple(out_pm, out_re, "pipelined-masked-vs-replicated")
     # lane-packed shard planes carry no §5.8 residency bit: every
     # descent re-assembles its local sub-plane (counted per shard body)
     assert int(out_rt[3].assembled) > 0, int(out_rt[3].assembled)
@@ -260,14 +251,8 @@ def run_parity() -> None:
         _assert_triple(out_sp[:3], out_re, "mass forced-spill")
         # §5.8 residency: the mass-split blocks ARE the local sub-plane
         # — the steady-state routed descent must not re-assemble (the
-        # counted probe for the "no _assemble_device" acceptance), and
-        # the pipelined kernel must agree on the segmented plane too
+        # counted probe for the "no _assemble_device" acceptance)
         assert int(out_rt[3].assembled) == 0, int(out_rt[3].assembled)
-        out_pp = ssk.splay_search_sharded(ps, qs, mesh=mesh,
-                                          pipelined=True,
-                                          return_stats=True)
-        _assert_triple(out_pp[:3], out_re, "mass routed pipelined")
-        assert int(out_pp[3].assembled) == 0
     # a lanes refresh repacks the segmented plane bit-identically
     pl_back, _ = dix.refresh_device_sharded(st, ps, max_new=48,
                                             mesh=mesh)

@@ -339,14 +339,13 @@ def four_chip(ctx, size):
     check(np.asarray(res_s).ravel().tolist() == want,
           "sharded answers != oracle")
     check((np.asarray(ovf) == 0).all(), "sharded epochs overflowed")
-    for name in ("keys", "rank_map", "bot_rank"):
-        shards = getattr(pl_s, name).addressable_shards
-        check(len({s.device for s in shards}) == 4
-              and all(s.data.shape == (levels, width // 4) for s in shards),
-              f"plane.{name} is not split W/4 per device")
+    shards = pl_s.keys.addressable_shards
+    check(len({s.device for s in shards}) == 4
+          and all(s.data.shape == (levels, width // 4) for s in shards),
+          "plane.keys is not split W/4 per device")
     audit = pc.audit_plane(st_s, pl_s)
     print(f"sharded plane {pc.audit_summary(audit)}; each of 4 devices "
-          f"holds [{levels}, {width // 4}] of keys/rank_map/bot_rank; "
+          f"holds [{levels}, {width // 4}] of keys; "
           f"routed spill {int(np.asarray(spill).sum())}")
     check(pc.audit_ok(audit), "sharded plane audit failed")
     print(f"bit-identical: sharded == replicated == oracle on "
@@ -373,7 +372,6 @@ def main(argv=None) -> int:
     from repro.core import splaylist as sx
     from repro.core import workload as wl
     from repro.kernels import ops as kops
-    from repro.kernels import splay_search as ssk
 
     dev = jax.devices()[0]
     if not args.rehearse and (dev.platform != "tpu"
@@ -387,8 +385,8 @@ def main(argv=None) -> int:
                kops=kops, meter=compile_meter(jax))
     print(f"smoke timings of one run, not benchmark numbers; device "
           f"{dev.device_kind} x{len(jax.devices())}, jax {jax.__version__}, "
-          f"kernels {kops.exec_mode()}, descent "
-          f"{ssk.descent_kind(size[3])}, compile cache {cache_dir}")
+          f"kernels {kops.exec_mode()}, descent tiered, "
+          f"compile cache {cache_dir}")
     try:
         (four_chip if args.four_chip else one_chip)(ctx, size)
     except SmokeFailure as e:

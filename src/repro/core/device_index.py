@@ -12,12 +12,12 @@ module keeps the same layout but makes it live where it is consumed:
     wrappers), plus a ``slots`` companion mapping bottom-row keys to
     their state slots so epoch refreshes are pure gathers;
   * :func:`build_device` / :func:`from_state_device` — jitted full
-    construction (device co-sort + the same mask/prefix-sum pass as
-    ``level_arrays._assemble``);
+    construction (device co-sort + the row layout of
+    ``level_arrays._assemble``, by mask and sort);
   * :func:`refresh_device` — jitted incremental rebuild: alive
     keys/heights are read from the state *on device*, inserted keys are
     merged into the previous sorted bottom row by one sort (deletions
-    are masked out by absence), and the prefix-sum re-layering reruns —
+    are masked out by absence), and the row re-layering reruns —
     no full-membership sort, no host transfer, no shape change; with
     ``return_overflow=True`` it also reports the alive keys it could
     not represent (DESIGN.md §5.4 rebuild protocol);
@@ -32,19 +32,20 @@ module keeps the same layout but makes it live where it is consumed:
     emitting a *segmented* plane whose routed-search load balances
     under skew.
 
-Stream compaction is a sort that carries its payloads: each row of the
-plane is laid out by one ``lax.sort`` along the width axis (the row's
-members keyed by column, the rest lifted past them), and the insert
-merge is one sort of the survivors with the new keys.  The sort brings
-the keys, heights, slots and next-row prefix counts along, so no
-per-lane gather reads through a permutation afterwards; the
-width-sharded refresh sorts each shard's own block the same way and
-shifts the resulting runs into place.  The inverse
-prefix sum it replaces (:func:`_compact_take`, a binary search per
-output lane, kept for the plane auditor and as the tests' oracle) runs
-one dependent gather per lane per search round: on a v5e at
-``L = 17, W = 131072`` it took 451 ms a call where the sort takes
-4.4 ms (a scatter of each payload to its prefix count took 33 ms).
+Stream compaction is a sort: each row of the plane is laid out by one
+single-operand ``lax.sort`` along the width axis of the bottom row with
+the row's non-members lifted to ``PAD_KEY`` (the bottom row is
+ascending on its live lanes, so the members come first, in order), and
+the insert merge is one sort of the survivors with the new keys that
+carries their heights and slots.  No per-lane gather reads through a
+permutation afterwards; the width-sharded refresh sorts each shard's
+own block the same way and shifts the resulting runs into place.  The
+inverse prefix sum the sort replaced (:func:`_compact_take`, a binary
+search per output lane, kept for the plane auditor and as the tests'
+oracle) runs one dependent gather per lane per search round: on a v5e
+at ``L = 17, W = 131072`` it took 451 ms a call where a 3-operand
+compaction sort took 4.4 ms (a scatter of each payload to its prefix
+count took 33 ms).
 The epoch's newly inserted keys are ordered by ``splaylist.key_order``
 and the smallest ``max_new`` kept.
 
@@ -75,37 +76,32 @@ PAD_KEY = sx.POS_INF_32
 
 
 class DeviceLevelArrays(NamedTuple):
-    """The TPU-native splay layout, device-resident (same fields and
-    semantics as ``level_arrays.LevelArrays`` plus the slot map).
+    """The TPU-native splay layout, device-resident (the fields and
+    semantics of ``level_arrays.LevelArrays`` plus the slot map and the
+    residency set).
 
     Arrays are *global*: a width-sharded plane
     (``sharding.shard_index_plane``) keeps these exact shapes and
-    values and only changes placement — ``keys``/``rank_map`` split
-    their width dimension over the mesh's model axis, ``heights``/
-    ``slots`` likewise, ``widths`` replicates.  ``slots`` pad lanes
+    values and only changes placement — ``keys`` splits its width
+    dimension over the mesh's model axis, ``heights``/``slots``
+    likewise, ``widths`` replicates.  ``slots`` pad lanes
     (columns at or beyond the bottom row's live width) are unspecified
     and must not be read."""
     keys: jax.Array        # int32 [L, W], +INF padded, sorted, nested
     widths: jax.Array      # int32 [L], live entries per row
     heights: jax.Array     # int32 [W], splay height of bottom-row keys
-    rank_map: jax.Array    # int32 [L, W], index of keys[r, j] in row r+1
     slots: jax.Array       # int32 [W], state slot of bottom-row key j
     #                        (-1 when unknown: refresh falls back to the
     #                        scatter path for the epoch and re-derives it)
-    bot_rank: jax.Array    # int32 [L, W], index of keys[r, j] in the
-    #                        bottom row (the search's hit short-circuit:
-    #                        a membership hit at (r, j) answers its
-    #                        bottom-row rank without descending further;
-    #                        pad lanes are unspecified and never read)
     # --- segmented-provenance residency (DESIGN.md §5.8) --------------
     # The §5.6 mass-split refresh materializes each shard's local
     # [L, W/S] sub-plane; these fields keep its ingredients resident so
-    # the sharded search consumes keys/rank_map/bot_rank blocks AS the
-    # local sub-plane instead of re-deriving it per batch.  local_ok is
-    # the staleness bit: 1 only when keys/rank_map/bot_rank blocks are
-    # per-shard local sub-planes (set by refresh_device_sharded's mass
-    # split); every replicated builder/refresh resets it to 0, sending
-    # the search back to the per-batch assemble fallback.
+    # the sharded search consumes keys blocks AS the local sub-plane
+    # instead of re-deriving it per batch.  local_ok is the staleness
+    # bit: 1 only when keys blocks are per-shard local sub-planes (set
+    # by refresh_device_sharded's mass split); every replicated
+    # builder/refresh resets it to 0, sending the search back to the
+    # per-batch assemble fallback.
     local_bot: jax.Array      # int32 [W], shard's own sorted bottom
     #                           segment (+INF padded within its block)
     local_heights: jax.Array  # int32 [W], aligned splay heights
@@ -132,48 +128,26 @@ def _compact_take(cs: jax.Array, width: int) -> jax.Array:
 
 def _assemble_device(keys_sorted: jax.Array, rel_h: jax.Array,
                      slots: jax.Array, n_levels: int) -> DeviceLevelArrays:
-    """The mask/prefix-sum construction of ``level_arrays._assemble`` on
-    device: ``keys_sorted`` [W] holds the live keys sorted ascending in a
+    """The device counterpart of ``level_arrays._assemble``:
+    ``keys_sorted`` [W] holds the live keys sorted ascending in a
     prefix, PAD_KEY after; ``rel_h``/``slots`` [W] are aligned (pad lanes
-    ignored).  Row compaction is one sort along the width axis: each
-    lane's key is its column, lifted by ``W`` where the row does not
-    hold it, so the row's members come first in bottom-row order, and
-    the sort carries the bottom-row key and the next row's prefix count
-    with them — nothing is gathered afterwards."""
-    width = keys_sorted.shape[0]
+    ignored).  Row compaction is one single-operand sort along the width
+    axis: row r keeps the bottom-row keys it holds and lifts the rest to
+    PAD_KEY, and since the live keys ascend, the sort packs the row's
+    members first, in order — nothing is gathered afterwards."""
     alive = keys_sorted != PAD_KEY
     h = jnp.where(alive, rel_h, -1)
 
     row_min_h = (n_levels - 1 - jnp.arange(n_levels, dtype=jnp.int32))
     mask = h[None, :] >= row_min_h[:, None]                # [L, W]
-    cs = jnp.cumsum(mask, axis=1, dtype=jnp.int32)         # [L, W]
-    widths = cs[:, width - 1]
-
-    col = jnp.arange(width, dtype=jnp.int32)
-    live = col[None, :] < widths[:, None]
-    # rank map: the key at (r, j) sits in row r+1 at that row's prefix
-    # count minus one (nested rows); pad entries close the descent
-    # window at the next row's live width; bottom row is the identity.
-    cs_next = jnp.concatenate(
-        [cs[1:], jnp.ones((1, width), jnp.int32)], axis=0)
+    widths = jnp.sum(mask, axis=1, dtype=jnp.int32)
     with jax.named_scope("splay.compact"):
-        # the sorted lane keys on live lanes are `take`, the bottom-row
-        # index of the member at (r, j) — which is also its bottom rank
-        take, cs_taken, rows = jax.lax.sort(
-            (jnp.where(mask, col, width + col), cs_next,
-             jnp.broadcast_to(keys_sorted, (n_levels, width))),
-            dimension=1, num_keys=1, is_stable=False)
-        rows = jnp.where(live, rows, PAD_KEY)
-        bot_rank = jnp.where(live, take, widths[n_levels - 1])
-    pad_default = jnp.concatenate(
-        [widths[1:], jnp.zeros((1,), jnp.int32)])
-    rank_map = jnp.where(live, cs_taken - 1, pad_default[:, None])
-    rank_map = rank_map.at[n_levels - 1].set(col)
+        rows = jax.lax.sort(jnp.where(mask, keys_sorted, PAD_KEY),
+                            dimension=1, is_stable=False)
 
     heights = jnp.where(alive, rel_h, 0).astype(jnp.int32)
     return DeviceLevelArrays(
-        keys=rows, widths=widths, heights=heights, rank_map=rank_map,
-        slots=slots, bot_rank=bot_rank,
+        keys=rows, widths=widths, heights=heights, slots=slots,
         # residency defaults: the assembled inputs are recorded as
         # provenance, but the validity bit stays 0 — only the sharded
         # mass-split refresh may promote a plane to resident (its blocks
@@ -190,7 +164,7 @@ def build_device(keys: jax.Array, rel_h: jax.Array,
     """Full on-device build from bare (keys, heights): ``keys`` [W]
     int32 with PAD_KEY in dead lanes, ``rel_h`` [W] aligned.  One stable
     device co-sort (live keys are < PAD_KEY so they land in a sorted
-    prefix), then the shared prefix-sum pass.  The slot map is unknown
+    prefix), then the shared row layout.  The slot map is unknown
     (-1): fine for kernel fixtures; planes that will be *refreshed*
     against a state should come from :func:`from_state_device`, which
     fills it (a -1 slot map just makes the first refresh take the
@@ -291,7 +265,7 @@ def refresh_device(st: sx.SplayState, prev: DeviceLevelArrays,
          the bound are dropped from the plane until the next full
          build), then merged with the survivors by one sort that
          carries heights and slots;
-      4. the prefix-sum re-layering reruns on the merged row.
+      4. the row re-layering reruns on the merged row.
 
     The slot map is validated against the state (``rebuild`` compacts
     slots); a stale or absent map routes that epoch through a scatter
@@ -429,10 +403,10 @@ def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
     that names layers by jitted function puts it in the refresh.
 
     The re-layering sorts each shard's own ``[L, W/S]`` block once
-    (``_assemble_device``, carrying key, column and next-row rank) and
-    places every level row's runs by whole-run shifts: each shard's
-    members of a row hold a contiguous range of the row's global ranks,
-    so no lane searches the composed ``[W]`` row.
+    (``_assemble_device``, one single-operand sort) and places every
+    level row's runs by whole-run shifts: each shard's members of a row
+    hold a contiguous range of the row's global ranks, so no lane
+    searches the composed ``[W]`` row.
 
     Budget per shard and epoch: resident state O(L·W/S) (its plane
     blocks) + O(W) transient buffers (the [L, W] rectangle is never
@@ -440,7 +414,7 @@ def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
     step); compute O(capacity·log(W/S)) for the classification, one
     sort of ``[L, W/S]`` for the re-layering and O(L·W) lane moves for
     the placement, the local merge one sort of W/S + max_new lanes;
-    wire O(W + S·max_new) for the segment exchange plus 3·W received
+    wire O(W + S·max_new) for the segment exchange plus W received
     per level row of the placement."""
     S = n_shards
     wl = width // S
@@ -574,7 +548,7 @@ def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
         # own block prefix, +INF pads after.  The plane becomes
         # *segmented*: per-block sorted runs with pads at segment
         # boundaries — searched correctly ONLY by the sharded search
-        # (keys/rank_map/heights hold each shard's local sub-plane;
+        # (keys/heights hold each shard's local sub-plane;
         # widths stays the global per-row live count).
         pos_g = jnp.arange(width, dtype=jnp.int32)
         keys_g = pick(segs_k, pos_g, jnp.int32(PAD_KEY))   # [W] merged
@@ -604,7 +578,7 @@ def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
         local = _assemble_device(k_seg, h_seg, s_seg, n_levels)
         with jax.named_scope("splay.redistribute"):
             widths_g = jax.lax.psum(local.widths, axis)
-        # keys/rank_map/bot_rank ARE this shard's local sub-plane here —
+        # keys ARE this shard's local sub-plane here —
         # record the segment they were assembled from and set the
         # residency bit, so the sharded search consumes them directly
         # instead of re-running _assemble_device per batch (§5.8).
@@ -616,14 +590,13 @@ def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
         return plane, overflow
 
     # ---- re-layering of the shard's own columns of the packed row.
-    # Each shard lays out its [L, W/S] block by one payload-carrying
-    # sort (_assemble_device, the replicated refresh's compaction): row
-    # r's local members come first, carrying their key, their own
-    # column and their rank in the shard's row r+1.  An exclusive
-    # cross-shard scan of per-row totals then lifts local ranks to
-    # global ones: shard t's row-r run holds the row's members of
-    # global ranks [row_offs[t, r], row_offs[t, r] + tots[t, r]), and
-    # these runs tile the row in shard order.
+    # Each shard lays out its [L, W/S] block by one sort
+    # (_assemble_device, the replicated refresh's compaction): row r's
+    # local members come first, in key order.  An exclusive cross-shard
+    # scan of per-row totals then places them: shard t's row-r run
+    # holds the row's members of global ranks [row_offs[t, r],
+    # row_offs[t, r] + tots[t, r]), and these runs tile the row in
+    # shard order.
     k_own = pick(segs_k, col_g, jnp.int32(PAD_KEY))
     h_own = pick(segs_h, col_g, jnp.int32(0))
     slots_own = pick(segs_s, col_g, jnp.int32(-1))
@@ -632,10 +605,6 @@ def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
         tots = jax.lax.all_gather(local.widths, axis)  # [S, L]
         row_offs = jnp.cumsum(tots, axis=0) - tots     # [S, L] exclusive
         widths_g = jnp.sum(tots, axis=0)               # [L] global
-    offs_up = jnp.concatenate([row_offs[ax, 1:],
-                               jnp.zeros((1,), jnp.int32)])
-    runs = (local.keys, ax * wl + local.bot_rank,
-            local.rank_map + offs_up[:, None])
 
     # ---- placement, one level row per scan step (an all_gather of the
     # row's runs per step: a shard holds O(W) transient buffers, never
@@ -643,43 +612,34 @@ def _refresh_device_shard(st: sx.SplayState, prev: DeviceLevelArrays, *,
     # device's memory).  Output lane g of this shard takes run t's
     # member g - row_offs[t, r]: a shift of each run by a whole offset,
     # so every run is sliced once at that offset (zero-padded by a
-    # block each side) and kept where it covers.
+    # block each side) and kept where it covers; lanes no run covers
+    # are the row's pads.
     def place_row(_, inp):
-        run_r, offs_r, tots_r = inp                    # [3, wl], [S], [S]
+        run_r, offs_r, tots_r = inp                    # [wl], [S], [S]
         with jax.named_scope("splay.redistribute"):
-            got = jax.lax.all_gather(jnp.stack(run_r), axis)  # [S,3,wl]
-            got = jnp.pad(got, ((0, 0), (0, 0), (wl, wl)))
-            out = jnp.zeros((3, wl), jnp.int32)
+            got = jax.lax.all_gather(run_r, axis)      # [S, wl]
+            got = jnp.pad(got, ((0, 0), (wl, wl)))
+            out = jnp.full((wl,), PAD_KEY, jnp.int32)
             for t in range(S):
                 shift = ax * wl - offs_r[t]
                 src = shift + col_l
                 cover = (src >= 0) & (src < tots_r[t])
                 piece = jax.lax.dynamic_slice(
-                    got[t], (0, jnp.clip(shift, -wl, wl) + wl), (3, wl))
-                out = jnp.where(cover[None, :], piece, out)
+                    got[t], (jnp.clip(shift, -wl, wl) + wl,), (wl,))
+                out = jnp.where(cover, piece, out)
         return None, out
 
-    _, placed = jax.lax.scan(
+    _, rows_own = jax.lax.scan(
         place_row, None,
-        (runs, jnp.transpose(row_offs), jnp.transpose(tots)))  # [L, 3, wl]
-    live = col_g[None, :] < widths_g[:, None]
-    rows_own = jnp.where(live, placed[:, 0], PAD_KEY)
-    # bottom rank of own output lanes: the member's global column of the
-    # packed row
-    bot_rank_own = jnp.where(live, placed[:, 1], widths_g[n_levels - 1])
-    # rows 0..L-2: live rank in the next row, pad lanes close the window
-    # at the next row's live width; bottom row is the (global-column)
-    # identity
-    rank_own = jnp.where(live[:-1], placed[:-1, 2], widths_g[1:, None])
-    rank_own = jnp.concatenate([rank_own, col_g[None, :]], axis=0)
+        (local.keys, jnp.transpose(row_offs), jnp.transpose(tots)))
     heights_own = local.heights
 
     plane = DeviceLevelArrays(
         keys=rows_own, widths=widths_g, heights=heights_own,
-        rank_map=rank_own, slots=slots_own, bot_rank=bot_rank_own,
-        # lanes split keeps the packed global layout: blocks of
-        # keys/rank_map are global-row columns, NOT local sub-planes,
-        # so residency stays invalid (the search assembles per batch)
+        slots=slots_own,
+        # lanes split keeps the packed global layout: keys blocks are
+        # global-row columns, NOT local sub-planes, so residency stays
+        # invalid (the search assembles per batch)
         local_bot=k_own, local_heights=heights_own,
         local_live=(k_own != PAD_KEY).astype(jnp.int32),
         local_ok=jnp.zeros((1,), jnp.int32))
@@ -720,8 +680,8 @@ def refresh_device_sharded(st: sx.SplayState, prev: DeviceLevelArrays,
     Sharding contract: the state is replicated (every shard classifies
     its own key range against the full state); ``prev`` should be laid
     out by ``sharding.shard_index_plane`` /
-    :func:`sharding.index_plane_specs` — keys/rank_map ``P(None,
-    axis)``, heights/slots ``P(axis)``, widths replicated.  The result
+    :func:`sharding.index_plane_specs` — keys ``P(None, axis)``,
+    heights/slots ``P(axis)``, widths replicated.  The result
     carries the same layout.
 
     Returns ``(plane, overflow_count)``.  ``overflow_count`` (int32,
@@ -755,7 +715,7 @@ def refresh_device_sharded(st: sx.SplayState, prev: DeviceLevelArrays,
 
     Equivalence: on any 1×N host mesh the ``"lanes"`` result is
     bit-identical to the replicated refresh on ``keys``/``widths``/
-    ``heights``/``rank_map``/``bot_rank`` (asserted in
+    ``heights`` (asserted in
     ``tests/test_sharded_refresh.py``); the ``slots`` companion agrees
     on live lanes (pad lanes are unspecified in both paths and never
     read).  The ``"mass"`` result indexes the same key set (same
@@ -810,5 +770,4 @@ def to_host(plane: DeviceLevelArrays):
     from repro.core import level_arrays as la
     return la.LevelArrays(
         keys=np.asarray(plane.keys), widths=np.asarray(plane.widths),
-        heights=np.asarray(plane.heights),
-        rank_map=np.asarray(plane.rank_map))
+        heights=np.asarray(plane.heights))

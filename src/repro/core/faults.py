@@ -11,9 +11,9 @@ snapshot/restore path must survive — and applies each event once.
 Four fault families (the chaos probe gates all of them):
 
 ``FAULT_BITFLIP``     flip ``arg`` random bits in live lanes of the
-                      device plane (keys / heights / rank_map /
-                      bot_rank), leaving the state untouched — the
-                      plane fsck must catch the divergence.
+                      device plane (keys / heights), leaving the state
+                      untouched — the plane fsck must catch the
+                      divergence.
 ``FAULT_SHARD_LOSS``  shrink the serving mesh to ``arg`` surviving
                       shards mid-serving (S -> S'); the pool rebuilds
                       the plane from the authoritative state via
@@ -45,10 +45,10 @@ FAULT_CRASH = "crash"
 FAULT_FAMILIES = (FAULT_BITFLIP, FAULT_SHARD_LOSS, FAULT_TELEMETRY,
                   FAULT_CRASH)
 
-# plane fields a bit-flip may target (2D descent arrays + the bottom
-# height vector; widths/local_* corruption is covered by flipping the
-# arrays they must agree with)
-BITFLIP_FIELDS = ("keys", "heights", "rank_map", "bot_rank")
+# plane fields a bit-flip may target (the [L, W] key rectangle the
+# descent reads + the bottom height vector; widths/local_* corruption
+# is covered by flipping the arrays they must agree with)
+BITFLIP_FIELDS = ("keys", "heights")
 
 
 class InjectedFault(RuntimeError):
@@ -127,9 +127,9 @@ def flip_plane_bits(plane, rng: np.random.Generator, n_flips: int = 1,
     XORs into *specified* (kernel-read) lanes of the plane, each
     logged as ``(field, index_tuple, bit)``.
 
-    Targets live lanes only (pad-lane entries of ``bot_rank``/
-    ``slots`` are documented unspecified — flipping them must not
-    count as corruption), and low-order bits (0..15) so a flipped key
+    Targets live lanes only (pad-lane entries of ``slots`` are
+    documented unspecified — flipping them must not count as
+    corruption), and low-order bits (0..15) so a flipped key
     stays in-range rather than teleporting to a sentinel.  The
     corrupted arrays are re-placed with the original array's sharding,
     so sharded planes stay sharded."""
@@ -148,8 +148,7 @@ def flip_plane_bits(plane, rng: np.random.Generator, n_flips: int = 1,
         field = fields[int(rng.integers(len(fields)))]
         arr = plane_np[field]
         if arr.ndim == 2:
-            rows, cols = np_.nonzero(live if field != "rank_map"
-                                     else live[:-1])
+            rows, cols = np_.nonzero(live)
             if rows.size == 0:
                 continue
             pick = int(rng.integers(rows.size))

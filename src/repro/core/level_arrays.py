@@ -9,24 +9,16 @@ key; by the splay property hot keys live in the small top rows, which stay
 VMEM-resident.  This is the paper's "popular elements move up" realized in
 the TPU memory hierarchy instead of list levels.
 
-Two additions carry the memory-tiling story (DESIGN.md §5.2):
-
-  * ``rank_map[r, j]`` — the index of ``keys[r, j]`` in row ``r + 1``
-    (rows are nested, so every row-r key appears one row down).  The
-    search kernel uses it for rank-windowed descent: the predecessor
-    rank at level r bounds a narrow window at level r+1, so per-query
-    work drops from O(L·W) to O(L·log window).  Pad entries map to
-    ``widths[r + 1]`` (one past the last live entry of the next row),
-    which closes the window for queries that ran off the row's end.
-  * an incremental :func:`refresh` path — after a rebalance epoch only
-    the heights move, not the membership, so the sorted bottom row can
-    be reused and the O(n log n) argsort skipped; serving loops call
-    ``refresh(state, prev)`` instead of rebuilding from scratch.
+``widths[r]`` counts row r's live entries, which is all the tiered
+descent reads besides the keys (DESIGN.md §5.2).  An incremental
+:func:`refresh` path rides along: after a rebalance epoch only the
+heights move, not the membership, so the sorted bottom row can be
+reused and the O(n log n) argsort skipped; serving loops call
+``refresh(state, prev)`` instead of rebuilding from scratch.
 
 The construction itself is a vectorized mask/prefix-sum pass (no Python
 loop over levels): position of key i in row r is the prefix count of
-keys j <= i with height >= h_r, which also *is* the rank map once read
-off one row down.
+keys j <= i with height >= h_r.
 
 This module is the HOST oracle: serving loops use the device-resident
 mirror (``core/device_index.py``, DESIGN.md §5.3), which runs the same
@@ -51,9 +43,6 @@ class LevelArrays(NamedTuple):
     keys: np.ndarray        # int32 [n_levels, width], +INF padded, sorted
     widths: np.ndarray      # int32 [n_levels], live entries per row
     heights: np.ndarray     # int32 [width]: splay height of bottom row keys
-    rank_map: np.ndarray    # int32 [n_levels, width]: index of keys[r, j]
-    #                         in row r+1 (identity on the bottom row; pad
-    #                         entries hold widths[r + 1])
 
 
 def _extract(st: sx.SplayState) -> Tuple[np.ndarray, np.ndarray]:
@@ -93,8 +82,7 @@ def build(keys: np.ndarray, rel_h: np.ndarray, min_levels: int = 2,
 def _assemble(keys_sorted: np.ndarray, rel_h: np.ndarray,
               min_levels: int, width: Optional[int]) -> LevelArrays:
     """Vectorized construction from already-sorted keys: one [L, n]
-    membership mask, one prefix-sum for in-row positions, and the rank
-    maps read off the same prefix sums one row down."""
+    membership mask and one prefix-sum for in-row positions."""
     n = len(keys_sorted)
     max_h = int(rel_h.max()) if n else 0
     n_levels = max(max_h + 1, min_levels)
@@ -107,23 +95,13 @@ def _assemble(keys_sorted: np.ndarray, rel_h: np.ndarray,
     widths = mask.sum(axis=1).astype(np.int32)
 
     rows = np.full((n_levels, width), PAD_KEY, np.int32)
-    rank_map = np.empty((n_levels, width), np.int32)
-    rank_map[-1] = np.arange(width, dtype=np.int32)        # bottom: identity
-    if n_levels > 1:
-        rank_map[:-1] = widths[1:, None]                   # pad default
     if n:
         rr, ii = np.nonzero(mask)
         rows[rr, pos[rr, ii]] = keys_sorted[ii]
-        if n_levels > 1:
-            rr2, ii2 = np.nonzero(mask[:-1])
-            # nested rows: every key of row r sits in row r+1, at the
-            # next row's prefix position
-            rank_map[rr2, pos[rr2, ii2]] = pos[rr2 + 1, ii2]
 
     hb = np.zeros((width,), np.int32)
     hb[:n] = rel_h
-    return LevelArrays(keys=rows, widths=widths, heights=hb,
-                       rank_map=rank_map)
+    return LevelArrays(keys=rows, widths=widths, heights=hb)
 
 
 def refresh(st: sx.SplayState, prev: LevelArrays,

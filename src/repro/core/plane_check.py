@@ -28,12 +28,11 @@ Invariants audited (field → what the kernels assume):
                       the number of bottom lanes with
                       ``heights >= L-1-r`` (heights↔row membership
                       prefix consistency); live heights non-negative
-``rank_map_bad``      live lanes: ``keys[r+1, base + rank_map[r, j]]``
-                      recovers ``keys[r, j]`` (block-local index); the
-                      bottom row is the identity map; pad lanes close
-                      the descent window at the next row's live count
-``bot_rank_bad``      live lanes: ``keys[L-1, base + bot_rank[r, j]]``
-                      recovers ``keys[r, j]`` (early-exit companion)
+``upper_bad``         every live key of a row above the bottom is in
+                      its block's bottom segment (one ``searchsorted``
+                      per row), at a height of at least ``L-1-r`` — with
+                      ``row_unsorted`` and ``heights_bad`` this pins
+                      each row to exactly the keys its height admits
 ``local_bad``         when ``local_ok == 1``: ``local_bot`` /
                       ``local_heights`` / ``local_live`` are exact
                       copies of the resident bottom row (the §5.8
@@ -54,8 +53,8 @@ Invariants audited (field → what the kernels assume):
 Segment discipline: ``n_segments`` is static.  ``1`` audits the packed
 / global layout (meshless planes, lanes-split sharded planes); ``S``
 audits the §5.6 mass-split layout where each of the ``S`` width-``W/S``
-blocks is an independent local assembly (block-local ``rank_map`` /
-``bot_rank`` indices, per-block pad defaults).  ``audit_plane`` infers
+blocks is an independent local assembly (each row's run packed from
+its block's first lane).  ``audit_plane`` infers
 the segment count from the concrete layout when not given.
 
 ``state_missing``/``state_extra`` compare against the state *snapshot*
@@ -93,8 +92,7 @@ class PlaneAudit(NamedTuple):
     block_order: int
     widths_bad: int
     heights_bad: int
-    rank_map_bad: int
-    bot_rank_bad: int
+    upper_bad: int
     local_bad: int
     state_missing: int
     state_extra: int
@@ -116,7 +114,6 @@ def _audit_device(st: sx.SplayState, plane: dix.DeviceLevelArrays,
     keys = plane.keys
     col = jnp.arange(W, dtype=jnp.int32)
     blk = col // wl
-    loc = col - blk * wl
     live = keys != PAD_KEY                      # [L, W]
     bot = keys[L - 1]
     bot_live = live[L - 1]
@@ -156,30 +153,22 @@ def _audit_device(st: sx.SplayState, plane: dix.DeviceLevelArrays,
     heights_bad = (jnp.sum(exp_cnt != got_cnt)
                    + jnp.sum(bot_live & (h < 0)))
 
-    # -- rank_map: pointer recovery + identity bottom + pad windows ------
-    blk_cnt = got_cnt                                         # [L, S]
-    rm = plane.rank_map[:-1]                                  # [L-1, W]
-    base = (blk * wl)[None, :]
-    nxt_idx = jnp.clip(base + rm, 0, W - 1)
-    tgt = jnp.take_along_axis(keys[1:], nxt_idx, axis=1)
-    live_u = live[:-1]
-    rank_live_bad = live_u & ((rm < 0) | (rm >= wl)
-                              | (tgt != keys[:-1]))
-    # pad lanes hold the next row's (block-local) live count — the
-    # closed descent window the kernels rely on to skip dead lanes
-    nxt_cnt = jnp.repeat(blk_cnt[1:], wl, axis=1)             # [L-1, W]
-    rank_pad_bad = ~live_u & (rm != nxt_cnt.astype(rm.dtype))
-    rank_bot_bad = plane.rank_map[L - 1] != loc
-    rank_map_bad = (jnp.sum(rank_live_bad) + jnp.sum(rank_pad_bad)
-                    + jnp.sum(rank_bot_bad))
-
-    # -- bot_rank: live lanes point at their bottom-row copy -------------
-    br = plane.bot_rank
-    br_idx = jnp.clip((blk * wl)[None, :] + br, 0, W - 1)
-    br_tgt = jnp.take_along_axis(
-        jnp.broadcast_to(bot, (L, W)), br_idx, axis=1)
-    bot_rank_bad = jnp.sum(live & ((br < 0) | (br >= wl)
-                                   | (br_tgt != keys)))
+    # -- upper rows: members of the block's bottom segment, tall enough -
+    # (a key changed to a value absent from the bottom row, order kept,
+    # passes every check above; the bottom row itself is checked
+    # against the state below)
+    if L > 1:
+        up = keys[:-1].reshape(L - 1, S, wl)
+        pos = jax.vmap(jax.vmap(jnp.searchsorted), in_axes=(None, 0))(
+            bot.reshape(S, wl), up)                           # [L-1, S, wl]
+        lane = (jnp.clip(pos, 0, wl - 1)
+                + (jnp.arange(S, dtype=jnp.int32) * wl)[None, :, None]
+                ).reshape(L - 1, W)
+        member = ((jnp.take(bot, lane) == keys[:-1])
+                  & (jnp.take(hh, lane) >= row_min[:-1, None]))
+        upper_bad = jnp.sum(live[:-1] & ~member)
+    else:
+        upper_bad = jnp.int32(0)
 
     # -- residency provenance (§5.8) -------------------------------------
     lok = plane.local_ok[0]
@@ -220,8 +209,7 @@ def _audit_device(st: sx.SplayState, plane: dix.DeviceLevelArrays,
         block_order=block_order.astype(jnp.int32),
         widths_bad=widths_bad.astype(jnp.int32),
         heights_bad=heights_bad.astype(jnp.int32),
-        rank_map_bad=rank_map_bad.astype(jnp.int32),
-        bot_rank_bad=bot_rank_bad.astype(jnp.int32),
+        upper_bad=upper_bad.astype(jnp.int32),
         local_bad=local_bad.astype(jnp.int32),
         state_missing=state_missing.astype(jnp.int32),
         state_extra=state_extra.astype(jnp.int32),

@@ -26,73 +26,66 @@ def exec_mode() -> str:
 
 
 def splay_search(level_keys, queries, query_block: int = 256,
-                 rank_map=None, widths=None, sharded=None,
-                 pipelined: bool = None):
+                 widths=None, sharded=None):
     """Batched level-array search (see kernels/splay_search.py).  Queries
     of any length (the kernel wrapper pads to the block multiple and
     slices back).  ``level_keys`` may be a bare [L, W] matrix or an index
     plane struct (``DeviceLevelArrays``/``LevelArrays``) — the struct's
-    precomputed rank_map/widths skip the on-the-fly window derivation.
-    A concretely width-sharded plane dispatches to the sharded search
+    precomputed widths skip counting them on the fly.  A concretely
+    width-sharded plane dispatches to the sharded search
     (``sharded=None`` auto-detects; True/False force either path —
-    DESIGN.md §5.5).  ``pipelined=True`` runs the §5.8 windowed-DMA
-    kernel (interpret mode only); ``None``/``False`` the tiered one."""
+    DESIGN.md §5.5)."""
     return ssk.splay_search(
         level_keys, queries, query_block=query_block,
-        interpret=not on_tpu(), rank_map=rank_map, widths=widths,
-        sharded=sharded, pipelined=pipelined)
+        interpret=not on_tpu(), widths=widths, sharded=sharded)
 
 
 def splay_search_sharded(plane, queries, query_block: int = 256,
                          mesh=None, axis: str = "model",
                          routed: bool = True, capacity: int = None,
                          slack: float = ssk.DEFAULT_ROUTE_SLACK,
-                         return_stats: bool = False,
-                         pipelined: bool = None):
+                         return_stats: bool = False):
     """Width-sharded tiered search: by default the routed all_to_all
     query exchange — owner-bucketed blocks shipped to the shard owning
     their bottom-row rank window, O(q/S) kernel work per shard, spill
     to the replicate-and-mask trace past ``capacity`` (see
     kernels/splay_search.py, DESIGN.md §5.6; ``routed=False`` keeps the
     masked full-batch trace).  Falls back to the replicated path when
-    no mesh resolves or the width is indivisible.  ``pipelined`` as in
-    :func:`splay_search` (per-shard §5.8 descent)."""
+    no mesh resolves or the width is indivisible."""
     return ssk.splay_search_sharded(
         plane, queries, query_block=query_block,
         interpret=not on_tpu(), mesh=mesh, axis=axis, routed=routed,
-        capacity=capacity, slack=slack, return_stats=return_stats,
-        pipelined=pipelined)
+        capacity=capacity, slack=slack, return_stats=return_stats)
 
 
 def splay_predecessor(plane, queries, query_block: int = 256,
-                      sharded=None, pipelined: bool = None):
+                      sharded=None):
     """Largest live key ``<= q`` and its packed-global rank —
     ``(keys [q], ranks [q])`` int32; ``(NEG_INF_KEY, -1)`` when no
     predecessor exists.  One descent + one select gather; dispatches
     replicated/sharded like :func:`splay_search` (DESIGN.md §5.10)."""
     return ssk.splay_predecessor(
         plane, queries, query_block=query_block,
-        interpret=not on_tpu(), sharded=sharded, pipelined=pipelined)
+        interpret=not on_tpu(), sharded=sharded)
 
 
 def splay_successor(plane, queries, query_block: int = 256,
-                    sharded=None, pipelined: bool = None):
+                    sharded=None):
     """Smallest live key ``>= q`` and its packed-global rank —
     ``(keys [q], ranks [q])`` int32; ``(PAD_KEY, live_count)`` when no
     successor exists (DESIGN.md §5.10)."""
     return ssk.splay_successor(
         plane, queries, query_block=query_block,
-        interpret=not on_tpu(), sharded=sharded, pipelined=pipelined)
+        interpret=not on_tpu(), sharded=sharded)
 
 
-def splay_rank(plane, queries, query_block: int = 256, sharded=None,
-               pipelined: bool = None):
+def splay_rank(plane, queries, query_block: int = 256, sharded=None):
     """Number of live keys ``<= q`` (int32 [q]) — the descent's
     bottom-row predecessor index plus one; one search call
     (DESIGN.md §5.10)."""
     return ssk.splay_rank(
         plane, queries, query_block=query_block,
-        interpret=not on_tpu(), sharded=sharded, pipelined=pipelined)
+        interpret=not on_tpu(), sharded=sharded)
 
 
 def splay_select(plane, ranks, sharded=None, mesh=None,
@@ -106,25 +99,24 @@ def splay_select(plane, ranks, sharded=None, mesh=None,
 
 
 def splay_range_count(plane, lo, hi, query_block: int = 256,
-                      sharded=None, pipelined: bool = None):
+                      sharded=None):
     """Live keys in the inclusive range ``[lo, hi]`` (int32 [q]; 0 for
     empty/inverted ranges) — a rank pair from one batched descent
     (DESIGN.md §5.10)."""
     return ssk.splay_range_count(
         plane, lo, hi, query_block=query_block,
-        interpret=not on_tpu(), sharded=sharded, pipelined=pipelined)
+        interpret=not on_tpu(), sharded=sharded)
 
 
 def splay_range_scan(plane, lo, hi, max_range: int,
-                     query_block: int = 256, sharded=None,
-                     pipelined: bool = None):
+                     query_block: int = 256, sharded=None):
     """Range members in key order: ``(keys [q, max_range], count [q],
     truncated [q])`` — ``count`` is the full population, ``truncated``
     what the static ``max_range`` capacity cut (counted, never silent);
     unused lanes hold ``PAD_KEY`` (DESIGN.md §5.10)."""
     return ssk.splay_range_scan(
         plane, lo, hi, max_range, query_block=query_block,
-        interpret=not on_tpu(), sharded=sharded, pipelined=pipelined)
+        interpret=not on_tpu(), sharded=sharded)
 
 
 def splay_top_k(plane, hits, k: int, sharded=None, mesh=None,
@@ -135,13 +127,6 @@ def splay_top_k(plane, hits, k: int, sharded=None, mesh=None,
     past the live count (DESIGN.md §5.10)."""
     return ssk.splay_top_k(plane, hits, k, sharded=sharded, mesh=mesh,
                            axis=axis)
-
-
-def splay_search_full(level_keys, queries, query_block: int = 256):
-    """Seed baseline kernel (whole level matrix as one resident block)."""
-    return ssk.splay_search_full(
-        level_keys, queries, query_block=query_block,
-        interpret=not on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=())

@@ -4,10 +4,8 @@ TPU adaptation of the paper's search phase (DESIGN.md §5): instead of
 pointer chasing, each splay level is a dense sorted row; a query block
 compares against rows top-down (row 0 = hottest).
 
-Three kernels live here:
-
-``splay_search`` — the tiered pipeline (DESIGN.md §5.2), the descent
-that runs on the chip.  Grid ``(query_blocks, n_levels)``; the level
+One kernel lives here, ``splay_search`` — the tiered pipeline
+(DESIGN.md §5.2).  Grid ``(query_blocks, n_levels)``; the level
 matrix is tiled *per row*, viewed as ``[L, W/128, 128]`` (one row
 block plus its ``[1, W/128]`` chunk-first sample row VMEM resident per
 grid step: O(W) instead of O(L·W)).  The row index_map goes through a
@@ -20,36 +18,16 @@ per-query gather (the TPU compiler lowers none from a ``[W]`` row): the
 queries are compared against the samples to pick their 128-lane chunk,
 a one-hot MXU product fetches that chunk, and a compare-and-count inside
 it gives the offset.  ``found``/``level_found`` accumulate in revisited
-output blocks.
+output blocks.  The kernel reads a plane's ``keys`` and ``widths`` and
+nothing else.
 
-``splay_search_pipelined`` — the foresight-pipelined descent (DESIGN.md
-§5.8): operands stay in HBM (``memory_space=ANY``) and the kernel
-double-buffers manual ``pltpu.make_async_copy`` tile fetches covering
-only the block's live ``[lo, hi)`` window union per level, launching
-the level-r+1 fetch before level-r's compute and suppressing every
-remaining row DMA once the whole block is resolved (membership hit, or
-a width-1 bottom-row window projection via the ``bot_rank`` companion).
-Bit-identical to the tiered kernel while streaming O(window) instead
-of O(W) bytes per row, and 0 bytes for rows below the block's
-resolution depth.  Interpret mode only: its per-query probes are
-``[QB]``-from-``[W]`` gathers, which the TPU compiler refuses.
-``splay_search`` takes ``pipelined=True`` to run it (``None``/``False``:
-tiered) and the sharded paths thread the same flag through their
-per-shard descents.
-
-``splay_search_full`` — the seed kernel, kept as the measured baseline:
-it declares the whole ``[n_levels, width]`` matrix as one constant block
-(entire matrix resident; full-width compare per level) and can only skip
-cold-row *compute*, never their residency.  ``benchmarks/kernels_bench``
-races the two and emits the bytes-touched model.
-
-Both wrappers pad the query batch to the block multiple internally and
-slice the outputs back — callers never pre-pad.  They also accept an
+The wrapper pads the query batch to the block multiple internally and
+slices the outputs back — callers never pre-pad.  It also accepts an
 index plane struct (``core.device_index.DeviceLevelArrays`` or the host
 ``core.level_arrays.LevelArrays``) in place of the bare key matrix, in
-which case the struct's precomputed rank map and row widths ride along
-(both the host build and the device build/refresh emit them); the
-``rank_windows`` jnp fallback below serves bare-matrix callers only.
+which case the struct's row widths ride along (both the host build and
+the device build/refresh emit them); a bare matrix has them counted on
+the fly.
 
 Sharding (DESIGN.md §5.5–§5.6): a plane laid out width-sharded by
 ``parallel.sharding.shard_index_plane`` executes the search *sharded* —
@@ -67,15 +45,14 @@ to the replicate-and-mask trace (the PR-4 path, kept as
 ``routed=False``): counted, never dropped, bit-identical either way.
 ``splay_search`` dispatches here automatically for a concretely
 width-sharded plane; gather-to-replicated remains the documented
-fallback (no mesh, one shard, indivisible width, or ``sharded=False``)
-and is all ``splay_search_full`` ever does.
+fallback (no mesh, one shard, indivisible width, or ``sharded=False``).
 
 Ordered operations (DESIGN.md §5.10): the descent's bottom-row
 predecessor rank is already an order statistic, so the full ordered-op
 family — ``splay_predecessor``/``splay_successor``, ``splay_rank``/
 ``splay_select``, ``splay_range_count``/``splay_range_scan`` (static
 ``max_range`` capacity, truncation counted, never silent) and
-``splay_top_k`` by hit mass — derives from the same search kernels plus
+``splay_top_k`` by hit mass — derives from the same search kernel plus
 packed bottom-row gathers.  Every op dispatches replicated vs. sharded
 exactly like ``splay_search``; on the sharded plane a rank (or a range
 of ranks) decomposes by the live-lane count prefix into per-shard
@@ -85,7 +62,6 @@ sub-ranges stitched back by one psum.
 from __future__ import annotations
 
 import functools
-import math
 from typing import NamedTuple
 
 import jax
@@ -175,47 +151,15 @@ def _reject_segmented(level_keys):
             "masked), or refresh it with split='lanes' to repack first")
 
 
-def rank_windows(level_keys):
-    """rank_map[r, j] = index of level_keys[r, j] in row r+1 (identity on
-    the bottom row; pad entries map to the next row's live width).  The
-    jnp fallback for bare-matrix callers — both plane builders
-    (``level_arrays.build`` on host, ``device_index`` on device)
-    precompute it."""
-    n_levels, width = level_keys.shape
-    ident = jnp.arange(width, dtype=jnp.int32)[None, :]
-    if n_levels == 1:
-        return ident
-    rm = jax.vmap(
-        lambda nxt, row: jnp.searchsorted(nxt, row, side="left"))(
-            level_keys[1:], level_keys[:-1])
-    return jnp.concatenate([rm.astype(jnp.int32), ident], axis=0)
+def _is_plane(x) -> bool:
+    """True for an index plane struct (``DeviceLevelArrays`` or
+    ``LevelArrays``), False for a bare ``[L, W]`` key matrix."""
+    return hasattr(x, "widths") and hasattr(x, "heights")
 
 
 def row_widths(level_keys):
     """Live entries per row (rows are +INF padded)."""
     return jnp.sum(level_keys != PAD_KEY, axis=1).astype(jnp.int32)
-
-
-def bottom_ranks(level_keys):
-    """bot_rank[r, j] = index of level_keys[r, j] in the bottom row —
-    the pipelined descent's hit short-circuit companion (DESIGN.md
-    §5.8): a membership hit at (r, j) answers its bottom-row rank
-    immediately, so a block whose every query has resolved stops
-    fetching rows.  Identity on the bottom row; pad lanes map to the
-    bottom live width (never read on hits).  The jnp fallback for
-    bare-matrix callers — both plane builders precompute it (device
-    planes carry it as ``DeviceLevelArrays.bot_rank``).  Assumes a
-    packed sorted bottom row (the same invariant as
-    :func:`rank_windows`)."""
-    n_levels, width = level_keys.shape
-    ident = jnp.arange(width, dtype=jnp.int32)[None, :]
-    if n_levels == 1:
-        return ident
-    bottom = level_keys[n_levels - 1]
-    br = jax.vmap(
-        lambda row: jnp.searchsorted(bottom, row, side="left"))(
-            level_keys[:-1])
-    return jnp.concatenate([br.astype(jnp.int32), ident], axis=0)
 
 
 def _check_query_block(query_block, nq):
@@ -239,9 +183,9 @@ def _check_query_block(query_block, nq):
 def _as_device_plane(plane):
     """Normalize an index plane struct to the full ``DeviceLevelArrays``
     pytree the sharded shard_maps expect: host ``LevelArrays`` (no slot
-    map, no residency set) get jnp fields, an unknown (-1) slot map, a
-    derived :func:`bottom_ranks` companion, and *stale* residency — the
-    per-batch assemble fallback stays their execution path."""
+    map, no residency set) get jnp fields, an unknown (-1) slot map and
+    *stale* residency — the per-batch assemble fallback stays their
+    execution path."""
     if hasattr(plane, "local_ok"):
         return plane
     from repro.core import device_index as dix
@@ -253,9 +197,7 @@ def _as_device_plane(plane):
         keys=keys,
         widths=jnp.asarray(plane.widths, jnp.int32),
         heights=heights,
-        rank_map=jnp.asarray(plane.rank_map, jnp.int32),
         slots=jnp.full((width,), -1, jnp.int32),
-        bot_rank=bottom_ranks(keys),
         local_bot=bot,
         local_heights=heights,
         local_live=(bot != PAD_KEY).astype(jnp.int32),
@@ -276,18 +218,6 @@ def tiered_row_bytes(n_levels: int, width: int) -> int:
     key row, padded to whole 128-lane chunks (the sample rows add
     1/128 of that and are left out)."""
     return n_levels * (-(-width // CHUNK) * CHUNK) * 4
-
-
-def descent_kind(width: int, pipelined: bool = None) -> str:
-    """Which descent kernel :func:`splay_search` runs on a plane of this
-    width: ``"tiered"`` unless the pipelined descent is asked for
-    (``pipelined=True``, interpret mode only), which itself hands
-    widths past its tile budget to the tiered one."""
-    if not pipelined:
-        return "tiered"
-    if width // math.gcd(width, 256) > _MAX_PIPE_TILES:
-        return "tiered (pipelined width fallback)"
-    return "pipelined"
 
 
 # ---------------------------------------------------------------------------
@@ -365,14 +295,13 @@ def _kernel_tiered(fetch_ref, widths_ref, q_ref, samp_ref, row_ref,
 
 def splay_search(level_keys, queries, query_block: int =
                  DEFAULT_QUERY_BLOCK, interpret: bool = True,
-                 rank_map=None, widths=None, sharded=None,
-                 pipelined: bool = None):
+                 widths=None, sharded=None):
     """Tiered batched search.  level_keys: int32 [n_levels, width]
     (sorted rows, +INF padded, nested) — or an index plane struct
-    (``DeviceLevelArrays``/``LevelArrays``), whose rank_map/widths are
-    used directly.  queries int32 [q] (any length — padded to the block
-    multiple internally).  rank_map/widths: precomputed companions
-    (derived on the fly when a bare matrix is passed without them).
+    (``DeviceLevelArrays``/``LevelArrays``), whose widths are used
+    directly.  queries int32 [q] (any length — padded to the block
+    multiple internally).  widths: the precomputed live count per row
+    (counted on the fly when a bare matrix is passed without it).
     Returns (found [q] bool, rank [q] int32, level_found [q] int32).
 
     Dispatch (DESIGN.md §5.5): ``sharded=None`` routes a plane that is
@@ -384,43 +313,22 @@ def splay_search(level_keys, queries, query_block: int =
     ``sharded=False`` forces the legacy gather-to-replicated execution
     (the single-device kernel on the gathered plane) — the seam the
     parity tests pin.  Replicated execution constrains the query batch
-    to the ``"batch"`` logical axis when a mesh is active.
-
-    ``pipelined`` picks the descent kernel (DESIGN.md §5.8): ``True``
-    the foresight-pipelined windowed-DMA kernel (interpret mode only;
-    :func:`descent_kind` names what answers), ``False``/``None`` the
-    tiered per-row stream.  Answers are bit-identical either way
-    (asserted in ``tests/test_pipelined_search.py``)."""
+    to the ``"batch"`` logical axis when a mesh is active."""
     nq = jnp.asarray(queries).shape[0]
     _check_query_block(query_block, nq)
-    if hasattr(level_keys, "rank_map"):        # index plane struct
+    if _is_plane(level_keys):
         plane = level_keys
         if sharded is None:
             sharded = shd.plane_width_mesh(plane) is not None
         if sharded:
             return splay_search_sharded(plane, queries,
                                         query_block=query_block,
-                                        interpret=interpret,
-                                        pipelined=pipelined)
+                                        interpret=interpret)
         level_keys = _replicated(jnp.asarray(plane.keys))
         _reject_segmented(level_keys)
-        if rank_map is None:
-            rank_map = _replicated(jnp.asarray(plane.rank_map))
         if widths is None:
             widths = _replicated(jnp.asarray(plane.widths))
-        if hasattr(plane, "bot_rank"):
-            bot_rank = _replicated(jnp.asarray(plane.bot_rank))
-        else:
-            bot_rank = None
-    else:
-        bot_rank = None
     queries = shd.constrain(jnp.asarray(queries), "batch")
-    if pipelined:
-        f, r, lv, _ = _splay_search_pipelined_arrays(
-            level_keys, queries, query_block=query_block,
-            interpret=interpret, rank_map=rank_map, widths=widths,
-            bot_rank=bot_rank)
-        return f, r, lv
     return _splay_search_arrays(level_keys, queries,
                                 query_block=query_block,
                                 interpret=interpret, widths=widths)
@@ -480,313 +388,6 @@ def _splay_search_arrays(level_keys, queries, query_block: int =
 
 
 # ---------------------------------------------------------------------------
-# pipelined kernel (DESIGN.md §5.8): foresight-windowed row DMA with
-# block-level early exit.  The operands stay in HBM (memory_space=ANY);
-# the kernel itself double-buffers manual async tile copies covering
-# only the block's live [lo, hi) window union at each level, issues the
-# level-r+1 fetch before computing level r (the rank map bounds the next
-# window union from the predecessors already in hand — the "foresight"
-# of the skiplist prefetching literature), and stops fetching entirely
-# once every query in the block is resolved.  Resolution = membership
-# hit (bot_rank answers the bottom rank at hit time) OR a width-1
-# bottom-row window projection (the predecessor rank is pinned) — so
-# hot-key batches resolve in the top rows and never stream the wide
-# bottom rows at all.  Bit-identical to the tiered kernel by
-# construction (same windows while unresolved; same rank/level algebra).
-# ---------------------------------------------------------------------------
-
-# Tile length of the windowed copies: the largest divisor of width that
-# is <= 256 (so tile boundaries always land in bounds without clamping
-# arithmetic inside the DMA descriptor).  A width whose tile count
-# exceeds _MAX_PIPE_TILES (pathological: large prime widths) falls back
-# to the tiered stream rather than unrolling hundreds of per-tile
-# copies.
-_MAX_PIPE_TILES = 64
-
-
-def _kernel_pipelined(widths_ref, q_ref, keys_hbm, rm_hbm, br_hbm,
-                      found_ref, rank_ref, level_ref, bytes_ref,
-                      kbuf, rmbuf, brbuf, sem, *,
-                      n_levels: int, width: int, n_steps: int,
-                      tile: int, max_tiles: int, n_live: int,
-                      query_block: int):
-    i = pl.program_id(0)
-    q = q_ref[...]                                     # [QB]
-    qb = q.shape[0]
-    gidx = (i * query_block
-            + jax.lax.broadcasted_iota(jnp.int32, (qb, 1), 0)[:, 0])
-    is_pad = gidx >= n_live                            # batch padding
-
-    w0 = widths_ref[0]
-    bot_w = widths_ref[n_levels - 1]
-
-    lo = jnp.where(is_pad, 0, -1)
-    hi = jnp.where(is_pad, 0, w0)
-    found = jnp.zeros((qb,), jnp.bool_)
-    rank = jnp.zeros((qb,), jnp.int32)
-    level = jnp.full((qb,), n_levels, jnp.int32)
-    resolved = is_pad
-    done = jnp.all(resolved)
-
-    def union_window(lo_, hi_, res):
-        # union [ulo, uhi) of the unresolved lanes' windows (resolved
-        # lanes are frozen at (0, 0) and masked out here)
-        ulo = jnp.min(jnp.where(res, jnp.int32(width), lo_))
-        uhi = jnp.max(jnp.where(res, jnp.int32(0), hi_))
-        return ulo, uhi
-
-    def cover(l, h):
-        # tile-aligned buffer cover [base, base + nt*tile): row reads
-        # reach index min(h, width-1) at most (probes stay below hi,
-        # the rank/bot companions are read at p+1 <= hi)
-        base = (jnp.clip(l, 0, width - 1) // tile) * tile
-        end = jnp.clip(h, 0, width - 1)
-        nt = jnp.maximum(-((base - (end + 1)) // tile), 1)
-        return base, nt
-
-    def copies(r, slot, base, k):
-        off = base + k * tile
-        return [
-            pltpu.make_async_copy(
-                src.at[r, pl.ds(off, tile)],
-                dst.at[slot, pl.ds(k * tile, tile)],
-                sem.at[slot, a, k])
-            for a, (src, dst) in enumerate(
-                ((keys_hbm, kbuf), (rm_hbm, rmbuf), (br_hbm, brbuf)))
-        ]
-
-    # prologue: row 0's cover into buffer slot 0
-    ulo0, uhi0 = union_window(lo, hi, resolved)
-    base0, nt0 = cover(ulo0, uhi0)
-    for k in range(max_tiles):
-        @pl.when(~done & (k < nt0))
-        def _start0(k=k):
-            for c in copies(0, 0, base0, k):
-                c.start()
-    fetched = jnp.where(done, 0, 3 * nt0 * tile)
-
-    def body(r, carry):
-        (lo, hi, found, rank, level, resolved, done,
-         inflight, base_c, nt_c, fetched) = carry
-        slot = jax.lax.rem(r, 2)
-
-        # ---- wait row r's tiles (issued at r-1 / the prologue).  Gated
-        # by the *issue-time* predicate, not `done`: an early exit still
-        # drains the one speculative in-flight row.
-        for k in range(max_tiles):
-            @pl.when(inflight & (k < nt_c))
-            def _wait(k=k):
-                for c in copies(r, slot, base_c, k):
-                    c.wait()
-
-        run = ~done
-        w_r = widths_ref[r]
-        next_w = widths_ref[jnp.minimum(r + 1, n_levels - 1)]
-
-        def bidx(pos):
-            # row position -> buffer lane.  Out-of-cover positions only
-            # occur on resolved/masked lanes; the clip keeps them in
-            # bounds (the values are never consumed).
-            return jnp.clip(pos - base_c, 0, width - 1)
-
-        # ---- foresight: bound row r+1's window union through row r's
-        # rank-map tiles and launch its fetch BEFORE computing row r —
-        # the copy overlaps the binary refinement below.  The bound is
-        # conservative (pre-compute unresolved set, monotone rank map),
-        # so the next cover always contains the post-compute windows.
-        rm_row = rmbuf[slot, :]
-        ulo, uhi = union_window(lo, hi, resolved)
-        l1 = jnp.where(ulo < 0, jnp.int32(-1),
-                       jnp.take(rm_row,
-                                bidx(jnp.clip(ulo, 0, width - 1))))
-        h1 = jnp.where((uhi >= width) | (w_r == 0), next_w,
-                       jnp.take(rm_row,
-                                bidx(jnp.clip(uhi, 0, width - 1))))
-        base_n, nt_n = cover(l1, h1)
-        want = run & (r < n_levels - 1)
-        slot_n = jax.lax.rem(r + 1, 2)
-        for k in range(max_tiles):
-            @pl.when(want & (k < nt_n))
-            def _start(k=k):
-                for c in copies(r + 1, slot_n, base_n, k):
-                    c.start()
-        fetched = fetched + jnp.where(want, 3 * nt_n * tile, 0)
-
-        # ---- compute row r on the buffered tiles ----------------------
-        def do_row(_):
-            row = kbuf[slot, :]
-            br_row = brbuf[slot, :]
-
-            def step(_, c):
-                lo_, hi_ = c
-                active = hi_ - lo_ > 1
-                mid = (lo_ + hi_) // 2
-                vals = jnp.take(row, bidx(jnp.clip(mid, 0, width - 1)))
-                le = vals <= q
-                return (jnp.where(active & le, mid, lo_),
-                        jnp.where(active & ~le, mid, hi_))
-
-            p, _ = jax.lax.fori_loop(0, n_steps, step, (lo, hi))
-            pc = bidx(jnp.clip(p, 0, width - 1))
-            pc1 = bidx(jnp.clip(p + 1, 0, width - 1))
-            pred = jnp.take(row, pc)
-            hit = (p >= 0) & (pred == q)
-            # bottom-row projection of the predecessor gap: once it has
-            # width 1, the bottom rank is pinned at bl and the lane is
-            # resolved without descending further (§5.8); a hit pins it
-            # too (bl = bot_rank of the hit key).
-            bl = jnp.where(p >= 0, jnp.take(br_row, pc), -1)
-            bh = jnp.where((p + 1 >= width) | (w_r == 0), bot_w,
-                           jnp.take(br_row, pc1))
-            lo_n = jnp.where(p >= 0, jnp.take(rm_row, pc), -1)
-            hi_n = jnp.where((p + 1 >= width) | (w_r == 0), next_w,
-                             jnp.take(rm_row, pc1))
-            return hit, bl, bh, lo_n, hi_n
-
-        def skip_row(_):
-            z = jnp.zeros((qb,), jnp.int32)
-            return jnp.zeros((qb,), jnp.bool_), z, z, z, z
-
-        hit, bl, bh, lo_n, hi_n = jax.lax.cond(run, do_row, skip_row,
-                                               operand=None)
-        hitn = hit & ~resolved
-        pinned = run & ~hit & ~resolved & (bh - bl == 1)
-        level = jnp.where(hitn, r, level)
-        rank = jnp.where(hitn | pinned, bl, rank)
-        found = found | hitn
-        resolved = resolved | hitn | pinned
-        lo = jnp.where(resolved, 0, lo_n)
-        hi = jnp.where(resolved, 0, hi_n)
-        done = done | jnp.all(resolved)
-        return (lo, hi, found, rank, level, resolved, done,
-                want, base_n, nt_n, fetched)
-
-    carry = (lo, hi, found, rank, level, resolved, done,
-             ~done, base0, nt0, fetched)
-    carry = jax.lax.fori_loop(0, n_levels, body, carry)
-    (lo, hi, found, rank, level, resolved, done,
-     inflight, base_c, nt_c, fetched) = carry
-    found_ref[...] = found
-    rank_ref[...] = rank
-    level_ref[...] = level
-    bytes_ref[...] = jnp.full((1,), fetched * 4, jnp.int32)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("query_block", "interpret"))
-def _splay_search_pipelined_arrays(level_keys, queries, query_block: int =
-                                   DEFAULT_QUERY_BLOCK,
-                                   interpret: bool = True, rank_map=None,
-                                   widths=None, bot_rank=None):
-    if not interpret:
-        raise ValueError(
-            "the pipelined descent runs in interpret mode only: its "
-            "per-query probes are [QB]-from-[W] gathers, which the TPU "
-            "compiler does not lower — use the tiered descent "
-            "(pipelined=False/None) on the chip")
-    n_levels, width = level_keys.shape
-    nq = queries.shape[0]
-    if nq == 0:
-        z = jnp.zeros((0,), jnp.int32)
-        return jnp.zeros((0,), jnp.bool_), z, z, z
-    if rank_map is None:
-        rank_map = rank_windows(level_keys)
-    if widths is None:
-        widths = row_widths(level_keys)
-    if bot_rank is None:
-        bot_rank = bottom_ranks(level_keys)
-    pad = (-nq) % query_block
-    nq_p = nq + pad
-    n_blocks = nq_p // query_block
-    tile = math.gcd(width, 256)
-    max_tiles = width // tile
-    if max_tiles > _MAX_PIPE_TILES:
-        # pathological width (no divisor near 256): the per-tile copy
-        # unroll would dominate — take the tiered stream and report its
-        # whole-row byte model (keys + rank map rows, 4 bytes a lane)
-        f, r, lv = _splay_search_arrays(
-            level_keys, queries, query_block=query_block,
-            interpret=interpret, widths=widths)
-        return f, r, lv, jnp.full((n_blocks,), tiered_row_bytes(
-            n_levels, width), jnp.int32)
-    if pad:
-        queries = jnp.pad(queries, (0, pad), constant_values=PAD_KEY - 1)
-    n_steps = max(int(width + 1).bit_length(), 1)
-    kernel = functools.partial(
-        _kernel_pipelined, n_levels=n_levels, width=width,
-        n_steps=n_steps, tile=tile, max_tiles=max_tiles, n_live=nq,
-        query_block=query_block)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((query_block,), lambda i, w: (i,)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=(
-            pl.BlockSpec((query_block,), lambda i, w: (i,)),
-            pl.BlockSpec((query_block,), lambda i, w: (i,)),
-            pl.BlockSpec((query_block,), lambda i, w: (i,)),
-            pl.BlockSpec((1,), lambda i, w: (i,)),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, width), jnp.int32),         # key tiles
-            pltpu.VMEM((2, width), jnp.int32),         # rank-map tiles
-            pltpu.VMEM((2, width), jnp.int32),         # bot-rank tiles
-            pltpu.SemaphoreType.DMA((2, 3, max_tiles)),
-        ],
-    )
-    out_shapes = (
-        jax.ShapeDtypeStruct((nq_p,), jnp.bool_),
-        jax.ShapeDtypeStruct((nq_p,), jnp.int32),
-        jax.ShapeDtypeStruct((nq_p,), jnp.int32),
-        jax.ShapeDtypeStruct((n_blocks,), jnp.int32),
-    )
-    found, rank, lvl, nbytes = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(widths, queries, jnp.asarray(level_keys, jnp.int32),
-      jnp.asarray(rank_map, jnp.int32), jnp.asarray(bot_rank, jnp.int32))
-    return found[:nq], rank[:nq], lvl[:nq], nbytes
-
-
-def splay_search_pipelined(level_keys, queries, query_block: int =
-                           DEFAULT_QUERY_BLOCK, interpret: bool = True,
-                           rank_map=None, widths=None, bot_rank=None):
-    """Foresight-pipelined batched search (DESIGN.md §5.8): same answer
-    triple as :func:`splay_search`, plus the per-block streamed-bytes
-    counter the windowed-DMA pipeline actually paid — ``(found [q],
-    rank [q], level_found [q], bytes [q_blocks] int32)``.  Accepts a
-    bare matrix or an index plane struct (whose precomputed
-    ``rank_map``/``widths``/``bot_rank`` companions ride along).
-    Bit-identical to the tiered kernel on every packed plane; the
-    tiered path remains the interpret-mode oracle the parity tests pin
-    this against.  Widths with no divisor <= 256 within a 64-tile
-    budget fall back to the tiered stream (bytes then report its
-    whole-row model)."""
-    if hasattr(level_keys, "rank_map"):        # index plane struct
-        plane = level_keys
-        level_keys = _replicated(jnp.asarray(plane.keys))
-        _reject_segmented(level_keys)
-        if rank_map is None:
-            rank_map = _replicated(jnp.asarray(plane.rank_map))
-        if widths is None:
-            widths = _replicated(jnp.asarray(plane.widths))
-        if bot_rank is None and hasattr(plane, "bot_rank"):
-            bot_rank = _replicated(jnp.asarray(plane.bot_rank))
-    queries = jnp.asarray(queries)
-    _check_query_block(query_block, queries.shape[0])
-    queries = shd.constrain(queries, "batch")
-    return _splay_search_pipelined_arrays(
-        level_keys, queries, query_block=query_block, interpret=interpret,
-        rank_map=rank_map, widths=widths, bot_rank=bot_rank)
-
-
-# ---------------------------------------------------------------------------
 # width-sharded execution (DESIGN.md §5.5–§5.6): ownership routing +
 # per-shard tiered descent on locally-assembled sub-planes.  Default is
 # the routed all_to_all query exchange; the replicate-and-mask trace is
@@ -839,23 +440,6 @@ def _owner_of(bounds, queries):
             .astype(jnp.int32) - 1)                    # in [0, S-1]
 
 
-def _descend_local(local, queries, *, query_block: int, interpret: bool,
-                   pipelined: bool):
-    """One local tiered descent over a shard's [L, W/S] sub-plane —
-    through the §5.8 foresight-pipelined kernel when ``pipelined``
-    (same answers; the per-block byte counter is dropped here), else
-    the tiered stream (the interpret-mode oracle)."""
-    if pipelined:
-        f, r, lv, _ = _splay_search_pipelined_arrays(
-            local.keys, queries, query_block=query_block,
-            interpret=interpret, rank_map=local.rank_map,
-            widths=local.widths, bot_rank=local.bot_rank)
-        return f, r, lv
-    return _splay_search_arrays(
-        local.keys, queries, query_block=query_block,
-        interpret=interpret, widths=local.widths)
-
-
 def _local_subplane(plane, *, n_levels: int):
     """The shard's local [L, W/S] sub-plane (runs under ``shard_map``;
     ``plane`` leaves are this shard's blocks).  The one shared entry to
@@ -863,9 +447,9 @@ def _local_subplane(plane, *, n_levels: int):
 
     Resident fast path (DESIGN.md §5.8): when the residency bit
     ``local_ok`` is set (only the mass-split refresh sets it), the
-    plane's keys/rank_map/bot_rank blocks already ARE the per-shard
-    local sub-plane — the only global field is ``widths``, re-derived
-    from the resident provenance by one mask-sum.  Stale residency
+    plane's keys blocks already ARE the per-shard local sub-plane —
+    the only global field is ``widths``, re-derived from the resident
+    provenance by one mask-sum.  Stale residency
     (any replicated build/refresh, lanes-split layout, host plane)
     re-layers the provenance blocks through ``_assemble_device`` per
     batch — the pre-§5.8 behavior, kept as the fallback.
@@ -894,7 +478,7 @@ def _local_subplane(plane, *, n_levels: int):
 
 
 def _masked_descent(local, bounds, lift, queries, *, axis: str,
-                    query_block: int, interpret: bool, pipelined: bool):
+                    query_block: int, interpret: bool):
     """The replicate-and-mask trace (the PR-4 §5.5 execution, now the
     spill target): every shard descends the FULL (replicated) query
     batch on its local sub-plane, masks the lanes it does not own, and
@@ -903,8 +487,9 @@ def _masked_descent(local, bounds, lift, queries, *, axis: str,
     — but any query answers correctly here, capacity-free."""
     owner = _owner_of(bounds, queries)
     mine = owner == jax.lax.axis_index(axis).astype(jnp.int32)
-    f, r, lv = _descend_local(local, queries, query_block=query_block,
-                              interpret=interpret, pipelined=pipelined)
+    f, r, lv = _splay_search_arrays(local.keys, queries,
+                                    query_block=query_block,
+                                    interpret=interpret, widths=local.widths)
     rank_g = jnp.where(r >= 0, r + lift, -1)
     stacked = jnp.where(mine[None, :],
                         jnp.stack([f.astype(jnp.int32), rank_g, lv]),
@@ -914,8 +499,7 @@ def _masked_descent(local, bounds, lift, queries, *, axis: str,
 
 
 def _search_shard_body(plane, queries, *, axis: str, n_levels: int,
-                       query_block: int, interpret: bool,
-                       pipelined: bool):
+                       query_block: int, interpret: bool):
     """Per-shard body of the ``routed=False`` path (runs under
     ``shard_map``; ``plane`` leaves are this shard's blocks, queries
     are replicated).  Three stages:
@@ -936,8 +520,8 @@ def _search_shard_body(plane, queries, *, axis: str, n_levels: int,
          mask/prefix-sum pass as the refresh; rows of the sub-plane are
          the shard's key range restricted to each level, so row
          membership — and hence ``level_found`` — matches the global
-         plane exactly); the tiered (or §5.8 pipelined) kernel runs on
-         it.  Resident footprint O(L·W/S).
+         plane exactly); the tiered kernel runs on it.  Resident
+         footprint O(L·W/S).
       3. *composition* — local ranks lift to packed-global by the
          shard's live-lane prefix (:func:`_route_tables`), and ONE
          stacked ``[3, q]`` ``psum`` (masked to each query's owner)
@@ -952,13 +536,13 @@ def _search_shard_body(plane, queries, *, axis: str, n_levels: int,
     local, assembled = _local_subplane(plane, n_levels=n_levels)
     f, r, lv = _masked_descent(local, bounds, lift, queries, axis=axis,
                                query_block=query_block,
-                               interpret=interpret, pipelined=pipelined)
+                               interpret=interpret)
     return f, r, lv, jax.lax.psum(assembled, axis)
 
 
 def _routed_shard_body(plane, q_loc, *, axis: str, n_shards: int,
                        n_levels: int, capacity: int, query_block: int,
-                       interpret: bool, n_live: int, pipelined: bool):
+                       interpret: bool, n_live: int):
     """Per-shard body of the routed query exchange (DESIGN.md §5.6;
     runs under ``shard_map``; ``plane`` leaves are this shard's blocks,
     ``q_loc`` is its ``[q/S]`` slice of the batch-sharded queries).
@@ -1049,8 +633,9 @@ def _routed_shard_body(plane, q_loc, *, axis: str, n_shards: int,
                        fill)                           # [cap] kernel batch
 
     # ---- 3. the tiered descent over the compacted O(q/S) block -----------
-    f, r, lv = _descend_local(local, kq, query_block=query_block,
-                              interpret=interpret, pipelined=pipelined)
+    f, r, lv = _splay_search_arrays(local.keys, kq,
+                                    query_block=query_block,
+                                    interpret=interpret, widths=local.widths)
     rank_g = jnp.where(r >= 0, r + lift, -1)
 
     with jax.named_scope("splay.route"):
@@ -1093,8 +678,7 @@ def _routed_shard_body(plane, q_loc, *, axis: str, n_shards: int,
         q_all = jax.lax.all_gather(q_loc, axis, tiled=True)  # [S*qs]
         fa, ra, la = _masked_descent(
             local, bounds, lift, q_all, axis=axis,
-            query_block=query_block, interpret=interpret,
-            pipelined=pipelined)
+            query_block=query_block, interpret=interpret)
         sl = lambda x: jax.lax.dynamic_slice(x, (ax * qs,), (qs,))
         return sl(fa), sl(ra), sl(la)
 
@@ -1111,9 +695,9 @@ def _routed_shard_body(plane, q_loc, *, axis: str, n_shards: int,
 
 @functools.lru_cache(maxsize=None)
 def _sharded_search_fn(mesh, axis: str, n_levels: int, query_block: int,
-                       interpret: bool, pipelined: bool):
+                       interpret: bool):
     """Build (and cache) the jitted shard_map of the replicate-and-mask
-    path for one (mesh, axis, n_levels, query_block, pipelined) cell —
+    path for one (mesh, axis, n_levels, query_block) cell —
     planes are shape-stable, so serving reuses one entry per mesh.  The
     plane enters as one pytree laid out by ``index_plane_specs`` (its
     residency fields ride along for :func:`_local_subplane`)."""
@@ -1121,8 +705,7 @@ def _sharded_search_fn(mesh, axis: str, n_levels: int, query_block: int,
     specs = shd.index_plane_specs(DeviceLevelArrays, axis)
     body = functools.partial(
         _search_shard_body, axis=axis, n_levels=n_levels,
-        query_block=query_block, interpret=interpret,
-        pipelined=pipelined)
+        query_block=query_block, interpret=interpret)
     fn = jax.shard_map(body, mesh=mesh, in_specs=(specs, P()),
                        out_specs=(P(), P(), P(), P()), check_vma=False)
     return jax.jit(fn)
@@ -1130,20 +713,19 @@ def _sharded_search_fn(mesh, axis: str, n_levels: int, query_block: int,
 
 @functools.lru_cache(maxsize=None)
 def _routed_search_fn(mesh, axis: str, n_levels: int, query_block: int,
-                      interpret: bool, capacity: int, n_live: int,
-                      pipelined: bool):
+                      interpret: bool, capacity: int, n_live: int):
     """Build (and cache) the jitted shard_map of the routed exchange for
-    one (mesh, axis, n_levels, query_block, capacity, n_live,
-    pipelined) cell.  The plane enters as one ``index_plane_specs``
-    pytree; queries enter batch-sharded (``P(axis)``) and the answer
-    triple leaves batch-sharded; the spill count, occupancy vector and
-    assembled count are replicated."""
+    one (mesh, axis, n_levels, query_block, capacity, n_live) cell.
+    The plane enters as one ``index_plane_specs`` pytree; queries enter
+    batch-sharded (``P(axis)``) and the answer triple leaves
+    batch-sharded; the spill count, occupancy vector and assembled
+    count are replicated."""
     from repro.core.device_index import DeviceLevelArrays
     specs = shd.index_plane_specs(DeviceLevelArrays, axis)
     body = functools.partial(
         _routed_shard_body, axis=axis, n_shards=mesh.shape[axis],
         n_levels=n_levels, capacity=capacity, query_block=query_block,
-        interpret=interpret, n_live=n_live, pipelined=pipelined)
+        interpret=interpret, n_live=n_live)
     fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(specs, P(axis)),
@@ -1187,10 +769,9 @@ def splay_search_sharded(level_keys, queries, query_block: int =
                          mesh=None, axis: str = "model",
                          routed: bool = True, capacity: int = None,
                          slack: float = DEFAULT_ROUTE_SLACK,
-                         return_stats: bool = False,
-                         pipelined: bool = None):
+                         return_stats: bool = False):
     """Width-sharded tiered search (DESIGN.md §5.5–§5.6): the
-    rank-windowed descent under ``shard_map`` over the ``splay_width``
+    tiered descent under ``shard_map`` over the ``splay_width``
     axis.  Each shard owns the contiguous key range of its plane
     segment (the same ownership as the §5.4 sharded refresh); by
     default (``routed=True``) the query batch is *exchanged*: each
@@ -1210,11 +791,6 @@ def splay_search_sharded(level_keys, queries, query_block: int =
     imbalance headroom (only read when ``capacity`` is None).
     ``return_stats=True`` appends a :class:`RouteStats` (spill count,
     per-shard occupancy, assembled-shard count) to the returned triple.
-    ``pipelined`` picks the per-shard descent kernel: the §5.8
-    foresight-pipelined one (``True``), the tiered stream (``False``),
-    or backend-adaptive (``None``, the default: pipelined exactly when
-    compiling — ``not interpret`` — so the tiered oracle stays the
-    interpret-mode reference).  Answers are bit-identical either way.
 
     Local sub-planes come from :func:`_local_subplane`: resident on a
     mass-split plane (``local_ok`` set — no per-batch
@@ -1229,13 +805,13 @@ def splay_search_sharded(level_keys, queries, query_block: int =
     routed path leaves them batch-sharded over the mesh; the masked
     path replicates them — same values either way).
 
-    Equivalence: bit-identical to the replicated tiered search (and to
-    ``splay_search_full``) on every plane and query batch — membership,
-    bottom-row predecessor rank, and first-row-found are functions of
-    (plane, query) alone, and the per-shard sub-plane preserves row
-    membership exactly (asserted on 1/2/4-way host meshes in
-    ``tests/test_sharded_search.py``, boundary-straddling windows,
-    forced spill, and mass-split planes included).  On a segmented
+    Equivalence: bit-identical to the replicated tiered search on every
+    plane and query batch — membership, bottom-row predecessor rank,
+    and first-row-found are functions of (plane, query) alone, and the
+    per-shard sub-plane preserves row membership exactly (asserted on
+    1/2/4-way host meshes in ``tests/test_sharded_search.py``,
+    boundary-straddling windows, forced spill, and mass-split planes
+    included).  On a segmented
     (§5.6 mass-split) plane this sharded entry point is the ONLY
     correct search — the gather-to-replicated path assumes a packed
     bottom row.
@@ -1245,7 +821,7 @@ def splay_search_sharded(level_keys, queries, query_block: int =
     gather-to-replicated path with the same return convention (stats:
     zero spill, one pseudo-shard owning the whole batch)."""
     plane = level_keys
-    if not hasattr(plane, "rank_map"):
+    if not _is_plane(plane):
         raise TypeError("splay_search_sharded takes an index plane "
                         "struct (DeviceLevelArrays/LevelArrays), got "
                         f"{type(level_keys).__name__}")
@@ -1257,7 +833,6 @@ def splay_search_sharded(level_keys, queries, query_block: int =
             f"splay_search_sharded: slack must be >= 1.0, got {slack}")
     nq = jnp.asarray(queries).shape[0]
     _check_query_block(query_block, nq)
-    pipelined = bool(pipelined)
     plane = _as_device_plane(plane)
     if mesh is None:
         mesh = shd.plane_width_mesh(plane, axis) or shd.active_mesh()
@@ -1265,8 +840,7 @@ def splay_search_sharded(level_keys, queries, query_block: int =
     if (mesh is None or axis not in mesh.shape
             or width % mesh.shape[axis]):
         out = splay_search(plane, queries, query_block=query_block,
-                           interpret=interpret, sharded=False,
-                           pipelined=pipelined)
+                           interpret=interpret, sharded=False)
         if return_stats:
             return out + (RouteStats(
                 jnp.zeros((), jnp.int32),
@@ -1285,7 +859,7 @@ def splay_search_sharded(level_keys, queries, query_block: int =
         return out
     if not routed:
         fn = _sharded_search_fn(mesh, axis, n_levels, query_block,
-                                interpret, pipelined)
+                                interpret)
         f, r, lv, assembled = fn(plane, queries)
         out = (f, r, lv)
         if return_stats:
@@ -1305,120 +879,12 @@ def splay_search_sharded(level_keys, queries, query_block: int =
         queries = jnp.pad(queries, (0, pad),
                           constant_values=PAD_KEY - 1)
     fn = _routed_search_fn(mesh, axis, n_levels, query_block, interpret,
-                           int(capacity), int(nq), pipelined)
+                           int(capacity), int(nq))
     f, r, lv, spill, occ, assembled = fn(plane, queries)
     out = (f[:nq], r[:nq], lv[:nq])
     if return_stats:
         return out + (RouteStats(spill, occ, assembled),)
     return out
-
-
-# ---------------------------------------------------------------------------
-# seed kernel (baseline): whole matrix as one constant block
-# ---------------------------------------------------------------------------
-
-def _kernel_full(q_ref, lv_ref, found_ref, rank_ref, level_ref, *,
-                 n_levels: int):
-    q = q_ref[...]                                    # [QB]
-    qb = q.shape[0]
-    found = jnp.zeros((qb,), jnp.bool_)
-    level_found = jnp.full((qb,), n_levels, jnp.int32)
-    rank = jnp.zeros((qb,), jnp.int32)
-
-    def body(r, carry):
-        found, level_found, rank = carry
-        all_resolved = jnp.all(found)
-        is_bottom = r == n_levels - 1
-
-        # Skip whole cold rows when every query already resolved — except
-        # the bottom row, which must still produce the predecessor rank
-        # (needed by insert/value lookup).
-        def do_row():
-            row = lv_ref[r, :]                        # [width] in VMEM
-            le = row[None, :] <= q[:, None]           # [QB, width] compare
-            cnt = jnp.sum(le, axis=1).astype(jnp.int32)
-            # membership: the predecessor equals q
-            idx = jnp.maximum(cnt - 1, 0)
-            pred = jnp.take(row, idx)
-            hit = (cnt > 0) & (pred == q)
-            return cnt - 1, hit
-
-        def skip_row():
-            return (jnp.zeros((qb,), jnp.int32),
-                    jnp.zeros((qb,), jnp.bool_))
-
-        run = (~all_resolved) | is_bottom
-        r_rank, hit = jax.lax.cond(run, do_row, skip_row)
-        newly = hit & ~found
-        level_found = jnp.where(newly, r, level_found)
-        found = found | hit
-        rank = jnp.where(is_bottom, r_rank, rank)
-        return found, level_found, rank
-
-    found, level_found, rank = jax.lax.fori_loop(
-        0, n_levels, body, (found, level_found, rank))
-    found_ref[...] = found
-    rank_ref[...] = rank
-    level_ref[...] = level_found
-
-
-def splay_search_full(level_keys, queries, query_block: int =
-                      DEFAULT_QUERY_BLOCK, interpret: bool = True):
-    """Seed baseline: the full [n_levels, width] matrix is a single
-    constant-index block (always resident; O(L·W) compare per query
-    block).  Queries of any length — padded internally.  Accepts an
-    index plane struct in place of the bare matrix; unlike
-    :func:`splay_search` it never dispatches to sharded execution — a
-    width-sharded plane is always gathered to replicated here (the
-    baseline stays a single-device measurement)."""
-    if hasattr(level_keys, "rank_map"):        # index plane struct
-        level_keys = _replicated(jnp.asarray(level_keys.keys))
-        _reject_segmented(level_keys)
-    queries = jnp.asarray(queries)
-    _check_query_block(query_block, queries.shape[0])
-    queries = shd.constrain(queries, "batch")
-    return _splay_search_full_arrays(level_keys, queries,
-                                     query_block=query_block,
-                                     interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("query_block", "interpret"))
-def _splay_search_full_arrays(level_keys, queries, query_block: int =
-                              DEFAULT_QUERY_BLOCK,
-                              interpret: bool = True):
-    n_levels, width = level_keys.shape
-    nq = queries.shape[0]
-    if nq == 0:
-        z = jnp.zeros((0,), jnp.int32)
-        return jnp.zeros((0,), jnp.bool_), z, z
-    pad = (-nq) % query_block
-    if pad:
-        queries = jnp.pad(queries, (0, pad), constant_values=PAD_KEY - 1)
-    nq_p = nq + pad
-    grid = (nq_p // query_block,)
-
-    kernel = functools.partial(_kernel_full, n_levels=n_levels)
-    out_shapes = (
-        jax.ShapeDtypeStruct((nq_p,), jnp.bool_),
-        jax.ShapeDtypeStruct((nq_p,), jnp.int32),
-        jax.ShapeDtypeStruct((nq_p,), jnp.int32),
-    )
-    found, rank, lvl = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((query_block,), lambda i: (i,)),
-            pl.BlockSpec((n_levels, width), lambda i: (0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((query_block,), lambda i: (i,)),
-            pl.BlockSpec((query_block,), lambda i: (i,)),
-            pl.BlockSpec((query_block,), lambda i: (i,)),
-        ),
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(queries, level_keys)
-    return found[:nq], rank[:nq], lvl[:nq]
 
 
 # ---------------------------------------------------------------------------
@@ -1429,7 +895,7 @@ def _splay_search_full_arrays(level_keys, queries, query_block: int =
 def _require_plane(level_keys, op: str):
     """Ordered ops are defined on *packed global ranks*, a plane-level
     concept — they take an index plane struct, never a bare matrix."""
-    if not hasattr(level_keys, "rank_map"):
+    if not _is_plane(level_keys):
         raise TypeError(
             f"{op} takes an index plane struct "
             "(DeviceLevelArrays/LevelArrays), got "
@@ -1578,7 +1044,7 @@ def splay_select(level_keys, ranks, sharded=None, axis: str = "model",
 
 def splay_rank(level_keys, queries, query_block: int =
                DEFAULT_QUERY_BLOCK, interpret: bool = True,
-               sharded=None, pipelined: bool = None):
+               sharded=None):
     """``rank(q)``: the number of live keys ``<= q`` — exactly the
     descent's bottom-row predecessor index plus one, so this is ONE
     :func:`splay_search` call (replicated or routed sharded by the same
@@ -1590,14 +1056,13 @@ def splay_rank(level_keys, queries, query_block: int =
     queries = jnp.asarray(queries, jnp.int32)
     q_eff = jnp.minimum(queries, jnp.int32(PAD_KEY - 1))
     _, r, _ = splay_search(plane, q_eff, query_block=query_block,
-                           interpret=interpret, sharded=sharded,
-                           pipelined=pipelined)
+                           interpret=interpret, sharded=sharded)
     return r + 1
 
 
 def splay_predecessor(level_keys, queries, query_block: int =
                       DEFAULT_QUERY_BLOCK, interpret: bool = True,
-                      sharded=None, pipelined: bool = None):
+                      sharded=None):
     """``predecessor(q)``: the largest live key ``<= q`` and its
     packed-global rank — the descent's final window endpoint, lifted to
     the global rank exactly as membership ranks are.  Returns
@@ -1608,15 +1073,14 @@ def splay_predecessor(level_keys, queries, query_block: int =
     queries = jnp.asarray(queries, jnp.int32)
     q_eff = jnp.minimum(queries, jnp.int32(PAD_KEY - 1))
     _, r, _ = splay_search(plane, q_eff, query_block=query_block,
-                           interpret=interpret, sharded=sharded,
-                           pipelined=pipelined)
+                           interpret=interpret, sharded=sharded)
     keys = splay_select(plane, r, sharded=sharded)
     return jnp.where(r >= 0, keys, jnp.int32(NEG_INF_KEY)), r
 
 
 def splay_successor(level_keys, queries, query_block: int =
                     DEFAULT_QUERY_BLOCK, interpret: bool = True,
-                    sharded=None, pipelined: bool = None):
+                    sharded=None):
     """``successor(q)``: the smallest live key ``>= q`` and its
     packed-global rank.  A membership hit answers ``(q, rank)``
     directly; a miss answers the key one past the predecessor rank.  No
@@ -1628,16 +1092,14 @@ def splay_successor(level_keys, queries, query_block: int =
     none = queries >= jnp.int32(PAD_KEY)          # no key >= PAD_KEY
     q_eff = jnp.minimum(queries, jnp.int32(PAD_KEY - 1))
     f, r, _ = splay_search(plane, q_eff, query_block=query_block,
-                           interpret=interpret, sharded=sharded,
-                           pipelined=pipelined)
+                           interpret=interpret, sharded=sharded)
     r_succ = jnp.where(f & ~none, r, r + 1)
     keys = splay_select(plane, r_succ, sharded=sharded)
     keys = jnp.where(f & ~none, q_eff, keys)
     return jnp.where(none, jnp.int32(PAD_KEY), keys), r_succ
 
 
-def _range_ranks(plane, lo, hi, *, query_block, interpret, sharded,
-                 pipelined):
+def _range_ranks(plane, lo, hi, *, query_block, interpret, sharded):
     """(start rank, in-range count) of the inclusive key range
     ``[lo, hi]`` — ONE batched descent over the concatenated endpoint
     batch (so the routed path pays one exchange for both ends), then
@@ -1648,7 +1110,7 @@ def _range_ranks(plane, lo, hi, *, query_block, interpret, sharded,
     hi_eff = jnp.minimum(hi, jnp.int32(PAD_KEY - 1))
     f, r, _ = splay_search(plane, jnp.concatenate([lo_eff, hi_eff]),
                            query_block=query_block, interpret=interpret,
-                           sharded=sharded, pipelined=pipelined)
+                           sharded=sharded)
     f_lo, r_lo = f[:n], r[:n]
     r_hi = r[n:]
     start = jnp.where(f_lo, r_lo, r_lo + 1)       # |{live k < lo}|
@@ -1659,7 +1121,7 @@ def _range_ranks(plane, lo, hi, *, query_block, interpret, sharded,
 
 def splay_range_count(level_keys, lo, hi, query_block: int =
                       DEFAULT_QUERY_BLOCK, interpret: bool = True,
-                      sharded=None, pipelined: bool = None):
+                      sharded=None):
     """Number of live keys in the inclusive range ``[lo, hi]`` —
     int32 [q] (0 for empty or inverted ranges).  A rank pair from one
     batched descent; on the sharded plane a range spanning adjacent
@@ -1675,15 +1137,13 @@ def splay_range_count(level_keys, lo, hi, query_block: int =
     if lo.shape[0] == 0:
         return jnp.zeros((0,), jnp.int32)
     _, count = _range_ranks(plane, lo, hi, query_block=query_block,
-                            interpret=interpret, sharded=sharded,
-                            pipelined=pipelined)
+                            interpret=interpret, sharded=sharded)
     return count
 
 
 def splay_range_scan(level_keys, lo, hi, max_range: int,
                      query_block: int = DEFAULT_QUERY_BLOCK,
-                     interpret: bool = True, sharded=None,
-                     pipelined: bool = None):
+                     interpret: bool = True, sharded=None):
     """The live keys in the inclusive range ``[lo, hi]``, in key order:
     a rank pair plus a contiguous bottom-row gather (the gather-first
     layout's cheap range scan).  Returns
@@ -1718,8 +1178,7 @@ def splay_range_scan(level_keys, lo, hi, max_range: int,
         return (jnp.zeros((0, max_range), jnp.int32),
                 jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.int32))
     start, count = _range_ranks(plane, lo, hi, query_block=query_block,
-                                interpret=interpret, sharded=sharded,
-                                pipelined=pipelined)
+                                interpret=interpret, sharded=sharded)
     offs = jnp.arange(max_range, dtype=jnp.int32)[None, :]
     want = offs < jnp.minimum(count, max_range)[:, None]
     ranks = jnp.where(want, start[:, None] + offs, -1)

@@ -104,7 +104,7 @@ def splay_demo(args) -> dict:
             and (np.asarray(plen_s) == np.asarray(plen_r)).all()
             and all((np.asarray(getattr(pl_s, f))
                      == np.asarray(getattr(pl_r, f))).all()
-                    for f in ("keys", "widths", "heights", "rank_map")))
+                    for f in ("keys", "widths", "heights")))
 
         # the same loop under the mass-weighted re-split (§5.6): the
         # plane goes segmented, so only the answers — not the layout —
@@ -151,7 +151,7 @@ def splay_demo(args) -> dict:
                                       return_overflow=True)
         refresh_match = all(
             (np.asarray(getattr(ps, f)) == np.asarray(getattr(pr, f))).all()
-            for f in ("keys", "widths", "heights", "rank_map"))
+            for f in ("keys", "widths", "heights"))
         print(f"sharded refresh "
               f"{pc.audit_summary(pc.audit_plane(st3, ps))}")
 

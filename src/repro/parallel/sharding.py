@@ -169,8 +169,8 @@ def named_sharding(shape: Sequence[int],
 
 def constrain_index_plane(plane):
     """Apply the splay index-plane rules to a level-array pytree
-    (``device_index.DeviceLevelArrays``): the [L, W] rectangle and rank
-    map follow ("splay_level", "splay_width") — width-sharded when W
+    (``device_index.DeviceLevelArrays``): the [L, W] key rectangle
+    follows ("splay_level", "splay_width") — width-sharded when W
     divides the model axis, replicated otherwise — and the 1-D
     widths/heights companions follow their own axis.  No-op without an
     active mesh, so serving loops can call it unconditionally.
@@ -184,14 +184,10 @@ def constrain_index_plane(plane):
         "keys": constrain(plane.keys, "splay_level", "splay_width"),
         "widths": constrain(plane.widths, "splay_level"),
         "heights": constrain(plane.heights, "splay_width"),
-        "rank_map": constrain(plane.rank_map, "splay_level",
-                              "splay_width"),
         "slots": constrain(plane.slots, "splay_width"),
     }
     if hasattr(plane, "local_ok"):     # DeviceLevelArrays residency set
         fields.update(
-            bot_rank=constrain(plane.bot_rank, "splay_level",
-                               "splay_width"),
             local_bot=constrain(plane.local_bot, "splay_width"),
             local_heights=constrain(plane.local_heights, "splay_width"),
             local_live=constrain(plane.local_live, "splay_width"),
@@ -201,14 +197,13 @@ def constrain_index_plane(plane):
 
 # spec of every known index-plane field on a width-sharded layout; the
 # builder below filters by the plane class's actual fields so the host
-# 4-field LevelArrays and the device 10-field DeviceLevelArrays both
+# 3-field LevelArrays and the device 8-field DeviceLevelArrays both
 # resolve (DESIGN.md §5.8: the residency set rides the same layout —
 # local_* blocks are per-shard, the validity bit replicates)
 def _plane_field_specs(axis: str):
     return {
         "keys": P(None, axis), "widths": P(), "heights": P(axis),
-        "rank_map": P(None, axis), "slots": P(axis),
-        "bot_rank": P(None, axis),
+        "slots": P(axis),
         "local_bot": P(axis), "local_heights": P(axis),
         "local_live": P(axis), "local_ok": P(),
     }
@@ -217,8 +212,8 @@ def _plane_field_specs(axis: str):
 def index_plane_specs(plane_cls, axis: str = "model"):
     """The ``PartitionSpec`` pytree of a width-sharded index plane, in
     the shape of ``plane_cls`` (``device_index.DeviceLevelArrays``):
-    ``keys``/``rank_map``/``bot_rank`` split their width (last)
-    dimension over ``axis``; ``heights``/``slots`` and the §5.8
+    ``keys`` splits its width (last) dimension over ``axis``;
+    ``heights``/``slots`` and the §5.8
     residency companions ``local_bot``/``local_heights``/``local_live``
     split their only dimension; the per-level ``widths`` vector and the
     ``local_ok`` staleness bit are replicated (every shard needs every

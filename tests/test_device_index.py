@@ -22,7 +22,7 @@ from repro.kernels import ops, ref
 
 def _assert_plane_equal(plane: dix.DeviceLevelArrays, host: la.LevelArrays,
                         msg=""):
-    for f in ("keys", "widths", "heights", "rank_map"):
+    for f in ("keys", "widths", "heights"):
         np.testing.assert_array_equal(
             np.asarray(getattr(plane, f)), getattr(host, f),
             err_msg=f"{f} {msg}")
@@ -181,9 +181,27 @@ def test_run_epoch_and_serving_loop_on_device():
     assert res3.shape == (B,)
 
 
+def test_plane_holds_only_what_its_readers_read():
+    """The plane format: the key rectangle and row widths the descent
+    reads, the heights and slot map the refresh reads, and the
+    residency set of the sharded search — on host and device alike."""
+    assert dix.DeviceLevelArrays._fields == (
+        "keys", "widths", "heights", "slots", "local_bot",
+        "local_heights", "local_live", "local_ok")
+    assert la.LevelArrays._fields == ("keys", "widths", "heights")
+    plane = dix.build_device(jnp.asarray([5, 3, dix.PAD_KEY, 9], jnp.int32),
+                             jnp.asarray([0, 2, 0, 1], jnp.int32), 3)
+    assert np.asarray(plane.keys).tolist() == [
+        [3, dix.PAD_KEY, dix.PAD_KEY, dix.PAD_KEY],
+        [3, 9, dix.PAD_KEY, dix.PAD_KEY],
+        [3, 5, 9, dix.PAD_KEY]]
+    assert np.asarray(plane.widths).tolist() == [1, 2, 3]
+
+
 def test_kernels_consume_device_plane():
-    """The search wrappers take the plane struct directly; results match
-    the jnp reference oracle on the same rectangle."""
+    """The search wrappers take the plane struct directly — the device
+    plane and its host copy alike; results match the jnp reference
+    oracle on the same rectangle."""
     pool = list(range(0, 256, 2))
     st = _seed_state(pool, cap=512, ml=14)
     plane = dix.from_state_device(st, n_levels=14, width=510)
@@ -195,8 +213,8 @@ def test_kernels_consume_device_plane():
     np.testing.assert_array_equal(np.asarray(f), np.asarray(f0))
     np.testing.assert_array_equal(np.asarray(r), np.asarray(r0))
     np.testing.assert_array_equal(np.asarray(lv), np.asarray(lv0))
-    out_full = ops.splay_search_full(plane, qs)
-    for a, b in zip((f, r, lv), out_full):
+    out_host = ops.splay_search(dix.to_host(plane), qs)
+    for a, b in zip(out_host, (f0, r0, lv0)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -337,7 +355,7 @@ def test_from_state_device_pads_small_states():
 def _assemble_by_search(keys_sorted, rel_h, n_levels):
     """The plane's rows by the inverse prefix sum: ``_compact_take``'s
     binary search per output lane, then numpy gathers through it.
-    Returns ``(keys, widths, rank_map, bot_rank, live)``."""
+    Returns ``(keys, widths)``."""
     ks, h = np.asarray(keys_sorted), np.asarray(rel_h)
     width = ks.size
     h = np.where(ks != dix.PAD_KEY, h, -1)
@@ -348,11 +366,7 @@ def _assemble_by_search(keys_sorted, rel_h, n_levels):
                      for c in cs])
     live = np.arange(width)[None, :] < widths[:, None]
     keys = np.where(live, ks[take], dix.PAD_KEY)
-    cs_next = np.concatenate([cs[1:], np.ones((1, width), np.int32)])
-    rank_map = np.where(live, np.take_along_axis(cs_next, take, 1) - 1,
-                        np.append(widths[1:], 0)[:, None])
-    rank_map[-1] = np.arange(width)
-    return keys, widths, rank_map, take, live
+    return keys, widths
 
 
 @pytest.mark.parametrize("n,width,n_levels,heights", [
@@ -362,6 +376,9 @@ def _assemble_by_search(keys_sorted, rel_h, n_levels):
     (211, 300, 7, "geometric"),         # width not a power of two
     (90, 128, 12, "low"),               # levels above the largest height
     (120, 160, 5, "high"),              # heights past the top saturate
+    (0, 300, 1, "top"),                 # empty plane of one level
+    (1, 64, 6, "top"),                  # one key, at full height
+    (100, 128, 1, "geometric"),         # one level: the bottom row alone
 ])
 def test_sorted_compaction_matches_search_oracle(n, width, n_levels,
                                                  heights):
@@ -377,13 +394,9 @@ def test_sorted_compaction_matches_search_oracle(n, width, n_levels,
     slots = rng.permutation(width).astype(np.int32)
     plane = jax.jit(dix._assemble_device, static_argnums=3)(
         jnp.asarray(ks), jnp.asarray(h), jnp.asarray(slots), n_levels)
-    keys, widths, rank_map, bot_rank, live = _assemble_by_search(
-        ks, h, n_levels)
+    keys, widths = _assemble_by_search(ks, h, n_levels)
     np.testing.assert_array_equal(np.asarray(plane.keys), keys)
     np.testing.assert_array_equal(np.asarray(plane.widths), widths)
-    np.testing.assert_array_equal(np.asarray(plane.rank_map), rank_map)
-    np.testing.assert_array_equal(np.asarray(plane.bot_rank)[live],
-                                  bot_rank[live])
     np.testing.assert_array_equal(np.asarray(plane.heights),
                                   np.where(ks != dix.PAD_KEY, h, 0))
     np.testing.assert_array_equal(np.asarray(plane.slots)[:n], slots[:n])
@@ -420,8 +433,9 @@ def _slot_state(keys, heights, deleted, cap, max_level):
 ])
 def test_refresh_merge_by_sort(case):
     """One incremental refresh with inserts against the host build of
-    the keys it can hold: the merged bottom row, every row above it,
-    the live bottom ranks and slots, and the overflow count."""
+    the keys it can hold: the merged bottom row, every row above it
+    (each nested in the row below), the live slots, and the overflow
+    count."""
     cap, L, W, kk = 128, 6, 48, 8
     rng = np.random.default_rng(len(case))
     n_old = {"truncated": 44}.get(case, 30)
@@ -459,7 +473,7 @@ def test_refresh_merge_by_sort(case):
     np.testing.assert_array_equal(np.asarray(plane.slots)[:w_bot],
                                   2 + held)
     rows = np.asarray(plane.keys)
-    live = np.arange(W)[None, :] < np.asarray(plane.widths)[:, None]
-    np.testing.assert_array_equal(
-        np.asarray(plane.bot_rank)[live],
-        np.searchsorted(bottom[:w_bot], rows[live]))
+    widths = np.asarray(plane.widths)
+    for r in range(L - 1):
+        assert np.isin(rows[r, :widths[r]],
+                       rows[r + 1, :widths[r + 1]]).all(), r

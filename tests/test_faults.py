@@ -77,8 +77,6 @@ def test_flips_target_live_lanes_only():
         for field, idx, _ in recs:
             if field == "heights":
                 assert live[-1][idx[0]]
-            elif field == "rank_map":
-                assert live[idx]          # live above the bottom row
             else:
                 assert live[idx]
 

@@ -1,4 +1,4 @@
-"""LevelArrays invariants: nested rows, rank maps, incremental refresh.
+"""LevelArrays invariants: nested rows, incremental refresh.
 
 Non-hypothesis counterpart of the property suite (which is skipped when
 hypothesis is absent): the nested-rows invariant (every key in row r
@@ -18,7 +18,7 @@ from repro.core import splaylist as sx
 
 def _check_invariants(L: la.LevelArrays):
     kk = L.keys
-    n_levels, width = kk.shape
+    n_levels = kk.shape[0]
     for r in range(n_levels):
         live = kk[r][kk[r] != la.PAD_KEY]
         assert len(live) == L.widths[r]
@@ -26,15 +26,8 @@ def _check_invariants(L: la.LevelArrays):
         if r + 1 < n_levels:
             nxt = kk[r + 1][kk[r + 1] != la.PAD_KEY]
             assert set(live).issubset(set(nxt)), f"row {r} not nested"
-            # rank map: live entries point at the same key one row down,
-            # pad entries close the window at the next row's width
-            for j in range(width):
-                if j < L.widths[r]:
-                    assert kk[r + 1][L.rank_map[r, j]] == kk[r, j]
-                else:
-                    assert L.rank_map[r, j] == L.widths[r + 1]
-        else:
-            np.testing.assert_array_equal(L.rank_map[r], np.arange(width))
+        # live entries form a prefix: every lane past the width is pad
+        assert (kk[r][L.widths[r]:] == la.PAD_KEY).all(), r
 
 
 @pytest.mark.parametrize("n,hmax,min_levels", [
@@ -42,7 +35,7 @@ def _check_invariants(L: la.LevelArrays):
     (123, 1, 8),          # empty top rows (min_levels >> max height)
     (500, 7, 2),
 ])
-def test_nested_rows_and_rank_map(n, hmax, min_levels):
+def test_nested_rows(n, hmax, min_levels):
     rng = np.random.default_rng(n + hmax)
     keys = rng.choice(10 ** 6, n, replace=False).astype(np.int32)
     heights = rng.integers(0, hmax, n).astype(np.int32)
@@ -84,7 +77,6 @@ def test_refresh_matches_full_build_same_keys():
     np.testing.assert_array_equal(out.keys, ref.keys)
     np.testing.assert_array_equal(out.widths, ref.widths)
     np.testing.assert_array_equal(out.heights, ref.heights)
-    np.testing.assert_array_equal(out.rank_map, ref.rank_map)
     _check_invariants(out)
 
 
@@ -118,8 +110,6 @@ def test_refresh_preserves_shape_on_transient_empty():
     assert out.keys.shape == prev.keys.shape
     assert (out.widths == 0).all()
     assert (out.keys == la.PAD_KEY).all()
-    np.testing.assert_array_equal(out.rank_map[-1],
-                                  np.arange(prev.keys.shape[1]))
     _check_invariants(out)
     # and refreshing out of the empty restores membership at that shape
     ins = jnp.asarray(np.asarray(pool[:4], np.int32))

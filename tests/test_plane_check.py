@@ -79,8 +79,7 @@ def _two_segment_plane(plane):
         [getattr(a, f), getattr(b, f)], axis=-1)
     return dix.DeviceLevelArrays(
         keys=cat("keys"), widths=a.widths + b.widths,
-        heights=cat("heights"), rank_map=cat("rank_map"),
-        slots=cat("slots"), bot_rank=cat("bot_rank"),
+        heights=cat("heights"), slots=cat("slots"),
         local_bot=cat("local_bot"), local_heights=cat("local_heights"),
         local_live=cat("local_live"), local_ok=a.local_ok)
 
@@ -91,8 +90,8 @@ def test_hand_built_two_segment_plane_audits_ok():
     assert dix.plane_is_segmented(seg)
     a = pc.audit_plane(st, seg, n_segments=2)
     assert pc.audit_ok(a), a
-    # the same arrays audited as ONE segment must fail: block-local
-    # rank indices and interior pads violate the packed invariants
+    # the same arrays audited as ONE segment must fail: interior pads
+    # violate the packed invariants
     assert not pc.audit_ok(pc.audit_plane(st, seg, n_segments=1))
 
 
@@ -107,6 +106,54 @@ def test_bitflip_family_detected(field):
         assert not pc.audit_ok(a), (field, seed, a)
     # the clean plane still audits OK (flips copied, never in place)
     assert pc.audit_ok(pc.audit_plane(st, plane, n_segments=1))
+
+
+def _tall():
+    """The clean fixture's key set with heights cycling through every
+    row, so row 0 holds keys (the state-built plane's row 0 is empty)."""
+    st, _ = _clean()
+    kk = np.full(W, dix.PAD_KEY, np.int32)
+    hh = np.zeros(W, np.int32)
+    kk[:POOL.size] = POOL
+    hh[:POOL.size] = np.arange(POOL.size) % L
+    return st, dix.build_device(jnp.asarray(kk), jnp.asarray(hh), L)
+
+
+def test_upper_row_key_absent_from_bottom_row_detected():
+    """A key of row 0 changed to a value the bottom row does not hold,
+    with the row's order kept: only the upper-row membership check can
+    see it (the row stays sorted and its live count stays right)."""
+    st, plane = _tall()
+    assert pc.audit_ok(pc.audit_plane(st, plane, n_segments=1))
+    keys = np.asarray(plane.keys).copy()
+    live = np.nonzero(keys[0] != dix.PAD_KEY)[0]
+    assert live.size >= 2, "fixture has fewer than two top-row keys"
+    j = int(live[0])
+    keys[0, j] = keys[0, j] - 1            # POOL is even: k - 1 is absent
+    assert keys[0, j] not in np.asarray(plane.keys[L - 1])
+    a = pc.audit_plane(st, plane._replace(keys=jnp.asarray(keys)),
+                       n_segments=1)
+    assert a.upper_bad == 1 and not pc.audit_ok(a), a
+    assert a.row_unsorted == 0 and a.heights_bad == 0, a
+
+
+def test_upper_row_key_too_short_for_its_row_detected():
+    """A key of row 0 swapped for a bottom-row key whose height keeps it
+    out of row 0, the row kept sorted: the key is in the bottom row, but
+    not at the height row 0 requires."""
+    st, plane = _tall()
+    keys = np.asarray(plane.keys).copy()
+    h = np.asarray(plane.heights)
+    bot = keys[L - 1]
+    top = keys[0][keys[0] != dix.PAD_KEY]
+    assert top.size, "fixture has no top-row keys"
+    j = int(np.nonzero(keys[0] == top[0])[0][0])
+    short = bot[(bot < top[0]) & (h < L - 1)]
+    assert short.size, "no short key below the smallest top-row key"
+    keys[0, j] = short[-1]
+    a = pc.audit_plane(st, plane._replace(keys=jnp.asarray(keys)),
+                       n_segments=1)
+    assert a.upper_bad == 1 and not pc.audit_ok(a), a
 
 
 def test_bitflips_detected_on_segmented_layout():

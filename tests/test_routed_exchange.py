@@ -19,6 +19,7 @@ import pytest
 
 from repro.core import device_index as dix
 from repro.core import splaylist as sx
+from repro.kernels.ref import splay_search_ref
 from repro.kernels import splay_search as ssk
 from repro.parallel import sharding as shd
 
@@ -222,10 +223,14 @@ def test_gather_path_rejects_segmented_plane():
     with pytest.raises(ValueError, match="segmented"):
         ssk.splay_search(seg, qs, sharded=False)
     with pytest.raises(ValueError, match="segmented"):
-        ssk.splay_search_full(seg, qs)
-    # packed planes (trailing pads only) pass untouched
-    f, _, _ = ssk.splay_search(plane, qs, sharded=False)
-    assert bool(f[0])
+        ssk.splay_select(seg, jnp.asarray([0, 1], jnp.int32),
+                         sharded=False)
+    # packed planes (trailing pads only) pass untouched, answering like
+    # the oracle
+    out = ssk.splay_search(plane, qs, sharded=False)
+    assert bool(out[0][0])
+    for got, want in zip(out, splay_search_ref(plane.keys, qs)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_meshless_paths_reject_mass_and_segmented():

@@ -125,7 +125,7 @@ def child() -> dict:
     found["state_fields_differing"] = [
         f for f in a if f != "counters" and not np.array_equal(a[f], b[f])]
     found["plane_fields_differing"] = [
-        f for f in ("keys", "widths", "heights", "rank_map", "bot_rank")
+        f for f in ("keys", "widths", "heights")
         if not np.array_equal(np.asarray(getattr(pl_s, f)),
                               np.asarray(getattr(pl_r, f)))]
     ref = reference.KeySet(keys, hits)

@@ -57,7 +57,7 @@ def test_no_mesh_falls_back_to_replicated():
     p_r, ovf_r = dix.refresh_device(st, plane, max_new=8,
                                     return_overflow=True)
     assert int(ovf) == int(ovf_r) == 0
-    for f in ("keys", "widths", "heights", "rank_map"):
+    for f in ("keys", "widths", "heights"):
         np.testing.assert_array_equal(
             np.asarray(getattr(p_s, f)), np.asarray(getattr(p_r, f)))
 

@@ -65,8 +65,8 @@ def test_constrain_index_plane_roundtrip():
         out = shd.constrain_index_plane(plane)
     np.testing.assert_array_equal(np.asarray(out.keys),
                                   np.asarray(plane.keys))
-    np.testing.assert_array_equal(np.asarray(out.rank_map),
-                                  np.asarray(plane.rank_map))
+    np.testing.assert_array_equal(np.asarray(out.heights),
+                                  np.asarray(plane.heights))
 
 
 def test_constrain_noop_without_mesh():

@@ -11,10 +11,10 @@ with no binary search per lane into a ``W``-wide row.  The widest plane
 one device's descent compiles, ``splay_search.MAX_DESCENT_WIDTH``, is
 checked against the compiler: twice as wide runs out of VMEM.  Each
 asserts that the kernel made it into the executable
-(``tpu_custom_call``).
-The pipelined descent is interpret-only and has no test here.  The
-plane refresh is compiled at the same deployment shape, to check that
-its row compaction stays a sort with no per-lane gather.
+(``tpu_custom_call``).  The plane refresh is compiled at the same
+deployment shape, to check that its row compaction stays one
+single-operand sort with no per-lane gather, and the four-chip refresh
+to check that it gathers one run per level row.
 
 The topology is described inside a fixture (never at import), so every
 test worker collects the same tests and only the one running this file
@@ -98,7 +98,7 @@ def test_routed_sharded_search_compiles(topo):
                                    sharding=NamedSharding(mesh, P(axis)))
     fn = ssk._routed_search_fn(
         mesh, axis, n_levels, ssk.DEFAULT_QUERY_BLOCK, False,
-        ssk.route_capacity(nq, 4), nq, False)
+        ssk.route_capacity(nq, 4), nq)
     compiled = fn.lower(plane, queries).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
@@ -145,7 +145,7 @@ def test_routed_sharded_search_compiles_at_the_four_chip_cell_shape(topo):
                                    sharding=NamedSharding(mesh, P(axis)))
     fn = ssk._routed_search_fn(
         mesh, axis, n_levels, ssk.DEFAULT_QUERY_BLOCK, False,
-        ssk.route_capacity(nq, 4), nq, False)
+        ssk.route_capacity(nq, 4), nq)
     text = fn.lower(plane, queries).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-to-all" in text
@@ -155,7 +155,7 @@ def test_routed_sharded_search_compiles_at_the_four_chip_cell_shape(topo):
 @pytest.fixture(scope="module")
 def four_chip_refresh_text(topo):
     """The lanes-split sharded refresh of ``paper4m-ro-99-1``, compiled
-    once for both tests below: the replicated 4,194,306-slot state
+    once for the tests below: the replicated 4,194,306-slot state
     against the 2^22-lane plane of 22 levels over four devices."""
     n_levels, width, axis = 22, 2 ** 22, "model"
     mesh, plane = _sharded_plane_shapes(topo, n_levels, width, axis)
@@ -194,15 +194,34 @@ def test_sharded_refresh_composes_rows_by_a_sort_without_per_lane_search(
     assert gathers, "no gather parsed: the HLO text format changed"
     assert width not in [size(shapes[op]) for _, op in gathers]
     assert n_levels * lanes not in [size(dims) for dims, _ in gathers]
-    rect = rf"s32\[{n_levels},{lanes}\]"
-    assert re.search(rf"= \({rect}\S*(, {rect}\S*)*\) sort\(", text)
+    assert re.search(_sort_of(rf"s32\[{n_levels},{lanes}\]"), text)
 
 
-def test_refresh_compaction_sorts_without_per_lane_gathers(topo):
-    """The plane refresh at the paper's cell shape lays its rows out by
-    a sort along the width axis: no gather in the compiled program has
-    one index per lane of the ``[L, W]`` rectangle (a binary search per
-    output lane, or a gather through a compaction permutation, has)."""
+def test_sharded_refresh_gathers_one_run_per_level_row(
+        four_chip_refresh_text):
+    """The placement all-gathers each level row's keys alone: one
+    ``[S, W/S]`` run per row, never the ``[S, 3, W/S]`` stack a plane
+    carrying per-row rank companions would ship."""
+    text, lanes = four_chip_refresh_text, 2 ** 20
+    # output shapes of every all-gather, unit dimensions dropped
+    gathered = [tuple(int(d) for d in dims.split(",") if d and d != "1")
+                for dims in re.findall(
+                    r"= s32\[([\d,]*)\]\S* all-gather\(", text)]
+    assert gathered, "no all-gather parsed: the HLO text format changed"
+    assert (4, lanes) in gathered, gathered
+    assert (4, 3, lanes) not in gathered, gathered
+
+
+def _sort_of(rect: str) -> str:
+    """A sort whose output is the rectangle ``rect``: one operand, or a
+    tuple of operands of that shape."""
+    return rf"= ({rect}|\({rect}\S*(, {rect}\S*)*\))\S* sort\("
+
+
+@pytest.fixture(scope="module")
+def one_chip_refresh_text(topo):
+    """The plane refresh at the paper's cell shape (``L = 17``,
+    ``W = 131072``) on one device, compiled once for both tests below."""
     n_levels, width, capacity, max_new = 17, 131072, 131074, 1024
     one = SingleDeviceSharding(topo.devices[0])
 
@@ -213,12 +232,31 @@ def test_refresh_compaction_sorts_without_per_lane_gathers(topo):
 
     st = place(jax.eval_shape(lambda: sx.make(capacity, n_levels)))
     plane = place(_plane_shapes(n_levels, width))
-    text = dix.refresh_device.lower(
+    return dix.refresh_device.lower(
         st, plane, max_new=max_new).compile().as_text()
+
+
+def test_refresh_compaction_sorts_without_per_lane_gathers(
+        one_chip_refresh_text):
+    """The plane refresh at the paper's cell shape lays its rows out by
+    a sort along the width axis: no gather in the compiled program has
+    one index per lane of the ``[L, W]`` rectangle (a binary search per
+    output lane, or a gather through a compaction permutation, has)."""
+    n_levels, width = 17, 131072
+    text = one_chip_refresh_text
     gathers = [math.prod(int(d) for d in dims.split(",") if d)
                for dims in re.findall(r"= \w+\[([\d,]*)\]\S* gather\(",
                                       text)]
     assert gathers, "no gather parsed: the HLO text format changed"
     assert n_levels * width not in gathers
-    rect = rf"s32\[{n_levels},{width}\]"
-    assert re.search(rf"= \({rect}\S*(, {rect}\S*)*\) sort\(", text)
+    assert re.search(_sort_of(rf"s32\[{n_levels},{width}\]"), text)
+
+
+def test_refresh_compaction_is_one_single_operand_sort(
+        one_chip_refresh_text):
+    """The row compaction sorts the key rectangle alone: one
+    ``s32[17,131072]`` operand, no payload carried beside it."""
+    rect = r"s32\[17,131072\]"
+    text = one_chip_refresh_text
+    assert len(re.findall(rf"= {rect}\S* sort\(", text)) == 1
+    assert not re.search(rf"= \({rect}[^\n]*\) sort\(", text)
